@@ -1,6 +1,5 @@
 """Dev driver: device-profile the BERT bench step and print the
-per-fusion breakdown (the BASELINE.md BERT tables — VERDICT round-4
-item 2: BERT evidence at the GPT grade).
+per-fusion breakdown.
 
 Usage: python _profile_bert.py [iters] [--dropout=R] [--batch=N]
 [--remat] — runs the EXACT bench step (imported from

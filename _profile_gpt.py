@@ -1,5 +1,5 @@
 """Dev driver: device-profile the flagship GPT bench step and print the
-per-fusion breakdown (the BASELINE.md bucket tables come from this).
+per-fusion breakdown.
 
 Usage: python _profile_gpt.py [iters] [--dropout=R] — runs bench.py's
 exact step under jax.profiler.trace and aggregates with

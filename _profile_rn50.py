@@ -1,5 +1,5 @@
 """Dev driver: device-profile the RN50 bench step (fused or unfused)
-and print the per-fusion breakdown (the BASELINE.md roofline tables).
+and print the per-fusion breakdown.
 
 Usage: python _profile_rn50.py [fused(0|1)] [iters]
 """

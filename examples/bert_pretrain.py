@@ -1,6 +1,6 @@
 """BERT pretraining with FusedLAMB + fused LayerNorm.
 
-The BASELINE.md config-4 scenario ("BERT-Large pretrain with FusedLAMB
+The BASELINE.json config-4 scenario ("BERT-Large pretrain with FusedLAMB
 + apex.normalization.FusedLayerNorm"; reference:
 apex/transformer/testing/standalone_bert.py driven by the L0 BERT
 minimal test, run_bert_minimal_test.py). Masked-LM objective on
@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
@@ -30,6 +30,7 @@ from rocm_apex_tpu.amp import all_finite
 from rocm_apex_tpu.models import BertConfig, BertModel
 from rocm_apex_tpu.optimizers import fused_lamb
 from rocm_apex_tpu.transformer.testing import parse_args
+from rocm_apex_tpu.utils.compile_cache import enable_compile_cache
 from rocm_apex_tpu.utils.tree import path_str
 
 
@@ -104,7 +105,7 @@ def main():
             local_step, mesh=mesh,
             in_specs=(P(), P(), P("data"), P("data"), P("data")),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -131,4 +132,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -4,7 +4,7 @@ TPU-native rebuild of the reference's DCGAN example
 (reference: examples/dcgan/main_amp.py — two models, two optimizers,
 `amp.initialize(num_losses=3)` with a scaler per loss). Generator and
 discriminator train data-parallel over the mesh; BatchNorm stats
-optionally merge across replicas (--sync-bn), the BASELINE.md config-3
+optionally merge across replicas (--sync-bn), the BASELINE.json config-3
 scenario.
 
 CPU smoke:
@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
@@ -29,6 +29,7 @@ from rocm_apex_tpu import amp
 from rocm_apex_tpu.models import Discriminator, Generator
 from rocm_apex_tpu.optimizers import FusedAdam
 from rocm_apex_tpu.parallel import sync_gradients
+from rocm_apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -147,7 +148,7 @@ def main():
             in_specs=(P(), P(), P(), P(), P(), P(), P(),
                       P("data"), P("data"), P("data")),
             out_specs=(P(), P(), P(), P(), P(), P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -173,4 +174,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -34,6 +34,7 @@ sys.path.insert(0, __file__.rsplit("/", 2)[0])
 from rocm_apex_tpu.inference import InferenceEngine, SamplingParams
 from rocm_apex_tpu.models.gpt import GPTConfig, GPTModel
 from rocm_apex_tpu.monitor import JsonlWriter, Tracer
+from rocm_apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def _install_sigterm_drain() -> threading.Event:
@@ -331,4 +332,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

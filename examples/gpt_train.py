@@ -22,7 +22,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
@@ -32,6 +32,7 @@ import optax
 from rocm_apex_tpu.amp import all_finite
 from rocm_apex_tpu.checkpoint import CheckpointManager
 from rocm_apex_tpu.contrib.optimizers import distributed_fused_adam
+from rocm_apex_tpu.models import gpt_134m
 from rocm_apex_tpu.models.gpt import GPTConfig, GPTModel, gpt_loss_fn
 from rocm_apex_tpu.monitor import (
     SLO,
@@ -54,6 +55,7 @@ from rocm_apex_tpu.optimizers.packed import PackedOptimizerStep
 from rocm_apex_tpu.transformer import parallel_state
 from rocm_apex_tpu.transformer.amp import GradScaler
 from rocm_apex_tpu.transformer.testing import parse_args
+from rocm_apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def _observability_args(parser):
@@ -88,6 +90,15 @@ def _observability_args(parser):
              "auto-set the threshold to 3x the first logging "
              "window's mean step time. Alerts print at the end and "
              "ride /varz when --metrics-port is set",
+    )
+    # the example's own default is no dropout (the schema's is 0.1);
+    # --hidden-dropout / --attention-dropout turn it on
+    parser.set_defaults(hidden_dropout=0.0, attention_dropout=0.0)
+    g0 = parser.add_argument_group(title="model (examples)")
+    g0.add_argument(
+        "--vocab-size", type=int, default=8192,
+        help="vocabulary size (the argument schema takes it from a "
+             "tokenizer file; the example trains on random ids)",
     )
     g2 = parser.add_argument_group(title="distributed optimizer")
     g2.add_argument(
@@ -150,14 +161,14 @@ def main():
     print(f"mesh: data={dp} x tensor={tp}")
 
     cfg = GPTConfig(
-        vocab_size=8192,
+        vocab_size=args.vocab_size,
         hidden_size=args.hidden_size,
         num_layers=args.num_layers,
         num_attention_heads=args.num_attention_heads,
         max_position_embeddings=args.max_position_embeddings,
         ffn_hidden_size=args.ffn_hidden_size,
-        hidden_dropout=0.0,
-        attention_dropout=0.0,
+        hidden_dropout=args.hidden_dropout,
+        attention_dropout=args.attention_dropout,
         tensor_parallel_size=tp,
         init_method_std=args.init_method_std,
         # the argument system migrates --checkpoint-activations to
@@ -198,6 +209,19 @@ def main():
 
     b_local = args.micro_batch_size
     seq = args.seq_length
+    dropout = max(args.hidden_dropout, args.attention_dropout)
+
+    def per_token_losses(p, tokens, labels, drop_rng):
+        if dropout == 0.0:
+            return model.apply(p, tokens, labels=labels)
+        # each data-parallel rank draws its own masks
+        drop_rng = jax.random.fold_in(
+            drop_rng, jax.lax.axis_index(parallel_state.DATA_AXIS)
+        )
+        return model.apply(
+            p, tokens, labels=labels, deterministic=False,
+            rngs={"dropout": drop_rng},
+        )
 
     def local_init(tokens):
         params32 = model.init(jax.random.PRNGKey(args.seed), tokens)
@@ -208,11 +232,11 @@ def main():
             return (params32, dist.init(params32)), scaler.init()
         return opt.init(params32), scaler.init()
 
-    def local_step_dist(state, sstate, tokens, labels):
+    def local_step_dist(state, sstate, tokens, labels, drop_rng):
         params, ostate = state
 
         def loss_fn(p):
-            losses = model.apply(p, tokens, labels=labels)
+            losses = per_token_losses(p, tokens, labels, drop_rng)
             return gpt_loss_fn(losses) * scaler.loss_scale(sstate)
 
         scaled, grads = jax.value_and_grad(loss_fn)(params)
@@ -249,16 +273,16 @@ def main():
             )))
         # pre-reduce-scatter grads differ across dp ranks, so every
         # scalar above is rank-local — mean them so the P() out_spec
-        # (check_rep=False) carries honest replicated values
+        # (check_vma=False) carries honest replicated values
         metrics = jax.tree_util.tree_map(
             lambda x: jax.lax.pmean(x, parallel_state.DATA_AXIS),
             metrics,
         )
         return (params2, ostate2), sstate2, metrics
 
-    def local_step(state, sstate, tokens, labels):
+    def local_step(state, sstate, tokens, labels, drop_rng):
         def loss_fn(p):
-            losses = model.apply(p, tokens, labels=labels)
+            losses = per_token_losses(p, tokens, labels, drop_rng)
             return gpt_loss_fn(losses) * scaler.loss_scale(sstate)
 
         scaled, grads = jax.value_and_grad(loss_fn)(state.model)
@@ -304,7 +328,7 @@ def main():
         shard_map(
             local_init, mesh=mesh,
             in_specs=(data_spec,), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
     # the (state, sstate) carry is donated: the loop reassigns both
@@ -316,9 +340,9 @@ def main():
         shard_map(
             local_step_dist if dist is not None else local_step,
             mesh=mesh,
-            in_specs=(P(), P(), data_spec, data_spec),
+            in_specs=(P(), P(), data_spec, data_spec, P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         ),
         donate_argnums=(0, 1),
     )
@@ -327,12 +351,13 @@ def main():
     # chaining splits, so a resumed run regenerates iteration N's batch
     # bitwise without replaying iterations 0..N-1
     base_rng = jax.random.PRNGKey(args.seed + 1)
+    drop_base = gpt_134m.dropout_key(dropout)
     tokens0 = jnp.ones((b_local * dp, seq), jnp.int32)
     state, sstate = init_f(tokens0)
 
     # --- checkpointing (--checkpoint-dir): rank-stacked host view ----
     # Training state lives at per-rank local shapes behind the P()
-    # out_specs (check_rep=False) — the "replicated" claim is false for
+    # out_specs (check_vma=False) — the "replicated" claim is false for
     # TP param shards and 1/dp ZeRO shards, so saving the host view of
     # `state` directly would persist rank 0's shard for every rank. The
     # gather jit all-gathers over BOTH mesh axes into a genuinely
@@ -356,11 +381,11 @@ def main():
     if args.checkpoint_dir is not None:
         gather_f = jax.jit(shard_map(
             local_gather, mesh=mesh,
-            in_specs=(P(), P()), out_specs=P(), check_rep=False,
+            in_specs=(P(), P()), out_specs=P(), check_vma=False,
         ))
         scatter_f = jax.jit(shard_map(
             local_scatter, mesh=mesh,
-            in_specs=(P(),), out_specs=(P(), P()), check_rep=False,
+            in_specs=(P(),), out_specs=(P(), P()), check_vma=False,
         ))
         # SIGTERM → should_exit(): the loop saves and leaves cleanly
         mgr = CheckpointManager(args.checkpoint_dir)
@@ -461,7 +486,8 @@ def main():
             logger.start_step()
             with tracer.step_span(it + 1):
                 state, sstate, metrics = step_f(
-                    state, sstate, tokens, labels
+                    state, sstate, tokens, labels,
+                    jax.random.fold_in(drop_base, it),
                 )
                 logger.end_step(sync_on=metrics["loss"])  # fetch = sync
             record = logger.log_step(it + 1, metrics)
@@ -554,4 +580,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

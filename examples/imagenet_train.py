@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])  # repo root
@@ -36,6 +36,7 @@ sys.path.insert(0, __file__.rsplit("/", 2)[0])  # repo root
 from rocm_apex_tpu import amp, models
 from rocm_apex_tpu.optimizers import FusedSGD
 from rocm_apex_tpu.parallel import sync_gradients
+from rocm_apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -155,7 +156,7 @@ def build_training(
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P("data"), P("data")),
         out_specs=(P(), P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(step), (params, batch_stats, opt_state, scaler_state)
 
@@ -238,4 +239,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,12 +16,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
 from rocm_apex_tpu.parallel import sync_gradients
+from rocm_apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -47,7 +48,7 @@ def main():
             local_step, mesh=mesh,
             in_specs=(P(), P(), P("data"), P("data")),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -63,4 +64,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

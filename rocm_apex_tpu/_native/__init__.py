@@ -3,16 +3,21 @@
 The analogue of importing the reference's compiled extensions with
 python fallbacks on failure (reference: apex/parallel/distributed.py:
 13-33 imports apex_C.flatten and falls back to torch._utils). The
-shared library is built on first import with g++ (cached next to the
-source); any failure leaves the numpy fallbacks active and
-``available = False`` (the multi_tensor_applier.available pattern,
+shared library is built on first use with g++ into a file named by the
+hash of the source, so a copied or checked-out tree (whose mtimes mean
+nothing) can never load a binary built from other source. A failed
+build warns once, with the compiler's output, and leaves the numpy
+fallbacks active with ``available = False`` (the
+multi_tensor_applier.available pattern,
 apex/multi_tensor_apply/multi_tensor_apply.py:3-30).
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
@@ -25,7 +30,6 @@ __all__ = [
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "..", "..", "csrc", "host_ops.cpp")
-_SO = os.path.join(_HERE, "_host_ops.so")
 _lib = None
 _lock = threading.Lock()
 available = False
@@ -37,19 +41,21 @@ def _build_and_load():
         if _lib is not None:
             return _lib
         try:
-            if (not os.path.exists(_SO)) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-            ):
+            with open(_SRC, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
+            so = os.path.join(_HERE, f"_host_ops.{digest}.so")
+            if not os.path.exists(so):
+                tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(
                     [
                         "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                        "-pthread", _SRC, "-o", _SO,
+                        "-pthread", _SRC, "-o", tmp,
                     ],
                     check=True,
                     capture_output=True,
                 )
-            lib = ctypes.CDLL(_SO)
+                os.replace(tmp, so)
+            lib = ctypes.CDLL(so)
             lib.apex_tpu_flatten.argtypes = [
                 ctypes.POINTER(ctypes.c_void_p),
                 ctypes.POINTER(ctypes.c_int64),
@@ -70,9 +76,17 @@ def _build_and_load():
             ]
             _lib = lib
             available = True
-        except Exception:
-            _lib = False  # build failed: numpy fallbacks stay active
+        except (OSError, subprocess.CalledProcessError) as e:
+            _lib = False  # numpy fallbacks stay active
             available = False
+            detail = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                "rocm_apex_tpu._native: building csrc/host_ops.cpp failed "
+                f"({e!r}); flatten/unflatten/fast_collate run in numpy. "
+                + detail.decode(errors="replace")[-2000:],
+                RuntimeWarning,
+                stacklevel=3,
+            )
     return _lib
 
 

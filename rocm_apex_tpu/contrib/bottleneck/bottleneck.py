@@ -19,9 +19,9 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.parallel import SyncBatchNorm
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["Bottleneck", "SpatialBottleneck", "halo_exchange"]
 

@@ -87,6 +87,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 import numpy as np
 import optax
 
@@ -101,7 +102,6 @@ from rocm_apex_tpu.ops.quantized_collectives import (
 )
 from rocm_apex_tpu.optimizers import _common as c
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = [
     "distributed_fused_adam",

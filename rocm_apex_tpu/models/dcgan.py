@@ -1,7 +1,7 @@
 """DCGAN generator/discriminator, TPU-native (NHWC).
 
 The reference ships DCGAN as an amp example and the SyncBatchNorm
-showcase (reference: examples/dcgan/main_amp.py; BASELINE.md config 3
+showcase (reference: examples/dcgan/main_amp.py; BASELINE.json config 3
 "DCGAN with SyncBatchNorm allreduce over ICI"). Standard DCGAN
 topology: transposed-conv generator, strided-conv discriminator,
 BatchNorm (optionally cross-replica) everywhere but the G output / D

@@ -79,8 +79,7 @@ class FoldedConvBN(nn.Module):
     the conv output is never written out for the stats read or the
     normalize read. G costs one small (Cin, Cin) MXU matmul over data
     the conv reads anyway. Measured 3.9× on the isolated stage-2
-    downsample chain (0.689 → 0.175 ms, BASELINE.md round-5 RN50
-    section); this is the graph-level version of the write-once
+    downsample chain (0.689 → 0.175 ms); this is the graph-level version of the write-once
     bottleneck structure the round-4 Pallas tap kernels could not win
     at the conv itself. Eval mode is the classic inference BN fold of
     the running statistics. Running stats update exactly as
@@ -193,7 +192,6 @@ class BasicBlock(nn.Module):
                 # OPT-IN: wins forward-only inference (3.9x isolated);
                 # the TRAIN step loses ~3 ms net to the fold backward
                 # (xs read twice more + strided-slice materialization)
-                # — BASELINE.md round-5 RN50 section has the numbers
                 residual = FoldedConvBN(
                     self.filters, self.strides, dtype=self.dtype,
                     name="downsample_fold",
@@ -242,7 +240,7 @@ class Bottleneck(nn.Module):
         if residual.shape != y.shape:
             if self.fold_downsample and _is_plain_bn(self.norm):
                 # no-ReLU edge: conv + BN in one pass over the input
-                # (opt-in; see BasicBlock note and BASELINE.md)
+                # (opt-in; see BasicBlock note)
                 residual = FoldedConvBN(
                     self.filters * self.expansion, self.strides,
                     dtype=self.dtype, name="downsample_fold",
@@ -280,8 +278,7 @@ class ResNet(nn.Module):
     sync_bn_axis: Optional[str] = None
     fused: bool = False
     # opt-in projection-shortcut fold (FoldedConvBN): a win for
-    # forward-only inference, a net loss for the train step —
-    # BASELINE.md round-5 RN50 section has the measurements
+    # forward-only inference, a net loss for the train step
     fold_downsample: bool = False
 
     @nn.compact

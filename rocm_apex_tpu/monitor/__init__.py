@@ -69,6 +69,8 @@ from rocm_apex_tpu.monitor.audit import (
     audit_jaxpr,
 )
 from rocm_apex_tpu.monitor.flops import (
+    UnknownDeviceError,
+    chip_peaks,
     mfu,
     model_flops,
     peak_flops_per_chip,
@@ -144,6 +146,8 @@ __all__ = [
     "transformer_train_flops",
     "resnet50_train_flops",
     "peak_flops_per_chip",
+    "chip_peaks",
+    "UnknownDeviceError",
     "mfu",
     "AuditReport",
     "audit",

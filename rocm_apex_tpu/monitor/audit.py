@@ -70,7 +70,7 @@ from typing import Any, Dict, FrozenSet, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 __all__ = ["AuditReport", "audit", "audit_jaxpr", "assert_no_intermediate"]
 

@@ -3,8 +3,7 @@
 bench.py grew three hand-computed copies of the Megatron-style
 train-step FLOPs formula (Narayanan et al. 2021 eq. 3; PaLM appendix B
 counts the logit layer the same way) — one each for the GPT and BERT
-benches plus the RN50 per-image constant — and BASELINE.md documents
-the crediting subtleties next to none of them. This module is the one
+benches plus the RN50 per-image constant. This module is the one
 copy everything routes through: the driver benches, the example train
 loops' MFU line, and any `MetricsLogger` configured with
 ``flops_per_step``.
@@ -17,16 +16,18 @@ The transformer formula, per train step (fwd + bwd ≈ 3x fwd):
   + 6·B·s·h·V                    the LM-head projection trio on the
                                  tied table (fwd + dW + dx) — real
                                  dense MXU work, credited explicitly
-                                 (BASELINE.md "MFU crediting")
 
 ``n_params`` is the non-embedding count: subtract ``V·h`` (the tied
 table) from the raw leaf count, which is what `transformer_train_flops`
 does when handed ``raw_param_count``.
 """
 
-from typing import Optional
+from typing import Optional, Tuple
 
 __all__ = [
+    "CHIP_PEAKS",
+    "UnknownDeviceError",
+    "chip_peaks",
     "peak_flops_per_chip",
     "transformer_train_flops",
     "model_flops",
@@ -34,33 +35,49 @@ __all__ = [
     "mfu",
 ]
 
-# bf16 peak FLOP/s per chip kind substring. The same table feeds the
-# profiler's roofline column (profiler._CHIP_PEAKS carries these plus
-# HBM bandwidth); kept in value-sync by test_monitor.py.
-_PEAKS = {
-    "v6e": 918e12,
-    "v6": 918e12,
-    "v5p": 459e12,
-    "v5 lite": 197e12,
-    "v5e": 197e12,
-    "v5": 459e12,
-    "v4": 275e12,
+# The one peaks table: (bf16 FLOP/s, HBM bytes/s) per chip, keyed by a
+# substring of ``device_kind``, first match wins (so "v5 lite"/"v5e"
+# sit ahead of "v5"). `profiler.op_stats` reads its roofline column
+# from the same rows. Source: Google Cloud TPU documentation, the
+# per-generation system-architecture pages (v5e: 197 TFLOP/s bf16,
+# 819 GB/s HBM).
+CHIP_PEAKS = {
+    "v6": (918e12, 1640e9),
+    "v5p": (459e12, 2765e9),
+    "v5 lite": (197e12, 819e9),
+    "v5e": (197e12, 819e9),
+    "v5": (459e12, 2765e9),
+    "v4": (275e12, 1228e9),
 }
 
 
-def peak_flops_per_chip(device_kind: Optional[str] = None) -> float:
-    """Best-effort bf16 peak for ``device_kind`` (default: the local
-    chip). Unknown kinds (CPU CI) get a nominal 1e12 so MFU-shaped
-    arithmetic stays finite without claiming a real roofline."""
+class UnknownDeviceError(LookupError):
+    """``device_kind`` has no row in `CHIP_PEAKS`: no utilization or
+    roofline figure may be computed for it."""
+
+
+def chip_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
+    """``(bf16 FLOP/s, HBM bytes/s)`` for ``device_kind`` (default: the
+    local device). A device that is not in the table raises
+    `UnknownDeviceError`: a CPU run has no peak to be measured against."""
     if device_kind is None:
         import jax
 
-        device_kind = getattr(jax.devices()[0], "device_kind", "cpu")
-    device_kind = device_kind.lower()
-    for key, peak in _PEAKS.items():
-        if key in device_kind:
-            return peak
-    return 1e12
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower()
+    for key, peaks in CHIP_PEAKS.items():
+        if key in kind:
+            return peaks
+    raise UnknownDeviceError(
+        f"no peak FLOP/s or bandwidth known for device kind "
+        f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+    )
+
+
+def peak_flops_per_chip(device_kind: Optional[str] = None) -> float:
+    """bf16 peak FLOP/s of ``device_kind`` (default: the local device);
+    raises `UnknownDeviceError` for a device outside `CHIP_PEAKS`."""
+    return chip_peaks(device_kind)[0]
 
 
 def transformer_train_flops(
@@ -78,8 +95,8 @@ def transformer_train_flops(
 
     Pass EITHER ``n_params`` (non-embedding) or ``raw_param_count``
     (every leaf; the tied ``V·h`` table is subtracted here).
-    ``include_head=False`` drops the 6·B·s·h·V logit-trio term — the
-    round-3 "sans-head" crediting that BASELINE.md records alongside.
+    ``include_head=False`` drops the 6·B·s·h·V logit-trio term (the
+    "sans-head" crediting).
     """
     if (n_params is None) == (raw_param_count is None):
         raise ValueError(

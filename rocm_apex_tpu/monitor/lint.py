@@ -61,7 +61,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from rocm_apex_tpu.monitor.audit import (
     _ALIASES,

@@ -1,14 +1,16 @@
-"""Shared Pallas plumbing: backend detection + interpret-mode fallback.
+"""Shared Pallas plumbing: backend detection + interpret mode for the
+CPU suite.
 
 Every kernel in rocm_apex_tpu/ops is written for TPU (Mosaic) but must
 also run under the CPU test harness (tests/conftest.py simulates an
-8-device mesh on CPU). `pallas_call` here transparently switches to the
-Pallas interpreter off-TPU — the analogue of the reference's pure-python
+8-device mesh on CPU). `pallas_call` here switches to the Pallas
+interpreter off-TPU — the analogue of the reference's pure-python
 fallbacks selected on failed extension import
 (reference: apex/parallel/__init__.py:14-19, apex/amp/scaler.py:6-40).
+Interpret mode is for that suite only: nothing on the chip path may
+reach it, and `chip_smoke.py` fails unless the compiled train step and
+serving programs hold their `tpu_custom_call`s.
 """
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +33,9 @@ SUBLANE = 8
 LANE = 128
 
 
-@functools.cache
 def on_tpu() -> bool:
+    # asked each time: a cached answer taken before the platform was
+    # settled would stay wrong for the life of the process
     return jax.default_backend() == "tpu"
 
 

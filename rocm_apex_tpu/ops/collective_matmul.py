@@ -59,13 +59,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.ops.quantized_collectives import (
     check_comm_dtype,
     dequantize_int8,
     quantize_int8,
 )
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["all_gather_matmul", "matmul_reduce_scatter"]
 

@@ -8,7 +8,7 @@ apex/contrib/csrc/bottleneck/bottleneck.cpp). The reason the kernels
 exist is identical on both architectures: training-mode BatchNorm
 otherwise forces each feature map through conv-write -> normalize-read
 -> normalized-write -> conv-read, and the framework's own RN50 roofline
-(BASELINE.md) shows XLA cannot fold the normalize into the *consuming*
+shows XLA cannot fold the normalize into the *consuming*
 conv's prologue — the step is pinned at ~93-97% of HBM peak moving
 ~36 GB. These kernels restore the once-in-once-out structure:
 
@@ -50,6 +50,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from rocm_apex_tpu.ops._pallas import pallas_call
 
@@ -78,9 +79,7 @@ config = {
 def _compiler_params():
     if config["vmem_limit"] is None:
         return None
-    from rocm_apex_tpu.utils.compat import tpu_compiler_params
-
-    return tpu_compiler_params(vmem_limit_bytes=config["vmem_limit"])
+    return pltpu.CompilerParams(vmem_limit_bytes=config["vmem_limit"])
 
 
 def _row_block(m: int, k: int, n: int, itemsize: int = 2,

@@ -356,12 +356,10 @@ linear_cross_entropy_mean.defvjp(_mean_vjp_fwd, _mean_vjp_bwd)
 
 
 def _vp_fwd_impl(hidden2d, weight, labels, axis_name, smoothing, chunk_size):
-    from rocm_apex_tpu.utils.compat import axis_size
-
     rows, _ = hidden2d.shape
     w = weight.astype(hidden2d.dtype)
     v_local = w.shape[0]
-    tp = axis_size(axis_name)
+    tp = jax.lax.axis_size(axis_name)
     vocab = v_local * tp
     start = jax.lax.axis_index(axis_name) * v_local
     chunk = _chunk_rows(rows, v_local, chunk_size)
@@ -396,13 +394,11 @@ def _vp_fwd_impl(hidden2d, weight, labels, axis_name, smoothing, chunk_size):
 
 def _vp_bwd_impl(hidden2d, weight, labels, lse, dloss, axis_name, smoothing,
                  chunk_size):
-    from rocm_apex_tpu.utils.compat import axis_size
-
     rows, hdim = hidden2d.shape
     cdt = hidden2d.dtype
     w = weight.astype(cdt)
     v_local = w.shape[0]
-    vocab = v_local * axis_size(axis_name)
+    vocab = v_local * jax.lax.axis_size(axis_name)
     start = jax.lax.axis_index(axis_name) * v_local
     chunk = _chunk_rows(rows, v_local, chunk_size)
     xs, ls, lses, dls = _to_chunks(

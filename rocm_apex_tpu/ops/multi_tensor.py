@@ -64,9 +64,26 @@ def _smem_scalar_spec():
     return pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
 
 
-def _flag_out_spec():
-    return pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM)
+# One non-finite flag per grid block. Mosaic blocks an output only in
+# whole (8, 128) tiles — a (1, 1) SMEM block over a (grid, 1) array is
+# refused as soon as grid > 1 — so each block broadcasts its flag over
+# one int32 tile (4 KiB beside the 256 KiB block it read).
+_FLAG_TILE = (8, 128)
 
+
+def _flag_out_spec():
+    return pl.BlockSpec(_FLAG_TILE, lambda i: (i, 0))
+
+
+def _flag_out_shape(grid: int):
+    return jax.ShapeDtypeStruct(
+        (grid * _FLAG_TILE[0], _FLAG_TILE[1]), jnp.int32
+    )
+
+
+def _nonfinite_tile(x):
+    bad = jnp.logical_not(jnp.isfinite(x).all()).astype(jnp.int32)
+    return jnp.full(_FLAG_TILE, bad)
 
 
 
@@ -77,7 +94,7 @@ def _flag_out_spec():
 
 def _scale_kernel(x_ref, s_ref, out_ref, flag_ref):
     x = x_ref[...].astype(jnp.float32) * s_ref[0, 0]
-    flag_ref[0, 0] = jnp.logical_not(jnp.isfinite(x).all()).astype(jnp.int32)
+    flag_ref[...] = _nonfinite_tile(x)
     out_ref[...] = x.astype(out_ref.dtype)
 
 
@@ -92,7 +109,7 @@ def _scale_buffer(buf, s, out_dtype):
         # per-block slicing on the CPU harness
         o, f = DirectOutRef(kd_out), DirectOutRef(jnp.int32)
         _scale_kernel(DirectRef(buf), DirectRef(s), o, f)
-        return o.value.astype(out_dtype), f.value > 0
+        return o.value.astype(out_dtype), (f.value > 0).any()
     out, flags = pallas_call(
         _scale_kernel,
         grid=(grid,),
@@ -100,10 +117,10 @@ def _scale_buffer(buf, s, out_dtype):
         out_specs=[_vmem_spec(), _flag_out_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((rows, WIDTH), kd_out),
-            jax.ShapeDtypeStruct((grid, 1), jnp.int32),
+            _flag_out_shape(grid),
         ],
     )(buf, s)
-    return out.astype(out_dtype), flags.sum() > 0
+    return out.astype(out_dtype), (flags > 0).any()
 
 
 def scale_packed(
@@ -143,7 +160,7 @@ def scale(tree: Any, scale_val, out_dtype=None) -> Tuple[Any, jnp.ndarray]:
 
 def _scale_sumsq_kernel(x_ref, s_ref, out_ref, flag_ref, rsq_ref):
     x = x_ref[...].astype(jnp.float32) * s_ref[0, 0]
-    flag_ref[0, 0] = jnp.logical_not(jnp.isfinite(x).all()).astype(jnp.int32)
+    flag_ref[...] = _nonfinite_tile(x)
     out_ref[...] = x.astype(out_ref.dtype)
     rsq_ref[...] = jnp.sum(x * x, axis=1, keepdims=True)
 
@@ -158,7 +175,7 @@ def _scale_sumsq_buffer(buf, s, out_dtype):
         f = DirectOutRef(jnp.int32)
         r = DirectOutRef(jnp.float32)
         _scale_sumsq_kernel(DirectRef(buf), DirectRef(s), o, f, r)
-        return o.value.astype(out_dtype), f.value > 0, r.value
+        return o.value.astype(out_dtype), (f.value > 0).any(), r.value
     out, flags, rsq = pallas_call(
         _scale_sumsq_kernel,
         grid=(grid,),
@@ -170,11 +187,11 @@ def _scale_sumsq_buffer(buf, s, out_dtype):
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, WIDTH), kd_out),
-            jax.ShapeDtypeStruct((grid, 1), jnp.int32),
+            _flag_out_shape(grid),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
     )(buf, s)
-    return out.astype(out_dtype), flags.sum() > 0, rsq
+    return out.astype(out_dtype), (flags > 0).any(), rsq
 
 
 def scale_sumsq_packed(
@@ -217,7 +234,7 @@ def _axpby_kernel(x_ref, y_ref, a_ref, b_ref, out_ref, flag_ref):
         x_ref[...].astype(jnp.float32) * a_ref[0, 0]
         + y_ref[...].astype(jnp.float32) * b_ref[0, 0]
     )
-    flag_ref[0, 0] = jnp.logical_not(jnp.isfinite(out).all()).astype(jnp.int32)
+    flag_ref[...] = _nonfinite_tile(out)
     out_ref[...] = out.astype(out_ref.dtype)
 
 
@@ -251,7 +268,7 @@ def axpby_packed(
                 o, f,
             )
             outs.append(o.value.astype(od))
-            infs.append(f.value > 0)
+            infs.append((f.value > 0).any())
             continue
         out, flags = pallas_call(
             _axpby_kernel,
@@ -265,11 +282,11 @@ def axpby_packed(
             out_specs=[_vmem_spec(), _flag_out_spec()],
             out_shape=[
                 jax.ShapeDtypeStruct((rows, WIDTH), kd_out),
-                jax.ShapeDtypeStruct((grid, 1), jnp.int32),
+                _flag_out_shape(grid),
             ],
         )(xb, yb, a, b)
         outs.append(out.astype(od))
-        infs.append(flags.sum() > 0)
+        infs.append((flags > 0).any())
     found_inf = jnp.stack(infs).any() if infs else jnp.asarray(False)
     return PackedTree(outs, respec(x.spec, out_dtype)), found_inf
 

@@ -59,8 +59,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from rocm_apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 __all__ = [
     "COMM_DTYPES",

@@ -34,10 +34,10 @@ from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 import numpy as np
 
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = [
     "sync_gradients",

@@ -29,9 +29,9 @@ from typing import Optional, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["SyncBatchNorm", "convert_syncbn_model"]
 

@@ -17,10 +17,10 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.amp.scaler import LossScaler, ScalerState
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["GradScaler", "sync_found_inf"]
 

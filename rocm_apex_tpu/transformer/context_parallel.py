@@ -28,10 +28,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.ops.flash_attention import flash_attention_with_lse
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["ring_flash_attention", "ulysses_attention"]
 

@@ -24,10 +24,10 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 import numpy as np
 
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["SwitchMLP", "switch_route", "load_balancing_loss"]
 

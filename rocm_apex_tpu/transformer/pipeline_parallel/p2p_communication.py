@@ -28,9 +28,9 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = [
     "send_forward",

@@ -70,9 +70,9 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import core as _jax_core
+from jax.lax import axis_size
 
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size, pcast_varying
 
 
 def _start_timer(timers, forward_only, tracer=None, microbatches=0):
@@ -126,11 +126,11 @@ def _replicate_masked(x, maskf, axis):
     out = psum(where(maskf, x, 0)).
 
     Explicit VJP because the raw psum's transpose depends on shard_map
-    replication tracking: with check_rep=False it degenerates to a psum
+    replication tracking: with check_vma=False it degenerates to a psum
     of cotangents and every gradient through the loss replication comes
     back axis-size times too large. The true transpose of "replicate
     from the masked rank" keeps the cotangent only where the mask is
-    set — correct under either check_rep setting.
+    set — correct under either check_vma setting.
 
     Masking is a select, not a multiply: non-exit ranks run the head on
     zero activation buffers, and a NaN/Inf produced there would survive
@@ -162,10 +162,8 @@ def _pcast_varying(x, axis):
     Idempotent, and — unlike a raw `pcast(to='varying')`, whose
     transpose is a psum over the axis — the add's transpose passes the
     cotangent through per-rank, so no hidden collective appears in the
-    backward (the schedules do their cross-stage grad sums explicitly).
-    (compat.pcast_varying is identity on jax without the replication
-    type system, where nothing needs marking.)"""
-    z = pcast_varying(jnp.zeros((), jnp.result_type(x)), axis)
+    backward (the schedules do their cross-stage grad sums explicitly)."""
+    z = jax.lax.pcast(jnp.zeros((), jnp.result_type(x)), (axis,), to="varying")
     return x + z
 
 
@@ -196,9 +194,9 @@ def _head_losses(loss_fn, has_extra, extra, y_buf, targets, axis, is_last):
 
     NOTE: the predicate VARIES over the pipe axis, so this `cond` (and
     the per-tick head in `_one_pass_interleaved`) is only legal under
-    `shard_map(..., check_rep=False)` — every current caller. A future
+    `shard_map(..., check_vma=False)` — every current caller. A future
     caller with replication checking enabled would see this rejected;
-    it would need `check_rep=False` or a select-based head."""
+    it would need `check_vma=False` or a select-based head."""
 
     def one(y, t):
         loss = loss_fn(extra, y, t) if has_extra else loss_fn(y, t)
@@ -434,8 +432,12 @@ def forward_backward_pipelining_without_interleaving(
                 sent = jax.lax.ppermute(y, axis, perm)
             return (sent, y_buf), None
 
-        act0 = pcast_varying(jnp.zeros(a0.shape, a0.dtype), axis)
-        ybuf0 = pcast_varying(jnp.zeros((m,) + a0.shape, a0.dtype), axis)
+        act0 = jax.lax.pcast(
+            jnp.zeros(a0.shape, a0.dtype), (axis,), to="varying"
+        )
+        ybuf0 = jax.lax.pcast(
+            jnp.zeros((m,) + a0.shape, a0.dtype), (axis,), to="varying"
+        )
         (_, y_buf), _ = jax.lax.scan(tick, (act0, ybuf0), jnp.arange(ticks))
         # post_process on the last stage, once per microbatch
         loss_buf = _head_losses(
@@ -790,8 +792,12 @@ def forward_backward_pipelining_with_interleaving(
                 sent = jax.lax.ppermute(y, axis, ring)
             return (sent, y_buf), None
 
-        act0 = pcast_varying(jnp.zeros(a0.shape, a0.dtype), axis)
-        ybuf0 = pcast_varying(jnp.zeros((m,) + a0.shape, a0.dtype), axis)
+        act0 = jax.lax.pcast(
+            jnp.zeros(a0.shape, a0.dtype), (axis,), to="varying"
+        )
+        ybuf0 = jax.lax.pcast(
+            jnp.zeros((m,) + a0.shape, a0.dtype), (axis,), to="varying"
+        )
         (_, y_buf), _ = jax.lax.scan(tick, (act0, ybuf0), jnp.arange(ticks))
         loss_buf = _head_losses(
             loss_fn, has_extra, extra, y_buf, targets, axis, is_last

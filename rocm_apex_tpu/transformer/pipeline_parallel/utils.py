@@ -13,12 +13,12 @@ from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.transformer import parallel_state
 from rocm_apex_tpu.transformer.pipeline_parallel.microbatches import (
     build_num_microbatches_calculator,
 )
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = [
     "setup_microbatch_calculator",

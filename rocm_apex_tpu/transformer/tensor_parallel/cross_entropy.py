@@ -15,10 +15,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.transformer import parallel_state
 from rocm_apex_tpu.transformer.utils import VocabUtility
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = ["vocab_parallel_cross_entropy"]
 

@@ -19,9 +19,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from rocm_apex_tpu.transformer import parallel_state
-from rocm_apex_tpu.utils.compat import axis_size
 
 __all__ = [
     "copy_to_tensor_model_parallel_region",

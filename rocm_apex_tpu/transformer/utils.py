@@ -10,7 +10,7 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from rocm_apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 __all__ = [
     "ensure_divisibility",

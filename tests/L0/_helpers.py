@@ -2,7 +2,16 @@
 sys.path, so plain `from _helpers import ...` works without a package)."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+
+
+# Page geometry for the paged-cache tests. The CPU suite uses 4-row
+# pages. On a chip (APEX_TPU_TEST_PLATFORM=tpu) the paged kernels DMA
+# (page, head) tiles, so a page is the pool dtype's sublane tile — 8
+# rows for fp32, 32 for int8 — and the engine refuses anything else.
+ON_CHIP = jax.default_backend() == "tpu"
+PAGE = 8 if ON_CHIP else 4
+PAGE_I8 = 32 if ON_CHIP else 4
 
 
 def jit_shmap(*args, **kwargs):

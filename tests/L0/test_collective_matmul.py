@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap
 
@@ -105,7 +105,7 @@ class TestAllGatherMatmul:
             both, mesh=mesh,
             in_specs=(P("tensor"), P("tensor")),
             out_specs=(P(), P("tensor"), P("tensor")) * 2,
-            check_rep=False,
+            check_vma=False,
         )
         l1, dx1, dw1, l2, dx2, dw2 = f(x, w)
         np.testing.assert_allclose(
@@ -130,7 +130,7 @@ class TestAllGatherMatmul:
 
         y = jit_shmap(
             f, mesh=mesh, in_specs=(P("tensor"), P("tensor")),
-            out_specs=P("tensor"), check_rep=False,
+            out_specs=P("tensor"), check_vma=False,
         )(x, w).reshape(tp, tp * ROWS, N)
         ref = jnp.stack([x @ w[r] for r in range(tp)])
         np.testing.assert_allclose(
@@ -156,7 +156,7 @@ class TestAllGatherMatmul:
 
         ring, plain = jit_shmap(
             f, mesh=mesh, in_specs=(P("tensor"), P("tensor")),
-            out_specs=(P("tensor"), P("tensor")), check_rep=False,
+            out_specs=(P("tensor"), P("tensor")), check_vma=False,
         )(x, w)
         assert ring.dtype == jnp.bfloat16
         ref = jnp.matmul(
@@ -219,7 +219,7 @@ class TestMatmulReduceScatter:
             both, mesh=mesh,
             in_specs=(P(None, "tensor"), P("tensor"), P("tensor")),
             out_specs=(P(), P(None, "tensor"), P("tensor")) * 2,
-            check_rep=False,
+            check_vma=False,
         )
         l1, dx1, dw1, l2, dx2, dw2 = f(x, w, dl)
         np.testing.assert_allclose(
@@ -253,7 +253,7 @@ class TestMatmulReduceScatter:
                 mesh=mesh,
                 in_specs=(P(None, "tensor"), P("tensor")),
                 out_specs=P("tensor"),
-                check_rep=False,
+                check_vma=False,
             )
             y = f(x, w)
             np.testing.assert_allclose(
@@ -278,7 +278,7 @@ class TestMatmulReduceScatter:
             mesh=mesh,
             in_specs=(P(None, "tensor"), P("tensor")),
             out_specs=P("tensor"),
-            check_rep=False,
+            check_vma=False,
         )(x, w)
         assert y.dtype == jnp.bfloat16
         ref = x.astype(jnp.float32) @ w.astype(jnp.float32)
@@ -296,7 +296,7 @@ class TestMatmulReduceScatter:
             jit_shmap(
                 lambda xc, wc: matmul_reduce_scatter(xc, wc, "tensor"),
                 mesh=mesh, in_specs=(P(), P()), out_specs=P("tensor"),
-                check_rep=False,
+                check_vma=False,
             )(x, w)
 
 
@@ -373,7 +373,7 @@ class TestPipelineExitStage:
 
         l_sp, l_plain = jit_shmap(
             both, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(hidden, labels)
         np.testing.assert_allclose(
             float(l_sp), float(l_plain), rtol=1e-6
@@ -418,7 +418,7 @@ class TestNoGatheredActivationInJaxpr:
 
         f = shard_map(
             step, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return monitor.LintSubject.from_fn(
             f"cm_stack_cm{int(collective_matmul)}_chunk{chunk}", f, x_loc
@@ -488,7 +488,7 @@ class TestNoGatheredActivationInJaxpr:
 
         f = shard_map(
             step, mesh=mesh, in_specs=(P(),), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         report = audit(f, x_loc)
         assert report.has_intermediate((self.B, self.S, self.H))

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap as _jit_shmap
 from jax.sharding import Mesh, PartitionSpec as P
@@ -51,7 +51,7 @@ class TestRingAttention:
             mesh=mesh,
             in_specs=(P(None, "context"),) * 3,
             out_specs=P(None, "context"),
-            check_rep=False,
+            check_vma=False,
         )
         got = ring(q, k, v)
         want = flash_attention(q, k, v, None, causal)
@@ -70,7 +70,7 @@ class TestRingAttention:
                 mesh=mesh,
                 in_specs=(P(None, "context"),) * 3,
                 out_specs=P(None, "context"),
-                check_rep=False,
+                check_vma=False,
             )
             return jnp.sum(f(q, k, v).astype(jnp.float32) ** 2)
 
@@ -102,7 +102,7 @@ class TestUlyssesAttention:
             mesh=mesh,
             in_specs=(P(None, "context"),) * 3,
             out_specs=P(None, "context"),
-            check_rep=False,
+            check_vma=False,
         )
         got = uly(q, k, v)
 
@@ -127,7 +127,7 @@ class TestUlyssesAttention:
                 mesh=mesh,
                 in_specs=(P(None, "context"),),
                 out_specs=P(None, "context"),
-                check_rep=False,
+                check_vma=False,
             )(q)
 
 
@@ -167,7 +167,7 @@ class TestGPTContextParallel:
             mesh=mesh,
             in_specs=(P(), P(None, "context")),
             out_specs=P(None, "context"),
-            check_rep=False,
+            check_vma=False,
         )
         got = f(params, tokens)
         np.testing.assert_allclose(

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap as _jit_shmap
 from jax.sharding import Mesh, PartitionSpec as P
@@ -95,7 +95,7 @@ class TestGroupBN:
 
         f = _jit_shmap(
             local, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
         y = np.asarray(f(x))
         # normalized within groups: each group's output is ~zero-mean
@@ -319,7 +319,7 @@ class TestBottleneck:
             local, mesh=mesh,
             in_specs=(P(None, "spatial"),),
             out_specs=P(None, "spatial"),
-            check_rep=False,
+            check_vma=False,
         )
         y_spatial = f(x)
         np.testing.assert_allclose(

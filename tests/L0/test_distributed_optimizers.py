@@ -13,7 +13,7 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap as _jit_shmap
 
@@ -75,7 +75,7 @@ def run_sharded(tx, params, stacked_grads, mesh, steps=3):
         mesh=mesh,
         in_specs=(P(), P("data")),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(f)(params, stacked_grads)
 

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.experimental.shard_map import shard_map
+from _helpers import assert_close
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -28,6 +29,12 @@ from rocm_apex_tpu.ops.linear_xentropy import (
 # remainder-bearing shapes: 37 rows over chunk 8 leaves a 5-row tail
 N, H, V = 37, 16, 50
 CHUNK = 8
+
+# On a chip (APEX_TPU_TEST_PLATFORM=tpu) the chunked head and the naive
+# reference sum their fp32 matmul passes in different orders: measured
+# max absolute gradient difference 5.0e-6 on O(0.1) values. CPU bounds
+# are untouched.
+CHIP_TOL = dict(tpu_rtol=1e-4, tpu_atol=2e-5)
 
 
 def _data(seed=0, n=N, v=V, dtype=jnp.float32):
@@ -79,11 +86,11 @@ class TestSerialPerRow:
             lambda x, w: jnp.sum(_naive_losses(x, w, y, smoothing) * dl),
             (0, 1),
         )(x, w)
-        np.testing.assert_allclose(
-            np.asarray(gx), np.asarray(rx), rtol=1e-5, atol=1e-6
+        assert_close(
+            np.asarray(gx), np.asarray(rx), rtol=1e-5, atol=1e-6, **CHIP_TOL
         )
-        np.testing.assert_allclose(
-            np.asarray(gw), np.asarray(rw), rtol=1e-5, atol=1e-6
+        assert_close(
+            np.asarray(gw), np.asarray(rw), rtol=1e-5, atol=1e-6, **CHIP_TOL
         )
 
     def test_ignore_index_rows_zero_loss_and_grad(self):
@@ -111,11 +118,11 @@ class TestSerialPerRow:
         )(x, w)
         # masked rows carry exactly zero hidden gradient
         np.testing.assert_array_equal(np.asarray(gx)[masked], 0.0)
-        np.testing.assert_allclose(
-            np.asarray(gx), np.asarray(rx), rtol=1e-5, atol=1e-6
+        assert_close(
+            np.asarray(gx), np.asarray(rx), rtol=1e-5, atol=1e-6, **CHIP_TOL
         )
-        np.testing.assert_allclose(
-            np.asarray(gw), np.asarray(rw), rtol=1e-5, atol=1e-6
+        assert_close(
+            np.asarray(gw), np.asarray(rw), rtol=1e-5, atol=1e-6, **CHIP_TOL
         )
 
     def test_leading_shape_and_default_chunk(self):
@@ -176,8 +183,9 @@ class TestMeanVariant:
         v2, g2 = jax.value_and_grad(fused, (0, 1))(x, w)
         np.testing.assert_allclose(float(v1), float(v2), rtol=1e-6)
         for a, b in zip(g1, g2):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
+            assert_close(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7,
+                **CHIP_TOL,
             )
 
     def test_no_mask_plain_mean_vs_naive(self):
@@ -250,7 +258,7 @@ class TestVocabParallel:
     def test_matches_naive_tp2(self, smoothing, pad):
         """Loss, dx, and the gathered dW shards match the serial naive
         reference; gradients taken INSIDE shard_map (the training
-        idiom of examples/gpt_train.py — with check_rep=False an
+        idiom of examples/gpt_train.py — with check_vma=False an
         outside-grad cotangent arrives scaled, like every other TP
         layer in this package)."""
         mesh = self._mesh()
@@ -274,7 +282,7 @@ class TestVocabParallel:
         f = jax.jit(
             shard_map(
                 inner, mesh=mesh, in_specs=(P(), P("tensor")),
-                out_specs=(P(), P(), P(), P("tensor")), check_rep=False,
+                out_specs=(P(), P(), P(), P("tensor")), check_vma=False,
             )
         )
         val, losses, gx, gw = f(x, w)
@@ -291,11 +299,11 @@ class TestVocabParallel:
         )
         np.testing.assert_allclose(float(val), float(jnp.sum(ref * dl)),
                                    rtol=1e-5)
-        np.testing.assert_allclose(
-            np.asarray(gx), np.asarray(rx), rtol=1e-5, atol=1e-6
+        assert_close(
+            np.asarray(gx), np.asarray(rx), rtol=1e-5, atol=1e-6, **CHIP_TOL
         )
-        np.testing.assert_allclose(
-            np.asarray(gw), np.asarray(rw), rtol=1e-5, atol=1e-6
+        assert_close(
+            np.asarray(gw), np.asarray(rw), rtol=1e-5, atol=1e-6, **CHIP_TOL
         )
 
 

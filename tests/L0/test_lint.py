@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from rocm_apex_tpu import monitor
@@ -101,7 +101,7 @@ class TestPrecisionPolicy:
     def test_fp64_caught_anywhere(self):
         """fp64 sneaking in (an un-dtyped np scalar, a python float
         under x64) is flagged regardless of scope or policy dtype."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
 
             def f(x):
                 return x.astype(jnp.float64) * 2.0
@@ -189,7 +189,7 @@ class TestCollectiveContract:
         mesh = _mesh(2)
         return shard_map(
             fn, mesh=mesh, in_specs=(P("tensor"),), out_specs=P("tensor"),
-            check_rep=False,
+            check_vma=False,
         )
 
     def test_count_and_forbid_mutations_caught(self):

@@ -88,7 +88,7 @@ class TestResNet:
         """RN18 forward under a data mesh with cross-replica BN stats
         (reference: SyncBN inside main_amp.py's DDP training)."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from rocm_apex_tpu.models import ResNet, BasicBlock
 
@@ -112,7 +112,7 @@ class TestResNet:
 
         f = jit_shmap(
             local, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
         y = f(x)
         assert y.shape == (4, 4)

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap as _jit_shmap
 from jax.sharding import Mesh, PartitionSpec as P
@@ -118,7 +118,7 @@ class TestSwitchMLP:
                 P(),
             ),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         y_ep, aux_ep = f_ep(sharded, x)
         np.testing.assert_allclose(

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from _helpers import jit_shmap
@@ -36,6 +36,8 @@ from rocm_apex_tpu.monitor import (
     mfu,
     model_flops,
     peak_flops_per_chip,
+    chip_peaks,
+    UnknownDeviceError,
     tree_norm,
 )
 from rocm_apex_tpu.optimizers.mixed import MixedPrecisionAdam
@@ -100,7 +102,7 @@ class TestMetrics:
 
         m = jit_shmap(
             f, mesh=mesh, in_specs=(P("tensor"),), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(x)
         assert float(m["total"]) == pytest.approx(float(jnp.sum(x)))
         assert float(m["norm"]) == pytest.approx(
@@ -356,14 +358,29 @@ class TestModelFlops:
         assert mfu(5e11, 1.0, peak=1e12, n_chips=2) == pytest.approx(0.25)
         assert mfu(1.0, 0.0, peak=1e12) == 0.0
         assert peak_flops_per_chip("TPU v5 litepod") == 197e12
-        assert peak_flops_per_chip("weird-chip") == 1e12
-        # value-sync with the profiler's roofline table
+        # a device outside the one table is an error, never a default
+        with pytest.raises(UnknownDeviceError):
+            peak_flops_per_chip("weird-chip")
+        # the profiler's roofline column reads the same table
         from rocm_apex_tpu import profiler
 
-        from rocm_apex_tpu.monitor.flops import _PEAKS
+        assert profiler.chip_peaks is chip_peaks
+        assert chip_peaks("tpu v5e") == (197e12, 819e9)
 
-        for kind, (pf, _) in profiler._CHIP_PEAKS.items():
-            assert _PEAKS.get(kind, pf) == pf
+    def test_logger_omits_mfu_on_unknown_device(self):
+        """The CPU the suite runs on is not in the peaks table: the
+        logger still reports step time and tokens/s but no MFU."""
+        buf = io.StringIO()
+        logger = MetricsLogger(
+            writers=[JsonlWriter(stream=buf)],
+            tokens_per_step=8, flops_per_step=1e9, memory_stats=False,
+        )
+        logger.start_step()
+        logger.end_step()
+        logger.log_step(0, {"loss": 1.0})
+        row = json.loads(buf.getvalue())
+        assert "tokens_per_sec" in row and "step_time_ms" in row
+        assert "mfu" not in row
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +406,7 @@ class TestAuditBasics:
 
         g = shard_map(
             f, mesh=mesh, in_specs=(P(),), out_specs=P("tensor"),
-            check_rep=False,
+            check_vma=False,
         )
         r = audit(g, jnp.ones((4, 4), jnp.float32))
         assert r.count("psum") == 5 and r.count("ppermute") == 5
@@ -451,7 +468,7 @@ class TestAuditBasics:
 
         g = shard_map(
             f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         r = audit(g, jnp.ones((4, 4), jnp.float32))
         # 5 runtime trips, ONE counted: exact per-body, a lower bound
@@ -547,21 +564,18 @@ class TestAuditWalkerCoverage:
     def test_closed_call(self):
         """`closed_call` carries its body as a ClosedJaxpr param value
         (not the Jaxpr the other call primitives use) — the walker must
-        unwrap it. jax 0.4 has no user-facing API that emits one, so
-        bind the primitive directly."""
-        from functools import partial
-
-        from jax import core as _core
+        unwrap it. No user-facing API emits one, so bind the primitive
+        directly."""
         from jax.extend import linear_util as lu
+        from jax.extend.core import jaxpr_as_fun, primitives
 
         closed = jax.make_jaxpr(lambda y: y @ y)(self.X)
 
         def g(x):
-            (out,) = _core.closed_call_p.bind(
+            (out,) = primitives.closed_call_p.bind(
                 lu.wrap_init(
-                    partial(
-                        _core.eval_jaxpr, closed.jaxpr, closed.consts
-                    )
+                    jaxpr_as_fun(closed),
+                    debug_info=closed.jaxpr.debug_info,
                 ),
                 x,
                 call_jaxpr=closed,
@@ -626,7 +640,7 @@ class TestAuditCollectiveMatmulStack:
 
         f = shard_map(
             step, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return monitor.LintSubject.from_fn(
             f"spcm_stack_cm{int(collective_matmul)}", f, x_loc
@@ -702,7 +716,7 @@ class TestAuditVocabParallelHead:
 
         g = shard_map(
             f, mesh=mesh, in_specs=(P(), P("tensor")),
-            out_specs=(P(), P("tensor")), check_rep=False,
+            out_specs=(P(), P("tensor")), check_vma=False,
         )
         r = assert_no_intermediate(audit(g, x, w), (n, v))
         assert r.count("pmax") > 0  # chunk-wise running max

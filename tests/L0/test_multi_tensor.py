@@ -107,6 +107,42 @@ class TestScale:
         np.testing.assert_allclose(scaled["a"], 2.0)
 
 
+class TestManyGridBlocks:
+    """A buffer that spans several 64-row grid blocks: every tree above
+    packs into one. On a chip this is where a per-block output whose
+    block shape Mosaic refuses shows up (a (1, 1) flag block over a
+    (grid, 1) array compiled at grid == 1 and was refused at the 134M
+    model's grid of 2066)."""
+
+    def _big(self, key):
+        return {"big": jax.random.normal(key, (70 * WIDTH + 5,))}
+
+    @pytest.mark.parametrize("bad_at", [None, 3, 69 * WIDTH])
+    def test_scale_axpby_and_sumsq(self, bad_at):
+        x = self._big(jax.random.PRNGKey(8))
+        y = self._big(jax.random.PRNGKey(9))
+        if bad_at is not None:
+            x["big"] = x["big"].at[bad_at].set(np.inf)
+        packed = pack_tree(x)
+        assert packed.buffers[0].shape[0] // multi_tensor.BLOCK_ROWS > 1
+
+        scaled, inf_s = multi_tensor.scale(x, 0.5)
+        summed, inf_a = multi_tensor.axpby(x, y, 2.0, -0.5)
+        out, inf_q, (rsq,) = multi_tensor.scale_sumsq_packed(packed, 0.5)
+        for flag in (inf_s, inf_a, inf_q):
+            assert bool(flag) == (bad_at is not None)
+        if bad_at is None:
+            np.testing.assert_allclose(scaled["big"], 0.5 * x["big"])
+            np.testing.assert_allclose(
+                summed["big"], 2.0 * x["big"] - 0.5 * y["big"], rtol=1e-6
+            )
+            np.testing.assert_allclose(
+                float(rsq.sum()),
+                float(jnp.sum((0.5 * x["big"]) ** 2)),
+                rtol=1e-5,
+            )
+
+
 class TestAxpby:
     def test_matches_reference(self):
         x = make_tree(jax.random.PRNGKey(4))

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from _helpers import assert_close
 
 from rocm_apex_tpu import monitor
 from rocm_apex_tpu.optimizers import fused_adam, fused_lamb
@@ -89,9 +90,16 @@ def run_stepped(opt, params, gsteps, steps, skips=None):
 
 
 def assert_tree_equal(a, b):
+    """Bitwise on the CPU suite. On a chip the packed Mosaic kernels and
+    the XLA-fused tree update round the same fp32 Adam math differently
+    in the last place (measured: max relative difference 2.9e-6 after 5
+    steps), so the on-chip sweep allows a few ulps."""
     for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
         assert x.dtype == y.dtype
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        assert_close(
+            np.asarray(x, np.float32), np.asarray(y, np.float32),
+            rtol=0.0, atol=0.0, tpu_rtol=1e-5, tpu_atol=1e-8,
+        )
 
 
 class TestAdamParity:
@@ -121,9 +129,8 @@ class TestAdamParity:
         # a no-decay run
         nodecay, _ = run_stepped(fused_adam(1e-3), params, gsteps, 3)
         assert not np.array_equal(np.asarray(got["w"]), np.asarray(nodecay["w"]))
-        np.testing.assert_array_equal(
-            np.asarray(got["b"]), np.asarray(nodecay["b"])
-        )
+        # packed (masked) against tree (no decay): see assert_tree_equal
+        assert_tree_equal(got["b"], nodecay["b"])
 
 
 class TestLambParity:

@@ -13,7 +13,7 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap as _jit_shmap
 
@@ -397,7 +397,7 @@ class TestReplicaConsistency:
             mesh=mesh,
             in_specs=(P(), P("data"), P("data")),
             out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
         stacked = jax.jit(f)(params0, xs, ys)
         for path, leaf in jax.tree_util.tree_leaves_with_path(stacked):

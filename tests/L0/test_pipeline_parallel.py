@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from rocm_apex_tpu.transformer.pipeline_parallel import (
     ConstantNumMicroBatches,
@@ -295,7 +295,7 @@ class TestP2P:
             mesh=mesh,
             in_specs=P("pipe"),
             out_specs=(P("pipe"), P("pipe")),
-            check_rep=False,
+            check_vma=False,
         )
         plain, sg = f(x)
         np.testing.assert_allclose(np.asarray(plain), np.asarray(sg), rtol=1e-6)
@@ -495,7 +495,7 @@ class TestPipelineWithEmbedding:
             return jnp.mean(_serial_cross_entropy(logits, tgt))
 
         mesh = pipe_mesh(eight_devices)
-        # check_rep=False is safe: the schedule's loss replication has
+        # check_vma=False is safe: the schedule's loss replication has
         # an explicit VJP (schedules._replicate_masked), so gradients do
         # not depend on shard_map's replication tracking
         f = shard_map(
@@ -506,7 +506,7 @@ class TestPipelineWithEmbedding:
             mesh=mesh,
             in_specs=(P("pipe"), P(), P(), P()),
             out_specs=(P(), (P("pipe"), P())),
-            check_rep=False,
+            check_vma=False,
         )
         losses, (lgrads, egrads) = jax.jit(f)(stacked, e_params, tokens, labels)
 
@@ -618,7 +618,7 @@ class TestPipelineWithEmbedding:
             local, mesh=mesh,
             in_specs=(P(None, "pipe"), P(), P(), P()),
             out_specs=(P(), (P(None, "pipe"), P())),
-            check_rep=False,
+            check_vma=False,
         )
         losses, (lgrads, egrads) = jax.jit(f)(chunked, e_params, tokens, labels)
 
@@ -758,7 +758,7 @@ class TestOnePass1F1BMemoryBound:
                 mesh=mesh,
                 in_specs=(P("pipe"), P(), P()),
                 out_specs=(P(), P("pipe")),
-                check_rep=False,
+                check_vma=False,
             )
             compiled = jax.jit(f).lower(params, x, t).compile()
             ma = compiled.memory_analysis()

@@ -48,7 +48,7 @@ def stacked_inputs(key, shape=(DP, ROWS, COLS)):
 def _run_ring(fn, x, mesh, out_specs=P("data")):
     return jit_shmap(
         fn, mesh=mesh, in_specs=(P("data"),), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(x)
 
 
@@ -204,9 +204,9 @@ class TestDegradation:
         assert np.array_equal(np.asarray(got), np.asarray(want))
         # and the degraded program contains NO ppermute
         rep = audit(
-            jax.experimental.shard_map.shard_map(
+            jax.shard_map(
                 ring, mesh=mesh, in_specs=(P("data"),),
-                out_specs=P("data"), check_rep=False,
+                out_specs=P("data"), check_vma=False,
             ),
             x,
         )
@@ -268,7 +268,7 @@ class TestPackedBufferAlignment:
         dims = np.asarray(
             jit_shmap(
                 local, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )(jnp.zeros(1))
         )
         for rows_pad in dims:
@@ -313,9 +313,9 @@ class TestAuditPins:
             return ring_all_gather(shard, "data", comm_dtype="int8")
 
         rep = audit(
-            jax.experimental.shard_map.shard_map(
+            jax.shard_map(
                 local, mesh=mesh, in_specs=(P("data"),),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             ),
             x,
         )
@@ -355,9 +355,9 @@ class TestAuditPins:
                 return updates
 
             return audit(
-                jax.experimental.shard_map.shard_map(
+                jax.shard_map(
                     local, mesh=mesh, in_specs=(P(), P()),
-                    out_specs=P(), check_rep=False,
+                    out_specs=P(), check_vma=False,
                 ),
                 params, grads,
             )
@@ -394,12 +394,12 @@ class TestFoundInfGatherSkip:
             )
             return updates
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         return jax.make_jaxpr(
             shard_map(
                 local, mesh=mesh, in_specs=(P(), P()),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             )
         )(params, grads)
 
@@ -455,7 +455,7 @@ class TestFoundInfGatherSkip:
 
             updates, found_inf, master_same, count = jit_shmap(
                 local, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )(params, grads)
             assert bool(found_inf), mode
             assert bool(master_same), mode

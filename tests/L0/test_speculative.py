@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _helpers import ON_CHIP, PAGE as PS, PAGE_I8 as PS_I8
 
 from rocm_apex_tpu.inference import (
     InferenceEngine,
@@ -31,6 +32,13 @@ from rocm_apex_tpu.inference import (
     SamplingParams,
 )
 from rocm_apex_tpu.models.gpt import GPTConfig, GPTModel
+
+# Page geometry by platform: see _helpers.
+# (pool pages, new tokens) at which two 8-token prompts deadlock
+# mid-decode: 5 pages of 4 rows leave each 14-row request short of its
+# 4th page; with 8-row pages, 4 pages leave each 18-row request short of
+# its 3rd
+PREEMPT_POOL, PREEMPT_NEW = (4, 10) if ON_CHIP else (5, 6)
 
 
 def fp32_cfg(**kw):
@@ -98,9 +106,9 @@ MAX_NEW = 8
 
 LAYOUTS = [
     pytest.param({}, id="contig"),
-    pytest.param({"paged": True, "page_size": 4}, id="paged"),
+    pytest.param({"paged": True, "page_size": PS}, id="paged"),
     pytest.param(
-        {"paged": True, "page_size": 4, "kv_dtype": jnp.int8},
+        {"paged": True, "page_size": PS_I8, "kv_dtype": jnp.int8},
         id="paged-int8",
     ),
 ]
@@ -294,13 +302,13 @@ class TestRollback:
         pA = sys_prefix + [1, 2, 3]
         pB = sys_prefix + [7, 8]
         ref = base_engine(
-            model, params, paged=True, page_size=4,
+            model, params, paged=True, page_size=PS,
             prefix_sharing=True,
         )
         rA0 = ref.generate([pA], max_new_tokens=6)[0]
         rB0 = ref.generate([pB], max_new_tokens=6)[0]
         eng = spec_engine(
-            model, params, k=2, paged=True, page_size=4,
+            model, params, k=2, paged=True, page_size=PS,
             prefix_sharing=True,
         )
         rA = eng.generate([pA], max_new_tokens=6)[0]
@@ -364,13 +372,14 @@ class TestPreemption:
         with the stall/preemption counters exposing what happened."""
         cfg, model, params = model_and_params
         prompts = [list(range(1, 9)), list(range(9, 17))]
-        ref = base_engine(model, params, paged=True, page_size=4).generate(
-            prompts, max_new_tokens=6
+        ref = base_engine(model, params, paged=True, page_size=PS).generate(
+            prompts, max_new_tokens=PREEMPT_NEW
         )
         eng = base_engine(
-            model, params, paged=True, page_size=4, num_pages=5
+            model, params, paged=True, page_size=PS,
+            num_pages=PREEMPT_POOL,
         )
-        res = eng.generate(prompts, max_new_tokens=6)
+        res = eng.generate(prompts, max_new_tokens=PREEMPT_NEW)
         for r, b in zip(res, ref):
             assert r.tokens == b.tokens
         s = eng.stats()
@@ -387,7 +396,7 @@ class TestPreemption:
         deadlock diagnosis must still raise."""
         cfg, model, params = model_and_params
         eng = base_engine(
-            model, params, paged=True, page_size=4, num_pages=1
+            model, params, paged=True, page_size=PS, num_pages=1
         )
         eng.add_request(list(range(1, 9)), max_new_tokens=2)
         with pytest.raises(RuntimeError, match="deadlock"):

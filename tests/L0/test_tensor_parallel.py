@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from _helpers import jit_shmap
 
@@ -41,7 +41,7 @@ def tp_mesh():
 
 def shmap(mesh, fn, in_specs, out_specs):
     return jit_shmap(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from rocm_apex_tpu.amp import LossScaler
@@ -511,7 +511,7 @@ class TestGroupNonfinite:
 
         g_flag, h_flag = jax.jit(shard_map(
             f, mesh=mesh, in_specs=(P("tensor"),),
-            out_specs=(P("tensor"), P("tensor")), check_rep=False,
+            out_specs=(P("tensor"), P("tensor")), check_vma=False,
         ))(x)
         # every rank reports the global verdict
         assert np.asarray(g_flag).tolist() == [1.0] * 4
@@ -551,7 +551,7 @@ class TestGroupNonfinite:
         def shmap(f):
             return shard_map(
                 f, mesh=mesh, in_specs=(P("tensor"),), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
 
         ref = audit(shmap(baseline), x)

@@ -79,10 +79,13 @@ if str(REPO) not in sys.path:
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from rocm_apex_tpu import monitor  # noqa: E402
+from rocm_apex_tpu.utils.compile_cache import (  # noqa: E402
+    enable_compile_cache,
+)
 from rocm_apex_tpu.monitor import (  # noqa: E402
     CollectiveContract,
     DonationContract,
@@ -416,7 +419,7 @@ def _build_spcm_tp2():
 
     f = shard_map(
         step, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     subject = LintSubject.from_fn("spcm_tp2", f, x_loc)
     rules = [
@@ -458,7 +461,7 @@ def _build_zero_int8():
 
     f = shard_map(
         local, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     subject = LintSubject.from_fn("zero_int8", f, params, grads)
     rules = [
@@ -572,6 +575,7 @@ def main(argv=None) -> int:
     ap.add_argument("--manifest", default=str(MANIFEST_PATH),
                     help="manifest path (default: the checked-in one)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.configs:
         for name, builder in REGISTRY.items():
