@@ -1,0 +1,7 @@
+"""Share of the traced stretch in which no operation ran on the device."""
+
+from benchmarks.layer_metrics import _common
+
+
+def read(context):
+    return _common.idle_pct(context)
