@@ -239,9 +239,7 @@ def bench_rn50(fused: bool = False):
 
 def build_bert_train(dropout: float = 0.0, batch: int = 0,
                      remat: bool = False, iters: int = 0):
-    """The BERT bench step, importable: used by `bench_bert` AND
-    `_profile_bert.py`, so the committed profiles can never drift from
-    the benchmark they explain. Returns
+    """The BERT bench step, importable (`bench_bert` runs it). Returns
     ``(runN, state0, rng0, cfg, batch, seq, params32)``."""
     from rocm_apex_tpu.models import BertConfig, BertModel
     from rocm_apex_tpu.optimizers.mixed import MixedPrecisionLamb

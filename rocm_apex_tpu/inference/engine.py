@@ -54,7 +54,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from rocm_apex_tpu import profiler
 from rocm_apex_tpu.inference.faults import NO_FAULTS, FaultInjected
 from rocm_apex_tpu.inference.kv_cache import KVCache
 from rocm_apex_tpu.inference.paging import (
@@ -63,7 +62,11 @@ from rocm_apex_tpu.inference.paging import (
     PrefixStore,
 )
 from rocm_apex_tpu.inference.sampling import sample
-from rocm_apex_tpu.monitor.trace import NULL_TRACER, mint_trace_id
+from rocm_apex_tpu.monitor.trace import (
+    NULL_TRACER,
+    mint_trace_id,
+    phase,
+)
 from rocm_apex_tpu.ops._pallas import on_tpu
 
 __all__ = [
@@ -270,9 +273,24 @@ class InferenceEngine:
     reproduce the reported TTFT/queue-wait numbers. Default ``None``
     is the shared disabled tracer: call sites pay one attribute check,
     the compiled programs and the one-fetch-per-tick host↔device
-    pattern are untouched. Per-request COMPLETION records (TTFT, TPOT,
-    tokens, chunks, queue wait) accrue on ``completions``
-    unconditionally — pure host bookkeeping.
+    pattern are untouched.
+
+    Whatever the tracer, every tick is one ``apex/engine.tick``
+    profiler annotation (`monitor.trace.phase`) tiled by its phases
+    ``engine.admit`` → ``engine.pack`` → ``engine.table_push`` →
+    ``engine.rng`` → ``engine.dispatch`` → ``engine.fetch`` →
+    ``engine.commit``, the tick's counts (program, decodes, chunk
+    tokens, slots, pages, queue depth, admitted, finished) riding as
+    the tick's metadata; `add_request` is ``apex/engine.enqueue``. A
+    `jax.profiler` capture holds them on the device planes' clock;
+    with none live they cost microseconds a tick (the counts are
+    values the tick computes anyway; docs/observability.md says what
+    reads each).
+    An enabled tracer records the same phases on its ``engine``
+    track.
+    Per-request COMPLETION records (TTFT, TPOT, tokens, chunks, queue
+    wait) accrue on ``completions`` unconditionally — pure host
+    bookkeeping.
 
     ``paged=True`` swaps the contiguous per-slot cache for the
     block-table `PagedKVCache` (chunked scheduler required): device
@@ -1865,18 +1883,26 @@ class InferenceEngine:
                 )
             if victim_req is None:
                 return request_id
-        req = Request(
-            request_id, prompt, max_new_tokens,
-            enqueued_at=now,
-            deadline=(now + timeout) if timeout is not None else None,
-            queue_deadline=(
-                (now + queue_ttl) if queue_ttl is not None else None
-            ),
-            adapter_id=adapter_id,
-            tenant=tenant,
-            trace_id=trace_id,
-        )
-        self._queue.append(req)
+        # the arrival, on the clock of the tick that will lease it a
+        # slot (`engine.admit` carries the same id)
+        with phase(
+            "engine.enqueue", request_id=request_id,
+            prompt_tokens=len(prompt),
+        ):
+            req = Request(
+                request_id, prompt, max_new_tokens,
+                enqueued_at=now,
+                deadline=(
+                    (now + timeout) if timeout is not None else None
+                ),
+                queue_deadline=(
+                    (now + queue_ttl) if queue_ttl is not None else None
+                ),
+                adapter_id=adapter_id,
+                tenant=tenant,
+                trace_id=trace_id,
+            )
+            self._queue.append(req)
         if self.tracer.enabled:
             self.tracer.instant(
                 "enqueue", ts=req.enqueued_at,
@@ -1897,17 +1923,57 @@ class InferenceEngine:
         next) — including any shed (``queue_full``) and expired
         (``deadline``) requests, so every submitted request yields
         exactly one result."""
-        now = time.perf_counter()
-        self._check_watchdog(now)
-        out: List[GenerationResult] = []
-        if self._shed_results:
-            out.extend(self._shed_results)
-            self._shed_results = []
-        out.extend(self._expire_deadlines(now))
-        if self.chunked:
-            out.extend(self._step_chunked())
-        else:
-            out.extend(self._step_whole())
+        with self.tracer.phase(
+            "engine.tick", track="engine", tick=self._tick
+        ) as tick:
+            # read before the tick maps or frees a page
+            pages_used = self.pages_used
+            out, leased = self._admit_phase()
+            if self.chunked:
+                finished, counts = self._step_chunked()
+            else:
+                finished, counts = self._step_whole()
+            out.extend(finished)
+            # the tick's counts, all from values it computed anyway
+            # (docs/observability.md says what reads each); the legacy
+            # mode admits in `_step_whole` and counts its own
+            counts.setdefault("admitted", leased)
+            tick.set_metadata(
+                **counts,
+                finished=len(out),
+                queue_depth=len(self._queue),
+                slots=self.num_slots,
+                budget=self.prefill_token_budget or 0,
+                pages_used=pages_used,
+                pages_total=self.cache.num_pages if self.paged else 0,
+            )
+        return out
+
+    def _admit_phase(self) -> Tuple[List[GenerationResult], int]:
+        """The tick's ``engine.admit`` phase: the watchdog, the shed
+        and expired requests' results, and the lease of free slots to
+        queued requests. Returns those results and how many requests
+        were leased a slot; their ids ride on the span, the ids
+        ``engine.enqueue`` carries."""
+        with self.tracer.phase("engine.admit", track="engine") as admit:
+            now = time.perf_counter()
+            self._check_watchdog(now)
+            out: List[GenerationResult] = []
+            if self._shed_results:
+                out.extend(self._shed_results)
+                self._shed_results = []
+            out.extend(self._expire_deadlines(now))
+            leased = self._admit_free_slots(now) if self.chunked else []
+            if leased:
+                admit.set_metadata(
+                    request_ids=" ".join(str(i) for i in leased)
+                )
+        return out, len(leased)
+
+    def _close_tick(self) -> None:
+        """The end of every tick, inside its ``engine.commit`` phase:
+        tick count, progress mark, gauges, the sensor plane's sample
+        and the retrace sentinel's check."""
         self._tick += 1
         self._note_progress()
         if self.registry.enabled:
@@ -1922,7 +1988,6 @@ class InferenceEngine:
             # post-warmup compile fails HERE, never inside the jax
             # callback mid-compile
             self.retrace_sentinel.check()
-        return out
 
     def cancel(self, request_id: int) -> Optional[GenerationResult]:
         """Cancel one request, wherever it is in its lifecycle, and
@@ -2754,9 +2819,10 @@ class InferenceEngine:
             return req, aslot
         return None
 
-    def _admit_free_slots(self, now: float) -> None:
+    def _admit_free_slots(self, now: float) -> List[int]:
         """Lease free slots to queued requests (host bookkeeping; the
-        prefill work itself is scheduled by the caller). With prefix
+        prefill work itself is scheduled by the caller) and return the
+        ids of those leased. With prefix
         sharing, a prompt that extends an already-materialized page
         chain maps those pages by REFERENCE and starts its prefill
         cursor past them — the shared tokens are never re-prefilled.
@@ -2803,6 +2869,7 @@ class InferenceEngine:
                         request_id=victim.req.request_id,
                         trace_id=victim.req.trace_id,
                     )
+        leased: List[int] = []
         for slot in range(self.num_slots):
             if self._slots[slot] is not None or not self._queue:
                 continue
@@ -2813,6 +2880,7 @@ class InferenceEngine:
                 break
             req, aslot = picked
             self._admitted += 1
+            leased.append(req.request_id)
             self._record_queue_wait(now - req.enqueued_at)
             st = _Slot(
                 req=req, generated=[], pos=0, cursor=0,
@@ -2881,6 +2949,7 @@ class InferenceEngine:
                     track=f"req{req.request_id}", slot=slot,
                     request_id=req.request_id, trace_id=req.trace_id,
                 )
+        return leased
 
     # -- robustness internals ------------------------------------------
 
@@ -3121,236 +3190,236 @@ class InferenceEngine:
             f"draining={self._draining}; " + "; ".join(parts)
         )
 
-    def _step_chunked(self) -> List[GenerationResult]:
+    def _step_chunked(
+        self,
+    ) -> Tuple[List[GenerationResult], Dict[str, Any]]:
         finished: List[GenerationResult] = []
-        now = time.perf_counter()
-        self._admit_free_slots(now)
+        # (admission ran in `_admit_phase`, under ``engine.admit``)
+        with self.tracer.phase("engine.pack", track="engine"):
+            budget = self.prefill_token_budget
+            S = self.num_slots
+            chunk_tokens = np.zeros((budget,), np.int32)
+            # slot id == num_slots marks padding: the scatter drops it and
+            # the segment mask keeps pads talking only to each other
+            chunk_slots = np.full((budget,), S, np.int32)
+            chunk_pos = np.zeros((budget,), np.int32)
+            # speculative mode only: who COMMITS in-trace. Prefill rows
+            # commit like always; speculative rows keep the pad sentinel
+            # (the host commits their accepted prefix post-verification)
+            commit_slots = np.full((budget,), S, np.int32)
+            lengths_before = np.zeros((S,), np.int32)
+            lengths_after = np.zeros((S,), np.int32)
+            # logits poison: zeros on the fault-free path (the compiled
+            # programs add it unconditionally — x + 0.0 — so the fault-free
+            # tokens are bitwise identical and the trace never changes);
+            # a `logits` fault poisons ONE slot's rows with NaN/Inf
+            chunk_poison = np.zeros((budget,), np.float32)
+            dec_poison = np.zeros((S,), np.float32)
+            # per-row adapter BUFFER slots (multi-LoRA): pad rows stay 0 =
+            # base = zero factors, so padding is exact with or without
+            # adapters in the batch
+            pool = self.adapter_pool
+            chunk_adp = dec_adp = None
+            if pool is not None:
+                chunk_adp = np.zeros((budget,), np.int32)
+                dec_adp = np.zeros((S,), np.int32)
+            poison_slot = -1
+            poison_val = 0.0
+            if self.faults.enabled:
+                flt = self.faults.fire("logits", tick=self._tick)
+                if flt is not None:
+                    pay = (
+                        flt.payload if isinstance(flt.payload, dict)
+                        else {"slot": flt.payload}
+                    )
+                    s = pay.get("slot")
+                    poison_slot = int(s) if s is not None else 0
+                    poison_val = float(pay.get("value", float("nan")))
+                    if 0 <= poison_slot < S:
+                        dec_poison[poison_slot] = poison_val
+            # (slot, chunk index of last prompt token, fed-to-decode flag)
+            completions = []
+            packed = []  # (slot, tokens, start_pos) — tracer span payload
+            # paged prefix registration deferred past the device call (a
+            # failed step must not leave never-written pages registered)
+            reg_pending = []
+            # speculative bookkeeping: (slot, first chunk row, drafted
+            # count, draft tokens, pre-draft position)
+            spec_entries = []
+            used = 0
+            prefill_used = 0
 
-        budget = self.prefill_token_budget
-        S = self.num_slots
-        chunk_tokens = np.zeros((budget,), np.int32)
-        # slot id == num_slots marks padding: the scatter drops it and
-        # the segment mask keeps pads talking only to each other
-        chunk_slots = np.full((budget,), S, np.int32)
-        chunk_pos = np.zeros((budget,), np.int32)
-        # speculative mode only: who COMMITS in-trace. Prefill rows
-        # commit like always; speculative rows keep the pad sentinel
-        # (the host commits their accepted prefix post-verification)
-        commit_slots = np.full((budget,), S, np.int32)
-        lengths_before = np.zeros((S,), np.int32)
-        lengths_after = np.zeros((S,), np.int32)
-        # logits poison: zeros on the fault-free path (the compiled
-        # programs add it unconditionally — x + 0.0 — so the fault-free
-        # tokens are bitwise identical and the trace never changes);
-        # a `logits` fault poisons ONE slot's rows with NaN/Inf
-        chunk_poison = np.zeros((budget,), np.float32)
-        dec_poison = np.zeros((S,), np.float32)
-        # per-row adapter BUFFER slots (multi-LoRA): pad rows stay 0 =
-        # base = zero factors, so padding is exact with or without
-        # adapters in the batch
-        pool = self.adapter_pool
-        chunk_adp = dec_adp = None
-        if pool is not None:
-            chunk_adp = np.zeros((budget,), np.int32)
-            dec_adp = np.zeros((S,), np.int32)
-        poison_slot = -1
-        poison_val = 0.0
-        if self.faults.enabled:
-            flt = self.faults.fire("logits", tick=self._tick)
-            if flt is not None:
-                pay = (
-                    flt.payload if isinstance(flt.payload, dict)
-                    else {"slot": flt.payload}
-                )
-                s = pay.get("slot")
-                poison_slot = int(s) if s is not None else 0
-                poison_val = float(pay.get("value", float("nan")))
-                if 0 <= poison_slot < S:
-                    dec_poison[poison_slot] = poison_val
-        # (slot, chunk index of last prompt token, fed-to-decode flag)
-        completions = []
-        packed = []  # (slot, tokens, start_pos) — tracer span payload
-        # paged prefix registration deferred past the device call (a
-        # failed step must not leave never-written pages registered)
-        reg_pending = []
-        # speculative bookkeeping: (slot, first chunk row, drafted
-        # count, draft tokens, pre-draft position)
-        spec_entries = []
-        used = 0
-        prefill_used = 0
-
-        drafts_np = counts_np = None
-        t_d0 = t_d1 = 0.0
-        if self.spec_k > 0:
-            # one batched drafter call per tick, covering every
-            # decoding slot (jitted inside the drafter; numpy in/out)
-            W = self._spec_window
-            hist = np.full((S, W), -1, np.int32)
-            hist_len = np.zeros((S,), np.int32)
-            any_decoding = False
-            for slot, s in enumerate(self._slots):
-                if s is None or not s.generated or s.prefilling:
-                    continue
-                any_decoding = True
-                h = (s.req.prompt + s.generated)[-W:]
-                hist[slot, W - len(h):] = h
-                hist_len[slot] = len(h)
-            if any_decoding:
-                t_d0 = time.perf_counter()
-                drafts_np, counts_np = self._drafter(hist, hist_len)
-                t_d1 = time.perf_counter()
-
-        # slot order keeps the packed segment ids non-decreasing (the
-        # varlen kernel's contract); a slot contributes either prefill
-        # rows or a speculative span, never both
-        for slot in range(S):
-            st = self._slots[slot]
-            if st is not None:
-                lengths_before[slot] = st.pos
-                lengths_after[slot] = st.pos
-            if st is None or used >= budget:
-                continue
-            if st.prefilling:
-                n = min(budget - used, len(st.prefix) - st.cursor)
-                if self.prefill_chunk is not None:
-                    n = min(n, self.prefill_chunk)
-                if self.paged:
-                    # pool backpressure: only tokens whose pages exist
-                    # (or could be allocated / CoW-forked) are
-                    # scheduled; a starved slot just waits for
-                    # evictions to free pages
-                    n = self._secure_prefill_pages(st, slot, n)
-                    if n <= 0:
+            drafts_np = counts_np = None
+            t_d0 = t_d1 = 0.0
+            if self.spec_k > 0:
+                # one batched drafter call per tick, covering every
+                # decoding slot (jitted inside the drafter; numpy in/out)
+                W = self._spec_window
+                hist = np.full((S, W), -1, np.int32)
+                hist_len = np.zeros((S,), np.int32)
+                any_decoding = False
+                for slot, s in enumerate(self._slots):
+                    if s is None or not s.generated or s.prefilling:
                         continue
-                chunk_tokens[used:used + n] = st.prefix[
-                    st.cursor:st.cursor + n
-                ]
-                chunk_slots[used:used + n] = slot
-                commit_slots[used:used + n] = slot
-                chunk_pos[used:used + n] = np.arange(
-                    st.cursor, st.cursor + n
-                )
-                if chunk_adp is not None:
-                    chunk_adp[used:used + n] = st.adapter_slot
-                packed.append((slot, n, st.cursor))
-                st.cursor += n
-                st.pos = st.cursor
-                st.chunks += 1
-                lengths_after[slot] = st.cursor
-                self._prompt_tokens += n
-                if self.paged and self._store is not None:
-                    reg_pending.append((st, slot))
-                if not st.prefilling and not st.resumed:
-                    # the completing prompt's first sampled token is
-                    # fed straight into the fused decode — UNLESS that
-                    # decode write has nowhere to land: a prompt that
-                    # exactly fills capacity (the old silent
-                    # clamp-at-capacity; the host evicts it right
-                    # after the first token instead) or a paged slot
-                    # whose next page the pool cannot supply yet (it
-                    # decodes on a later tick). A RESUMED (preempted)
-                    # request completing its recomputed prefix emits
-                    # nothing here — its tokens already exist; it
-                    # rejoins the decode grid below this same tick.
-                    fed = st.cursor < self.capacity
-                    if fed and self.paged:
-                        fed = self._ensure_writable(
-                            st, slot, st.cursor // self.cache.page_size
-                        )
-                        if not fed:
-                            self._page_stalls += 1
-                    completions.append((slot, used + n - 1, fed))
-                used += n
-                prefill_used += n
-                continue
-            # ---- speculative span: [last generated token, k drafts].
-            # The last token needs its decode row scored anyway; the
-            # drafts ride the same packed chunk, so acceptance costs
-            # no extra trace. Clamps: drafter confidence, spec_k, the
-            # remaining budget (one row is the last token itself),
-            # capacity (every accepted token + bonus needs a cache
-            # row), and max_new (finishing mid-span is handled, but
-            # drafting past the request's end is wasted budget).
-            if drafts_np is None or not st.generated:
-                continue
-            n = min(
-                int(counts_np[slot]), self.spec_k, budget - used - 1,
-                self.capacity - st.pos - 1,
-                st.req.max_new_tokens - len(st.generated) - 1,
-            )
-            if n < 1:
-                continue
-            if self.paged and not self._ensure_writable(
-                st, slot, st.pos // self.cache.page_size
-            ):
-                # pool exhausted even for the last token's row: fall
-                # through to the decode grid, which hits the same wall
-                # and stalls the slot for the tick
-                continue
-            drafts = [int(t) for t in drafts_np[slot, :n]]
-            chunk_tokens[used] = st.generated[-1]
-            chunk_tokens[used + 1:used + 1 + n] = drafts
-            chunk_slots[used:used + n + 1] = slot
-            chunk_pos[used:used + n + 1] = np.arange(
-                st.pos, st.pos + n + 1
-            )
-            spec_entries.append((slot, used, n, drafts, st.pos))
-            self._tokens_drafted += n
-            used += n + 1
+                    any_decoding = True
+                    h = (s.req.prompt + s.generated)[-W:]
+                    hist[slot, W - len(h):] = h
+                    hist_len[slot] = len(h)
+                if any_decoding:
+                    t_d0 = time.perf_counter()
+                    drafts_np, counts_np = self._drafter(hist, hist_len)
+                    t_d1 = time.perf_counter()
 
-        if poison_slot >= 0:
-            # poison the faulted slot's chunk rows too (a prompt
-            # completion or speculative span must quarantine the same
-            # way a decode row does)
-            chunk_poison[chunk_slots == poison_slot] = poison_val
-
-        # decode grid: slots whose prompt completed in an EARLIER tick
-        # (a slot finishing prefill this tick gets its first token from
-        # the chunk logits below and starts decoding next tick; a slot
-        # with a speculative span this tick advances via the accept
-        # walk instead)
-        active = np.array(
-            [s is not None and bool(s.generated) and not s.prefilling
-             for s in self._slots],
-            dtype=bool,
-        )
-        for slot, _, _, _, _ in spec_entries:
-            active[slot] = False
-        self._guard_capacity(active)
-        if self.paged:
-            for slot, st in enumerate(self._slots):
-                if not active[slot]:
+            # slot order keeps the packed segment ids non-decreasing (the
+            # varlen kernel's contract); a slot contributes either prefill
+            # rows or a speculative span, never both
+            for slot in range(S):
+                st = self._slots[slot]
+                if st is not None:
+                    lengths_before[slot] = st.pos
+                    lengths_after[slot] = st.pos
+                if st is None or used >= budget:
                     continue
-                if not self._ensure_writable(
+                if st.prefilling:
+                    n = min(budget - used, len(st.prefix) - st.cursor)
+                    if self.prefill_chunk is not None:
+                        n = min(n, self.prefill_chunk)
+                    if self.paged:
+                        # pool backpressure: only tokens whose pages exist
+                        # (or could be allocated / CoW-forked) are
+                        # scheduled; a starved slot just waits for
+                        # evictions to free pages
+                        n = self._secure_prefill_pages(st, slot, n)
+                        if n <= 0:
+                            continue
+                    chunk_tokens[used:used + n] = st.prefix[
+                        st.cursor:st.cursor + n
+                    ]
+                    chunk_slots[used:used + n] = slot
+                    commit_slots[used:used + n] = slot
+                    chunk_pos[used:used + n] = np.arange(
+                        st.cursor, st.cursor + n
+                    )
+                    if chunk_adp is not None:
+                        chunk_adp[used:used + n] = st.adapter_slot
+                    packed.append((slot, n, st.cursor))
+                    st.cursor += n
+                    st.pos = st.cursor
+                    st.chunks += 1
+                    lengths_after[slot] = st.cursor
+                    self._prompt_tokens += n
+                    if self.paged and self._store is not None:
+                        reg_pending.append((st, slot))
+                    if not st.prefilling and not st.resumed:
+                        # the completing prompt's first sampled token is
+                        # fed straight into the fused decode — UNLESS that
+                        # decode write has nowhere to land: a prompt that
+                        # exactly fills capacity (the old silent
+                        # clamp-at-capacity; the host evicts it right
+                        # after the first token instead) or a paged slot
+                        # whose next page the pool cannot supply yet (it
+                        # decodes on a later tick). A RESUMED (preempted)
+                        # request completing its recomputed prefix emits
+                        # nothing here — its tokens already exist; it
+                        # rejoins the decode grid below this same tick.
+                        fed = st.cursor < self.capacity
+                        if fed and self.paged:
+                            fed = self._ensure_writable(
+                                st, slot, st.cursor // self.cache.page_size
+                            )
+                            if not fed:
+                                self._page_stalls += 1
+                        completions.append((slot, used + n - 1, fed))
+                    used += n
+                    prefill_used += n
+                    continue
+                # ---- speculative span: [last generated token, k drafts].
+                # The last token needs its decode row scored anyway; the
+                # drafts ride the same packed chunk, so acceptance costs
+                # no extra trace. Clamps: drafter confidence, spec_k, the
+                # remaining budget (one row is the last token itself),
+                # capacity (every accepted token + bonus needs a cache
+                # row), and max_new (finishing mid-span is handled, but
+                # drafting past the request's end is wasted budget).
+                if drafts_np is None or not st.generated:
+                    continue
+                n = min(
+                    int(counts_np[slot]), self.spec_k, budget - used - 1,
+                    self.capacity - st.pos - 1,
+                    st.req.max_new_tokens - len(st.generated) - 1,
+                )
+                if n < 1:
+                    continue
+                if self.paged and not self._ensure_writable(
                     st, slot, st.pos // self.cache.page_size
                 ):
-                    # stall THIS slot's decode for the tick; everyone
-                    # else advances (fixed shapes: the row just rides
-                    # along dead)
-                    active[slot] = False
-                    self._page_stalls += 1
-        dec_tokens = np.array(
-            [s.generated[-1] if s is not None and s.generated else 0
-             for s in self._slots],
-            np.int32,
-        )
-
-        completion_idx = np.full((S,), -1, np.int32)
-        for slot, idx, fed in completions:
-            completion_idx[slot] = idx if fed else -1
-        if dec_adp is not None:
-            # only rows the fused decode actually emits carry their
-            # adapter slot; dead rows stay 0 so a pure-base tick's
-            # `active` skip condition sees all-zero ids exactly
-            for slot, st in enumerate(self._slots):
-                if st is None:
+                    # pool exhausted even for the last token's row: fall
+                    # through to the decode grid, which hits the same wall
+                    # and stalls the slot for the tick
                     continue
-                if active[slot] or completion_idx[slot] >= 0:
-                    dec_adp[slot] = st.adapter_slot
-        if self.paged:
+                drafts = [int(t) for t in drafts_np[slot, :n]]
+                chunk_tokens[used] = st.generated[-1]
+                chunk_tokens[used + 1:used + 1 + n] = drafts
+                chunk_slots[used:used + n + 1] = slot
+                chunk_pos[used:used + n + 1] = np.arange(
+                    st.pos, st.pos + n + 1
+                )
+                spec_entries.append((slot, used, n, drafts, st.pos))
+                self._tokens_drafted += n
+                used += n + 1
+
+            if poison_slot >= 0:
+                # poison the faulted slot's chunk rows too (a prompt
+                # completion or speculative span must quarantine the same
+                # way a decode row does)
+                chunk_poison[chunk_slots == poison_slot] = poison_val
+
+            # decode grid: slots whose prompt completed in an EARLIER tick
+            # (a slot finishing prefill this tick gets its first token from
+            # the chunk logits below and starts decoding next tick; a slot
+            # with a speculative span this tick advances via the accept
+            # walk instead)
+            active = np.array(
+                [s is not None and bool(s.generated) and not s.prefilling
+                 for s in self._slots],
+                dtype=bool,
+            )
+            for slot, _, _, _, _ in spec_entries:
+                active[slot] = False
+            self._guard_capacity(active)
+            if self.paged:
+                for slot, st in enumerate(self._slots):
+                    if not active[slot]:
+                        continue
+                    if not self._ensure_writable(
+                        st, slot, st.pos // self.cache.page_size
+                    ):
+                        # stall THIS slot's decode for the tick; everyone
+                        # else advances (fixed shapes: the row just rides
+                        # along dead)
+                        active[slot] = False
+                        self._page_stalls += 1
+            dec_tokens = np.array(
+                [s.generated[-1] if s is not None and s.generated else 0
+                 for s in self._slots],
+                np.int32,
+            )
+
+            completion_idx = np.full((S,), -1, np.int32)
+            for slot, idx, fed in completions:
+                completion_idx[slot] = idx if fed else -1
+            if dec_adp is not None:
+                # only rows the fused decode actually emits carry their
+                # adapter slot; dead rows stay 0 so a pure-base tick's
+                # `active` skip condition sees all-zero ids exactly
+                for slot, st in enumerate(self._slots):
+                    if st is None:
+                        continue
+                    if active[slot] or completion_idx[slot] >= 0:
+                        dec_adp[slot] = st.adapter_slot
             if (
-                used == 0 and not active.any() and completions == []
-                and self.has_work()
+                self.paged and used == 0 and not active.any()
+                and completions == [] and self.has_work()
             ):
                 # pool deadlock: every in-flight request is stalled
                 # waiting for pages and no decode can run to free any.
@@ -3361,7 +3430,9 @@ class InferenceEngine:
                 # prompt + generated tokens are recomputed through the
                 # ordinary chunked prefill.
                 self._preempt_for_pages()
-            self._push_table()
+        if self.paged:
+            with self.tracer.phase("engine.table_push", track="engine"):
+                self._push_table()
 
         chunk_out = None
         dec_out = None
@@ -3369,6 +3440,12 @@ class InferenceEngine:
         dec_bad = None
         chunk_kv = None
         spec_t0 = spec_t1 = 0.0
+        program = "none"
+        # ``engine.rng`` is the tick's key split (eager dispatches of
+        # its own, made once so that a retry replays the same key);
+        # each thunk is the tick's ``engine.dispatch`` (the uploads
+        # and the jitted call until it returns) and ``engine.fetch``
+        # (the one batched `device_get`, = the device sync)
         if self.spec_k > 0 and (used > 0 or active.any()):
             # speculative engines ALWAYS run the (single) spec mixed
             # program, even on draft-free ticks: the decode-only fast
@@ -3376,43 +3453,42 @@ class InferenceEngine:
             # accept walk outruns — here the host cursors ride in as
             # arguments every tick, and one program means
             # mixed_trace_count == 1 at any k
-            self._rng, rng = jax.random.split(self._rng)
+            program = "spec"
+            with self.tracer.phase("engine.rng", track="engine"):
+                self._rng, rng = jax.random.split(self._rng)
             t0 = time.perf_counter()
 
             def _spec_thunk():
-                chunk_tok, dec_tok, cbad, dbad, cache, kv = (
-                    self._mixed_spec_jit(
-                        self.params, self.cache,
-                        jnp.asarray(chunk_tokens),
-                        jnp.asarray(chunk_slots),
-                        jnp.asarray(chunk_pos),
-                        jnp.asarray(commit_slots),
-                        jnp.asarray(lengths_before),
-                        jnp.asarray(lengths_after),
-                        jnp.asarray(completion_idx),
-                        jnp.asarray(dec_tokens),
-                        jnp.asarray(active),
-                        jnp.asarray(chunk_poison),
-                        jnp.asarray(dec_poison), rng,
+                with self.tracer.phase("engine.dispatch", track="engine"):
+                    chunk_tok, dec_tok, cbad, dbad, cache, kv = (
+                        self._mixed_spec_jit(
+                            self.params, self.cache,
+                            jnp.asarray(chunk_tokens),
+                            jnp.asarray(chunk_slots),
+                            jnp.asarray(chunk_pos),
+                            jnp.asarray(commit_slots),
+                            jnp.asarray(lengths_before),
+                            jnp.asarray(lengths_after),
+                            jnp.asarray(completion_idx),
+                            jnp.asarray(dec_tokens),
+                            jnp.asarray(active),
+                            jnp.asarray(chunk_poison),
+                            jnp.asarray(dec_poison), rng,
+                        )
                     )
-                )
-                self._maybe_fail_fetch()
-                # ONE batched value fetch per tick (= the device
-                # sync); chunk_kv stays on device for the commit
-                # program. The nonfinite flags ride the same fetch.
-                fetched = jax.device_get(
-                    (chunk_tok, dec_tok, cbad, dbad)
-                )
+                    self._maybe_fail_fetch()
+                # ONE batched value fetch per tick; chunk_kv stays on
+                # device for the commit program. The nonfinite flags
+                # ride the same fetch.
+                with self.tracer.phase("engine.fetch", track="engine"):
+                    fetched = jax.device_get(
+                        (chunk_tok, dec_tok, cbad, dbad)
+                    )
                 return fetched, cache, kv
 
-            with profiler.annotate(
-                "inference/mixed_step",
-                chunk_tokens=used, decodes=int(active.sum()),
-                drafted=sum(e[2] for e in spec_entries),
-            ):
-                fetched, self.cache, chunk_kv = self._call_device(
-                    _spec_thunk
-                )
+            fetched, self.cache, chunk_kv = self._call_device(
+                _spec_thunk
+            )
             chunk_out, dec_out, chunk_bad, dec_bad = fetched
             t1 = time.perf_counter()
             spec_t0, spec_t1 = t0, t1
@@ -3423,76 +3499,63 @@ class InferenceEngine:
                 self._decode_seconds += t1 - t0
             if active.any() or completions or spec_entries:
                 self._decode_steps += 1
-            if self.tracer.enabled:
-                self.tracer.add_span(
-                    "mixed_step", t0, t1, track="engine",
-                    chunk_tokens=used, decodes=int(active.sum()),
-                    drafted=sum(e[2] for e in spec_entries),
-                )
-                for slot, n, start_pos in packed:
-                    st = self._slots[slot]
-                    self.tracer.add_span(
-                        "prefill_chunk", t0, t1,
-                        track=f"req{st.req.request_id}",
-                        tokens=n, start_pos=start_pos, slot=slot,
-                    )
         elif used > 0:
-            self._rng, rng = jax.random.split(self._rng)
+            program = "mixed"
+            with self.tracer.phase("engine.rng", track="engine"):
+                self._rng, rng = jax.random.split(self._rng)
             t0 = time.perf_counter()
 
             def _mixed_thunk():
-                if pool is None:
-                    chunk_tok, dec_tok, cbad, dbad, cache = (
-                        self._mixed_jit(
-                            self.params, self.cache,
+                with self.tracer.phase("engine.dispatch", track="engine"):
+                    if pool is None:
+                        chunk_tok, dec_tok, cbad, dbad, cache = (
+                            self._mixed_jit(
+                                self.params, self.cache,
+                                jnp.asarray(chunk_tokens),
+                                jnp.asarray(chunk_slots),
+                                jnp.asarray(chunk_pos),
+                                jnp.asarray(lengths_before),
+                                jnp.asarray(lengths_after),
+                                jnp.asarray(completion_idx),
+                                jnp.asarray(dec_tokens),
+                                jnp.asarray(active),
+                                jnp.asarray(chunk_poison),
+                                jnp.asarray(dec_poison), rng,
+                            )
+                        )
+                        adapters = None
+                    else:
+                        # the SAME fused chunk+decode program for any
+                        # adapter mix — ids are data, so adapter add /
+                        # park / reclaim churn never retraces
+                        (chunk_tok, dec_tok, cbad, dbad, cache,
+                         adapters) = self._mixed_lora_jit(
+                            self.params, self.cache, pool.buffers,
                             jnp.asarray(chunk_tokens),
                             jnp.asarray(chunk_slots),
                             jnp.asarray(chunk_pos),
+                            jnp.asarray(chunk_adp),
                             jnp.asarray(lengths_before),
                             jnp.asarray(lengths_after),
                             jnp.asarray(completion_idx),
                             jnp.asarray(dec_tokens),
                             jnp.asarray(active),
+                            jnp.asarray(dec_adp),
                             jnp.asarray(chunk_poison),
                             jnp.asarray(dec_poison), rng,
                         )
-                    )
-                    adapters = None
-                else:
-                    # the SAME fused chunk+decode program for any
-                    # adapter mix — ids are data, so adapter add /
-                    # park / reclaim churn never retraces
-                    (chunk_tok, dec_tok, cbad, dbad, cache,
-                     adapters) = self._mixed_lora_jit(
-                        self.params, self.cache, pool.buffers,
-                        jnp.asarray(chunk_tokens),
-                        jnp.asarray(chunk_slots),
-                        jnp.asarray(chunk_pos),
-                        jnp.asarray(chunk_adp),
-                        jnp.asarray(lengths_before),
-                        jnp.asarray(lengths_after),
-                        jnp.asarray(completion_idx),
-                        jnp.asarray(dec_tokens),
-                        jnp.asarray(active),
-                        jnp.asarray(dec_adp),
-                        jnp.asarray(chunk_poison),
-                        jnp.asarray(dec_poison), rng,
-                    )
-                self._maybe_fail_fetch()
-                # ONE batched value fetch per tick (= the device sync)
-                # — never a per-request scalar pull; the nonfinite
-                # flags ride the same fetch
-                return jax.device_get(
-                    (chunk_tok, dec_tok, cbad, dbad)
-                ), cache, adapters
+                    self._maybe_fail_fetch()
+                # ONE batched value fetch per tick — never a
+                # per-request scalar pull; the nonfinite flags ride
+                # the same fetch
+                with self.tracer.phase("engine.fetch", track="engine"):
+                    return jax.device_get(
+                        (chunk_tok, dec_tok, cbad, dbad)
+                    ), cache, adapters
 
-            with profiler.annotate(
-                "inference/mixed_step",
-                chunk_tokens=used, decodes=int(active.sum()),
-            ):
-                fetched, self.cache, new_adp = self._call_device(
-                    _mixed_thunk
-                )
+            fetched, self.cache, new_adp = self._call_device(
+                _mixed_thunk
+            )
             if new_adp is not None:
                 # re-bind the donated adapter buffers (like the cache,
                 # they only move forward on step success)
@@ -3503,218 +3566,231 @@ class InferenceEngine:
             self._mixed_steps += 1
             if active.any() or completions:
                 self._decode_steps += 1
-            if self.tracer.enabled:
-                self.tracer.add_span(
-                    "mixed_step", t0, t1, track="engine",
-                    chunk_tokens=used, decodes=int(active.sum()),
-                )
-                for slot, n, start_pos in packed:
-                    st = self._slots[slot]
-                    self.tracer.add_span(
-                        "prefill_chunk", t0, t1,
-                        track=f"req{st.req.request_id}",
-                        tokens=n, start_pos=start_pos, slot=slot,
-                    )
         elif active.any():
-            self._rng, rng = jax.random.split(self._rng)
+            program = "decode"
+            with self.tracer.phase("engine.rng", track="engine"):
+                self._rng, rng = jax.random.split(self._rng)
             t0 = time.perf_counter()
 
             def _decode_thunk():
-                if pool is None:
-                    tok, bad, cache = self._decode_jit(
-                        self.params, self.cache,
-                        jnp.asarray(dec_tokens),
-                        jnp.asarray(active), jnp.asarray(dec_poison),
-                        rng,
-                    )
-                    adapters = None
-                else:
-                    tok, bad, cache, adapters = self._decode_lora_jit(
-                        self.params, self.cache, pool.buffers,
-                        jnp.asarray(dec_tokens), jnp.asarray(active),
-                        jnp.asarray(dec_adp), jnp.asarray(dec_poison),
-                        rng,
-                    )
-                self._maybe_fail_fetch()
-                # value fetch = device sync
-                return jax.device_get((tok, bad)), cache, adapters
+                with self.tracer.phase("engine.dispatch", track="engine"):
+                    if pool is None:
+                        tok, bad, cache = self._decode_jit(
+                            self.params, self.cache,
+                            jnp.asarray(dec_tokens),
+                            jnp.asarray(active),
+                            jnp.asarray(dec_poison), rng,
+                        )
+                        adapters = None
+                    else:
+                        tok, bad, cache, adapters = (
+                            self._decode_lora_jit(
+                                self.params, self.cache, pool.buffers,
+                                jnp.asarray(dec_tokens),
+                                jnp.asarray(active),
+                                jnp.asarray(dec_adp),
+                                jnp.asarray(dec_poison), rng,
+                            )
+                        )
+                    self._maybe_fail_fetch()
+                with self.tracer.phase("engine.fetch", track="engine"):
+                    return jax.device_get((tok, bad)), cache, adapters
 
-            with profiler.annotate(
-                "inference/decode", batch=int(active.sum())
-            ):
-                fetched, self.cache, new_adp = self._call_device(
-                    _decode_thunk
-                )
+            fetched, self.cache, new_adp = self._call_device(
+                _decode_thunk
+            )
             if new_adp is not None:
                 pool.buffers = new_adp
             dec_out, dec_bad = fetched
             t1 = time.perf_counter()
             self._decode_seconds += t1 - t0
             self._decode_steps += 1
-            if self.tracer.enabled:
+        if self.tracer.enabled and packed:
+            # the request lifelines: a prompt's chunks, over the
+            # device call that absorbed them
+            for slot, n, start_pos in packed:
+                st = self._slots[slot]
                 self.tracer.add_span(
-                    "decode_step", t0, t1, track="engine",
-                    decodes=int(active.sum()),
+                    "prefill_chunk", t0, t1,
+                    track=f"req{st.req.request_id}",
+                    tokens=n, start_pos=start_pos, slot=slot,
                 )
 
-        # the device step committed: NOW the tick's full prompt pages
-        # may register in the prefix store (see reg_pending above)
-        for st, slot in reg_pending:
-            self._register_full_pages(st, slot)
+        with self.tracer.phase("engine.commit", track="engine"):
+            # the tick's counts as the device step saw them, before
+            # the evictions below: decode-grid rows that emit a token
+            # (those of earlier ticks' prompts and the fused second
+            # token of a prompt completed in this one), leased slots
+            counts = {
+                "program": program,
+                "chunk_tokens": used,
+                "prefill_tokens": prefill_used,
+                "decodes": int(active.sum()) + sum(
+                    1 for _, _, fed in completions if fed
+                ),
+                "slots_busy": self.num_active,
+            }
+            # the device step committed: NOW the tick's full prompt pages
+            # may register in the prefix store (see reg_pending above)
+            for st, slot in reg_pending:
+                self._register_full_pages(st, slot)
 
-        now2 = time.perf_counter()
-        for slot, idx, fed in completions:
-            st = self._slots[slot]
-            if chunk_bad is not None and chunk_bad[idx]:
-                # fault isolation: only THIS slot quarantines; every
-                # other slot's tokens came out of the same fetch,
-                # bitwise identical to a fault-free tick
-                finished.append(self._quarantine(
-                    slot, st, "nonfinite logits at prompt completion",
-                ))
-                continue
-            st.generated.append(int(chunk_out[idx]))
-            self._generated_tokens += 1
-            st.first_token_at = now2
-            self._record_ttft(now2 - st.req.enqueued_at)
-            done = self._finish_reason(st)
-            if done is not None:
-                # any fused decode output for this slot is discarded
-                # with the eviction (dead-row junk)
-                finished.append(self._evict(slot, st, done))
-                continue
-            if not fed:
-                # no fused decode ran for this slot (at-capacity edge
-                # already evicted above, or a paged page stall): the
-                # second token arrives on a later tick
-                continue
-            if dec_bad is not None and dec_bad[slot]:
-                finished.append(self._quarantine(
-                    slot, st, "nonfinite logits in fused decode",
-                ))
-                continue
-            # the mixed step fed the first token straight into the
-            # decode grid: the SECOND token arrives in the same tick
-            # (the whole-prompt admit-tick cadence, without the pad)
-            st.pos += 1
-            st.generated.append(int(dec_out[slot]))
-            self._generated_tokens += 1
-            done = self._finish_reason(st)
-            if done is not None:
-                finished.append(self._evict(slot, st, done))
-        if dec_out is not None:
-            for slot, st in enumerate(self._slots):
-                if st is None or not active[slot]:
+            now2 = time.perf_counter()
+            for slot, idx, fed in completions:
+                st = self._slots[slot]
+                if chunk_bad is not None and chunk_bad[idx]:
+                    # fault isolation: only THIS slot quarantines; every
+                    # other slot's tokens came out of the same fetch,
+                    # bitwise identical to a fault-free tick
+                    finished.append(self._quarantine(
+                        slot, st, "nonfinite logits at prompt completion",
+                    ))
+                    continue
+                st.generated.append(int(chunk_out[idx]))
+                self._generated_tokens += 1
+                st.first_token_at = now2
+                self._record_ttft(now2 - st.req.enqueued_at)
+                done = self._finish_reason(st)
+                if done is not None:
+                    # any fused decode output for this slot is discarded
+                    # with the eviction (dead-row junk)
+                    finished.append(self._evict(slot, st, done))
+                    continue
+                if not fed:
+                    # no fused decode ran for this slot (at-capacity edge
+                    # already evicted above, or a paged page stall): the
+                    # second token arrives on a later tick
                     continue
                 if dec_bad is not None and dec_bad[slot]:
                     finished.append(self._quarantine(
-                        slot, st, "nonfinite logits in decode",
+                        slot, st, "nonfinite logits in fused decode",
                     ))
                     continue
-                st.pos += 1  # the input token was written this step
+                # the mixed step fed the first token straight into the
+                # decode grid: the SECOND token arrives in the same tick
+                # (the whole-prompt admit-tick cadence, without the pad)
+                st.pos += 1
                 st.generated.append(int(dec_out[slot]))
                 self._generated_tokens += 1
                 done = self._finish_reason(st)
                 if done is not None:
                     finished.append(self._evict(slot, st, done))
-
-        # ---- speculative accept walk. Every packed span was sampled
-        # under the target model (row j conditioned on the drafts before
-        # it), so for the point-mass drafter the exact rejection rule
-        # (arXiv 2302.01318) degenerates to: accept draft j iff the
-        # model's own sample at row j equals it; the first disagreeing
-        # row's sample is the corrected "bonus" token — m accepted
-        # drafts always yield m+1 emitted tokens. Rejected rows simply
-        # never commit: their K/V exists only in the trace's packed
-        # per-layer output, so rollback is "don't write", not "undo" —
-        # shared pages and int8 scales are untouchable by construction.
-        if spec_entries:
-            any_commit = False
-            commit_np = np.full((budget,), S, np.int32)
-            commit_pos_np = np.zeros((budget,), np.int32)
-            for slot, r0, n, drafts, pos0 in spec_entries:
-                st = self._slots[slot]
-                if chunk_bad is not None and chunk_bad[
-                    r0:r0 + n + 1
-                ].any():
-                    # the whole span's K/V stays uncommitted (rollback
-                    # = "never written"), so quarantining the slot
-                    # cannot leave poisoned rows in shared pages
-                    finished.append(self._quarantine(
-                        slot, st,
-                        "nonfinite logits in speculative span",
-                    ))
-                    continue
-                out = chunk_out[r0:r0 + n + 1]
-                m = 0
-                while m < n and int(out[m]) == drafts[m]:
-                    m += 1
-                if self.paged and m > 0:
-                    # accepted tokens become cache writes: clamp the
-                    # accept length to pages the pool can actually
-                    # supply (CoW-forking shared ones as usual)
-                    ps = self.cache.page_size
-                    for j in range(1, m + 1):
-                        if not self._ensure_writable(
-                            st, slot, (pos0 + j) // ps
-                        ):
-                            self._page_stalls += 1
-                            m = j - 1
-                            break
-                emit = drafts[:m] + [int(out[m])]
-                accepted = 0
-                done = None
-                for i, tok in enumerate(emit):
-                    st.pos += 1
-                    st.generated.append(int(tok))
+            if dec_out is not None:
+                for slot, st in enumerate(self._slots):
+                    if st is None or not active[slot]:
+                        continue
+                    if dec_bad is not None and dec_bad[slot]:
+                        finished.append(self._quarantine(
+                            slot, st, "nonfinite logits in decode",
+                        ))
+                        continue
+                    st.pos += 1  # the input token was written this step
+                    st.generated.append(int(dec_out[slot]))
                     self._generated_tokens += 1
-                    if i < m:
-                        accepted += 1
-                        self._tokens_accepted += 1
                     done = self._finish_reason(st)
                     if done is not None:
-                        break
-                if n - accepted > 0:
-                    self._rollbacks += 1
-                if self.tracer.enabled:
-                    track = f"req{st.req.request_id}"
-                    self.tracer.add_span(
-                        "draft", t_d0, t_d1, track=track, tokens=n,
-                    )
-                    self.tracer.add_span(
-                        "verify", spec_t0, spec_t1, track=track,
-                        drafted=n, accepted=accepted, slot=slot,
-                    )
-                    if n - accepted > 0:
-                        self.tracer.instant(
-                            "rollback", track=track,
-                            rejected=n - accepted,
-                        )
-                if done is not None:
-                    # evicted slot: its uncommitted rows just die with
-                    # the lease (paged pages are derefed by the evict)
-                    finished.append(self._evict(slot, st, done))
-                    continue
-                # commit the span's written prefix: the last token's
-                # row r0 (it was never in the cache — the scatter
-                # dropped it in-trace) plus the m accepted draft rows.
-                # The bonus token is NOT written: it is the slot's new
-                # trailing unwritten token, exactly like normal decode.
-                commit_np[r0:r0 + m + 1] = slot
-                commit_pos_np[r0:r0 + m + 1] = np.arange(
-                    pos0, pos0 + m + 1
-                )
-                any_commit = True
-            if any_commit:
-                if self.paged:
-                    self._push_table()  # CoW forks from the clamp above
-                self.cache = self._commit_jit(
-                    self.cache, chunk_kv,
-                    jnp.asarray(commit_np), jnp.asarray(commit_pos_np),
-                )
-        return finished
+                        finished.append(self._evict(slot, st, done))
 
-    def _step_whole(self) -> List[GenerationResult]:
+            # ---- speculative accept walk. Every packed span was sampled
+            # under the target model (row j conditioned on the drafts before
+            # it), so for the point-mass drafter the exact rejection rule
+            # (arXiv 2302.01318) degenerates to: accept draft j iff the
+            # model's own sample at row j equals it; the first disagreeing
+            # row's sample is the corrected "bonus" token — m accepted
+            # drafts always yield m+1 emitted tokens. Rejected rows simply
+            # never commit: their K/V exists only in the trace's packed
+            # per-layer output, so rollback is "don't write", not "undo" —
+            # shared pages and int8 scales are untouchable by construction.
+            if spec_entries:
+                any_commit = False
+                commit_np = np.full((budget,), S, np.int32)
+                commit_pos_np = np.zeros((budget,), np.int32)
+                for slot, r0, n, drafts, pos0 in spec_entries:
+                    st = self._slots[slot]
+                    if chunk_bad is not None and chunk_bad[
+                        r0:r0 + n + 1
+                    ].any():
+                        # the whole span's K/V stays uncommitted (rollback
+                        # = "never written"), so quarantining the slot
+                        # cannot leave poisoned rows in shared pages
+                        finished.append(self._quarantine(
+                            slot, st,
+                            "nonfinite logits in speculative span",
+                        ))
+                        continue
+                    out = chunk_out[r0:r0 + n + 1]
+                    m = 0
+                    while m < n and int(out[m]) == drafts[m]:
+                        m += 1
+                    if self.paged and m > 0:
+                        # accepted tokens become cache writes: clamp the
+                        # accept length to pages the pool can actually
+                        # supply (CoW-forking shared ones as usual)
+                        ps = self.cache.page_size
+                        for j in range(1, m + 1):
+                            if not self._ensure_writable(
+                                st, slot, (pos0 + j) // ps
+                            ):
+                                self._page_stalls += 1
+                                m = j - 1
+                                break
+                    emit = drafts[:m] + [int(out[m])]
+                    accepted = 0
+                    done = None
+                    for i, tok in enumerate(emit):
+                        st.pos += 1
+                        st.generated.append(int(tok))
+                        self._generated_tokens += 1
+                        if i < m:
+                            accepted += 1
+                            self._tokens_accepted += 1
+                        done = self._finish_reason(st)
+                        if done is not None:
+                            break
+                    if n - accepted > 0:
+                        self._rollbacks += 1
+                    if self.tracer.enabled:
+                        track = f"req{st.req.request_id}"
+                        self.tracer.add_span(
+                            "draft", t_d0, t_d1, track=track, tokens=n,
+                        )
+                        self.tracer.add_span(
+                            "verify", spec_t0, spec_t1, track=track,
+                            drafted=n, accepted=accepted, slot=slot,
+                        )
+                        if n - accepted > 0:
+                            self.tracer.instant(
+                                "rollback", track=track,
+                                rejected=n - accepted,
+                            )
+                    if done is not None:
+                        # evicted slot: its uncommitted rows just die with
+                        # the lease (paged pages are derefed by the evict)
+                        finished.append(self._evict(slot, st, done))
+                        continue
+                    # commit the span's written prefix: the last token's
+                    # row r0 (it was never in the cache — the scatter
+                    # dropped it in-trace) plus the m accepted draft rows.
+                    # The bonus token is NOT written: it is the slot's new
+                    # trailing unwritten token, exactly like normal decode.
+                    commit_np[r0:r0 + m + 1] = slot
+                    commit_pos_np[r0:r0 + m + 1] = np.arange(
+                        pos0, pos0 + m + 1
+                    )
+                    any_commit = True
+                if any_commit:
+                    if self.paged:
+                        self._push_table()  # CoW forks from the clamp above
+                    self.cache = self._commit_jit(
+                        self.cache, chunk_kv,
+                        jnp.asarray(commit_np), jnp.asarray(commit_pos_np),
+                    )
+            self._close_tick()
+        return finished, counts
+
+    def _step_whole(
+        self,
+    ) -> Tuple[List[GenerationResult], Dict[str, Any]]:
         """Legacy whole-prompt prefill (the A/B baseline): one padded
         compiled prefill per admitted request — every other slot's
         decode WAITS on it (the head-of-line blocking the chunked
@@ -3722,6 +3798,7 @@ class InferenceEngine:
         finished: List[GenerationResult] = []
         t_admit = time.perf_counter()
         pending = []  # (slot, device first-token)
+        prefilled = 0  # prompt tokens
         for slot in range(self.num_slots):
             if self._slots[slot] is not None or not self._queue:
                 continue
@@ -3735,15 +3812,14 @@ class InferenceEngine:
             toks = np.zeros((1, self.max_prompt_len), np.int32)
             toks[0, : len(req.prompt)] = req.prompt
             self._rng, rng = jax.random.split(self._rng)
-            with profiler.annotate(
-                "inference/prefill", slot=slot, prompt_len=len(req.prompt)
-            ):
+            with self.tracer.phase("engine.dispatch", track="engine"):
                 tok, self.cache = self._prefill_jit(
                     self.params, self.cache, jnp.asarray(toks),
                     slot, len(req.prompt), rng,
                 )
             self._admitted += 1
             self._prompt_tokens += len(req.prompt)
+            prefilled += len(req.prompt)
             self._slots[slot] = _Slot(
                 req=req, generated=[], pos=len(req.prompt),
                 cursor=len(req.prompt), prefix=list(req.prompt),
@@ -3754,7 +3830,8 @@ class InferenceEngine:
             # ONE batched value fetch for every admit this tick (the
             # device sync) — the per-request int(tok) pull serialized
             # host and device once per admitted request
-            first_toks = jax.device_get([t for _, t in pending])
+            with self.tracer.phase("engine.fetch", track="engine"):
+                first_toks = jax.device_get([t for _, t in pending])
             now = time.perf_counter()
             self._prefill_seconds += now - t_admit
             for (slot, _), tok in zip(pending, first_toks):
@@ -3778,6 +3855,7 @@ class InferenceEngine:
             [s is not None for s in self._slots], dtype=bool
         )
         self._guard_capacity(active)
+        toks = None
         if active.any():
             tokens = np.array(
                 [s.generated[-1] if s is not None else 0
@@ -3787,19 +3865,27 @@ class InferenceEngine:
             self._rng, rng = jax.random.split(self._rng)
             t0 = time.perf_counter()
             poison = np.zeros((self.num_slots,), np.float32)
-            with profiler.annotate(
-                "inference/decode", batch=int(active.sum())
-            ):
+            with self.tracer.phase("engine.dispatch", track="engine"):
                 tok, bad, self.cache = self._decode_jit(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(active), jnp.asarray(poison), rng,
                 )
             # value fetch = device sync
-            toks, bad_h = jax.device_get((tok, bad))
+            with self.tracer.phase("engine.fetch", track="engine"):
+                toks, bad_h = jax.device_get((tok, bad))
             self._decode_seconds += time.perf_counter() - t0
             self._decode_steps += 1
+        with self.tracer.phase("engine.commit", track="engine"):
+            counts = {
+                "program": "whole",
+                "chunk_tokens": 0,
+                "prefill_tokens": prefilled,
+                "decodes": int(active.sum()),
+                "slots_busy": int(active.sum()),
+                "admitted": len(pending),
+            }
             for slot, state in enumerate(self._slots):
-                if state is None:
+                if state is None or toks is None:  # no decode ran
                     continue
                 if bad_h[slot]:
                     # a genuine model blow-up on one slot quarantines
@@ -3815,7 +3901,8 @@ class InferenceEngine:
                 done = self._finish_reason(state)
                 if done is not None:
                     finished.append(self._evict(slot, state, done))
-        return finished
+            self._close_tick()
+        return finished, counts
 
     def _finish_reason(self, state: _Slot) -> Optional[str]:
         if (

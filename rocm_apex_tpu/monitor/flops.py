@@ -37,8 +37,7 @@ __all__ = [
 
 # The one peaks table: (bf16 FLOP/s, HBM bytes/s) per chip, keyed by a
 # substring of ``device_kind``, first match wins (so "v5 lite"/"v5e"
-# sit ahead of "v5"). `profiler.op_stats` reads its roofline column
-# from the same rows. Source: Google Cloud TPU documentation, the
+# sit ahead of "v5"). Source: Google Cloud TPU documentation, the
 # per-generation system-architecture pages (v5e: 197 TFLOP/s bf16,
 # 819 GB/s HBM).
 CHIP_PEAKS = {

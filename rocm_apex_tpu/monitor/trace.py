@@ -11,11 +11,18 @@ a timeline. `Tracer` is the instrument:
 * ``tracer.span("prefill", tokens=n)`` — a context manager recording a
   wall-clock span into a thread-safe ring buffer (bounded memory: a
   long serving run keeps the last ``capacity`` events, oldest dropped);
-* spans also enter `jax.profiler.TraceAnnotation` scopes (and
-  `step_span` a `StepTraceAnnotation`), so when a device capture
-  (`profiler.trace`) is live, the host spans land on the SAME captured
-  timeline as the XLA ops — host scheduling gaps and device ring hops
-  line up in one Perfetto view;
+* every span is also a `phase(name, **counts)`: a
+  `jax.profiler.TraceAnnotation` named ``apex/<name>`` whose counts
+  ride as annotation metadata (`step_span` a `StepTraceAnnotation`),
+  so when a device capture (`profiler.trace`) is live, the host spans
+  land on the SAME captured timeline as the XLA ops, under the names
+  the Chrome export carries — host scheduling gaps and device ring
+  hops line up in one Perfetto view. `phase` is the ONE span
+  primitive of the program: the serving engine's tick opens its
+  ``engine.*`` phases through `Tracer.phase`, which always enters
+  the annotation (under a microsecond and nothing formatted while no
+  capture is live) and records in the ring only when the tracer is
+  enabled;
 * ``export_chrome_trace(path)`` writes the standard Chrome trace-event
   JSON (``ph: "X"`` complete events over named tracks), loadable in
   Perfetto / ``chrome://tracing`` with no converter;
@@ -77,6 +84,8 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 
 __all__ = [
+    "PROGRAM_PREFIX",
+    "phase",
     "Tracer",
     "NULL_TRACER",
     "mint_trace_id",
@@ -87,6 +96,30 @@ __all__ = [
     "RetraceError",
     "COMPILE_EVENT_PHASES",
 ]
+
+
+#: every span the program itself opens in a profiler capture starts
+#: with this (the benchmark's own spans start with ``bench/``)
+PROGRAM_PREFIX = "apex/"
+
+
+def phase(name: str, *, step_num: Optional[int] = None, **counts):
+    """The program's one span primitive: a
+    `jax.profiler.TraceAnnotation` named ``apex/<name>`` (a
+    `StepTraceAnnotation` when ``step_num`` is given) with ``counts``
+    as annotation metadata — never formatted into the name; counts
+    known only later in the span are added with ``.set_metadata``.
+    In a capture it is a host event on the clock the device planes
+    share, with the counts as its stats; with no capture live,
+    entering and leaving it costs well under a microsecond and the
+    counts are not formatted, so call sites enter it always. A
+    string count must hold no comma (the profiler's encoding splits
+    on it): join lists with spaces."""
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation(
+            PROGRAM_PREFIX + name, step_num=step_num, **counts
+        )
+    return jax.profiler.TraceAnnotation(PROGRAM_PREFIX + name, **counts)
 
 
 class _NullSpan:
@@ -124,6 +157,13 @@ class _Span:
             self._ann.__enter__()
         self._t0 = self._tracer.clock()
         return self
+
+    def set_metadata(self, **counts):
+        """Counts known only once the span is open (the annotation's
+        own method, so a bare `phase` and a recorded one read alike)."""
+        self.args.update(counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
 
     def __exit__(self, *exc):
         end = self._tracer.clock()
@@ -193,13 +233,19 @@ class Tracer:
         on exit; a `TraceAnnotation` scope while open)."""
         if not self.enabled:
             return _NULL_SPAN
-        ann = None
-        if self.annotate_device:
-            label = name
-            if args:
-                label = f"{name}|{json.dumps(args, default=str, sort_keys=True)}"
-            ann = jax.profiler.TraceAnnotation(label)
+        ann = phase(name, **args) if self.annotate_device else None
         return _Span(self, name, track, args, ann)
+
+    def phase(self, name: str, track: Optional[str] = None, **counts):
+        """`phase(name, **counts)`, entered whether or not the tracer
+        is enabled; an enabled tracer also records it in the ring,
+        from one pair of clock reads around the annotation. The
+        serving engine's tick is built from these (`span` stays the
+        free no-op on a disabled tracer, so it cannot be)."""
+        ann = phase(name, **counts)
+        if not self.enabled:
+            return ann
+        return _Span(self, name, track, counts, ann)
 
     def step_span(self, step: int, name: str = "train_step"):
         """`StepTraceAnnotation`-aligned span for one train step: the
@@ -207,9 +253,10 @@ class Tracer:
         host-side span records wall time for the same tick."""
         if not self.enabled:
             return _NULL_SPAN
-        ann = None
-        if self.annotate_device:
-            ann = jax.profiler.StepTraceAnnotation(name, step_num=step)
+        ann = (
+            phase(name, step_num=int(step))
+            if self.annotate_device else None
+        )
         return _Span(self, name, None, {"step": int(step)}, ann)
 
     def add_span(

@@ -33,6 +33,14 @@ multi_tensor parity layer (ops/multi_tensor.py).
 Skip-step (dynamic loss scaling) folds into the update as a select on
 every buffer being written anyway — the jit-safe analogue of the
 reference's optimizer.step no-op patch (apex/amp/handle.py:128-154).
+
+Every update (and the master-to-model cast) traces under
+``jax.named_scope("optimizer")``: its operations carry the scope in
+their HLO ``op_name`` and its Pallas kernels are NAMED after it
+(``%optimizer.<n>`` where they were ``%train_step.<n>``). A TPU trace
+of this JAX keeps only the names, so `optimizer.kernels_device_ms`
+(`benchmarks/layer_metrics/`) totals the kernels and cannot see the
+update's fusions.
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -42,6 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from rocm_apex_tpu.optimizers import _common as c
+
+#: the scope a device trace finds the update under
+OPTIMIZER_SCOPE = "optimizer"
 
 __all__ = [
     "MixedPrecisionAdam",
@@ -111,6 +122,7 @@ class MixedPrecisionAdam:
         """The compute-dtype tree for `model.apply` (== state.model)."""
         return state.model
 
+    @jax.named_scope(OPTIMIZER_SCOPE)
     def step(
         self,
         state: MixedPrecisionState,
@@ -177,6 +189,7 @@ class MixedPrecisionAdam:
             v=v2,
         )
 
+    @jax.named_scope(OPTIMIZER_SCOPE)
     def step_and_probe(
         self,
         state: MixedPrecisionState,
@@ -357,10 +370,12 @@ class MixedPrecisionLamb:
     def model_params(self, state: MixedPrecisionState):
         if state.model is not None:
             return state.model
-        return jax.tree_util.tree_map(
-            lambda x: x.astype(self.compute_dtype), state.master
-        )
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            return jax.tree_util.tree_map(
+                lambda x: x.astype(self.compute_dtype), state.master
+            )
 
+    @jax.named_scope(OPTIMIZER_SCOPE)
     def step_and_probe(
         self,
         state: MixedPrecisionState,

@@ -83,11 +83,11 @@ def _start_timer(timers, forward_only, tracer=None, microbatches=0):
     device wall time); called under jit the outputs are tracers, so the
     stop records trace/build time only and the in-graph phase
     attribution comes from the ``pp_fwd``/``pp_bwd``/``pp_comm``/
-    ``pp_head`` named scopes instead (visible to `profiler.op_stats` —
-    one fused scan admits no host-side phase timers).
+    ``pp_head`` named scopes instead (visible in a device trace — one
+    fused scan admits no host-side phase timers).
 
     ``tracer=`` (a `monitor.Tracer`) records the same region as a span
-    on the host timeline (and a `jax.profiler.TraceAnnotation` scope,
+    on the host timeline (and an ``apex/`` `monitor.trace.phase` scope,
     so a live device capture shows the schedule boundary); the shared
     disabled tracer makes the default free."""
     name = "pipeline/forward" if forward_only else "pipeline/fwd-bwd"

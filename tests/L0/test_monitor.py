@@ -361,10 +361,6 @@ class TestModelFlops:
         # a device outside the one table is an error, never a default
         with pytest.raises(UnknownDeviceError):
             peak_flops_per_chip("weird-chip")
-        # the profiler's roofline column reads the same table
-        from rocm_apex_tpu import profiler
-
-        assert profiler.chip_peaks is chip_peaks
         assert chip_peaks("tpu v5e") == (197e12, 819e9)
 
     def test_logger_omits_mfu_on_unknown_device(self):

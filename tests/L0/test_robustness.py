@@ -576,7 +576,7 @@ class TestGracefulDegradation:
         model, params = model_and_params
         eng = greedy_engine(model, params)
         eng._GENERATE_STALL_TICKS = 5  # instance override for speed
-        eng._step_chunked = lambda: []  # wedge: ticks do nothing
+        eng._step_chunked = lambda: ([], {})  # wedge: ticks do nothing
         with pytest.raises(RuntimeError, match="generate"):
             eng.generate([PROMPTS[0]], 4)
 
