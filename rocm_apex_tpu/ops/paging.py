@@ -40,14 +40,37 @@ dims: the Pallas paged-decode kernel fetches ``(1, 1, page_size,
 head_dim)`` blocks, which Mosaic tiles natively, instead of a
 sublane-degenerate ``(1, page_size, 1, head_dim)`` slice.
 
+A WRITE IS A TILE GROUP, NOT A ROW. In that layout one token's write
+is one row of each head's ``(page_size, head_dim)`` tile, and on a TPU a
+16-bit row is HALF of a packed 32-bit sublane (tiling ``T(8,128)(2,1)``:
+rows ``2i`` and ``2i+1`` share words). XLA's scatter does not write
+half-sublanes: given ``pool.at[pages, :, offs].set(x)`` it re-laid the
+WHOLE pool to ``(page, row, head, head_dim)``, scattered, and re-laid it
+back, twice 168 MB of traffic per pool per write, 25 ms of a 45 ms
+decode tick at the 1.3B serving geometry (PERF.md, PR 25).
+``paged_scatter`` therefore writes at the granularity the pool is
+stored in: ``G = gcd(page_size, 32 // itemsize)`` rows (16 bf16, 8 fp32;
+the number `PagedKVCache.create` validates ``page_size`` against on a
+TPU). The pool is VIEWED as ``(pages, heads, page_size // G, G,
+head_dim)``, a bitcast; each token's ``(heads, G, head_dim)`` group is
+gathered, the rows this call writes into it are replaced, and whole
+groups are scattered back, which the compiler does in place in the
+stored layout (`tests/L0/test_paged_write_compiled.py` holds the
+compiled serving programs to that). On the small pages of the CPU suite
+the gcd shrinks ``G``, down to 1, which is the row scatter: one path,
+chosen by shape and dtype. `quantized_paged_scatter` still writes rows
+after its page-level rewrite (int8 pools: no benchmark cell runs them).
+
 This module lives in ``ops`` (not ``inference``) so the model layer
 can share it: models/gpt.py consumes any cache pytree without
 importing the inference package (the PR-1 layering rule), but both
 sides must agree byte-for-byte on the scatter/view math.
 """
 
+import math
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
@@ -89,6 +112,10 @@ def paged_destinations(
     return pages, pos % page_size
 
 
+# jitted: a serving program calls this for K and V of every layer with
+# the same shapes, so the body is traced and lowered once per program
+# and not 2 x layers times (set-up is mostly JAX tracing: PERF.md)
+@jax.jit
 def paged_scatter(
     pool: jnp.ndarray,
     page_table: jnp.ndarray,
@@ -99,12 +126,38 @@ def paged_scatter(
     """Scatter ``x`` (tokens, heads, head_dim) into the pool at the
     table-resolved destinations. Exact (no quantization): the stored
     bytes equal the contiguous cache's ``.at[slot, pos].set`` bytes,
-    which is what makes paged-vs-contiguous greedy parity exact."""
-    num_pages, _, page_size, _ = pool.shape
+    which is what makes paged-vs-contiguous greedy parity exact.
+
+    The write is made in whole TILE GROUPS of ``G`` rows, not in rows
+    (module docstring): each token gathers the ``(heads, G, head_dim)``
+    group its row lives in, replaces the rows that THIS call writes
+    into that group, all of them, and scatters the group back. Several
+    tokens of one group (a chunk's run of positions) therefore scatter
+    identical bytes, whichever lands last. Destinations are taken to be
+    distinct, as for the row scatter; dropped tokens carry the page
+    sentinel and write nothing. ``G`` follows from the pool's dtype and
+    page size; at ``G == 1`` this IS the row scatter."""
+    num_pages, heads, page_size, head_dim = pool.shape
     pages, offs = paged_destinations(
         page_table, slots, positions, page_size, num_pages
     )
-    return pool.at[pages, :, offs].set(x.astype(pool.dtype), mode="drop")
+    g = math.gcd(page_size, 32 // pool.dtype.itemsize)
+    grp, row = offs // g, offs % g
+    view = pool.reshape(num_pages, heads, page_size // g, g, head_dim)
+    # hit[token, source, r]: the source's row is row r of the token's
+    # group (a dropped source's page is the sentinel, which no live
+    # token has)
+    cell = pages * (page_size // g) + grp
+    hit = (cell[:, None] == cell[None, :])[:, :, None] & (
+        row[None, :, None] == jnp.arange(g, dtype=row.dtype)
+    )
+    written = jnp.any(hit, axis=1)
+    src = jnp.argmax(hit, axis=1)  # (tokens, g)
+    new = x.astype(pool.dtype)[src].transpose(0, 2, 1, 3)
+    old = view[jnp.clip(pages, 0, num_pages - 1), :, grp]
+    groups = jnp.where(written[:, None, :, None], new, old)
+    view = view.at[pages, :, grp].set(groups, mode="drop")
+    return view.reshape(pool.shape)
 
 
 def quantized_paged_scatter(

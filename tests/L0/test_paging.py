@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _helpers import ON_CHIP, PAGE as PS, PAGE_I8 as PS_I8
+from _helpers import ON_CHIP, PAGE as PS, PAGE_I8 as PS_I8, jit_shmap
 
 from rocm_apex_tpu.inference import (
     InferenceEngine,
@@ -30,7 +30,7 @@ from rocm_apex_tpu.inference import (
     SamplingParams,
 )
 from rocm_apex_tpu.models.gpt import GPTConfig, GPTModel
-from rocm_apex_tpu.ops.paging import paged_view
+from rocm_apex_tpu.ops.paging import paged_scatter, paged_view
 
 # Page geometry by platform (see _helpers); the scenarios below are
 # written in terms of it. The parity sweep takes a page size that
@@ -294,6 +294,125 @@ class TestPagedKVCache:
                 np.asarray(f.k_scale[layer][2]),
                 np.asarray(f.k_scale[layer][0]),
             )
+
+
+# ---------------------------------------------------------------------------
+# the write: whole tile groups, byte-equal to the row scatter
+# ---------------------------------------------------------------------------
+
+W_HEADS, W_HD = 2, 8
+
+
+def row_scatter(pool, table, slots, positions, x):
+    """The plain reference: one row a token, destinations resolved here
+    in numpy; pad slots, positions at or past capacity and unmapped
+    entries take the sentinel page and drop."""
+    num_pages, _, page_size, _ = pool.shape
+    table, slots, positions = map(np.asarray, (table, slots, positions))
+    ok = (
+        (slots >= 0) & (slots < table.shape[0])
+        & (positions >= 0) & (positions < table.shape[1] * page_size)
+    )
+    sl, pos = np.where(ok, slots, 0), np.where(ok, positions, 0)
+    pages = np.where(ok, table[sl, pos // page_size], num_pages)
+    return pool.at[pages, :, pos % page_size].set(
+        x.astype(pool.dtype), mode="drop")
+
+
+def write_case(name, page_size):
+    """(table, [(slots, positions), ...]): the ticks of one scenario on
+    4 slots of 2 pages; page 9 of the 10 is never mapped, page 6 is
+    mapped by slots 2 AND 3 (shared), slot 3's second entry is
+    unmapped (sentinel 10)."""
+    ps = page_size
+    table = np.array([[0, 1], [2, 3], [6, 4], [6, 10]], np.int32)
+    i32 = lambda *v: np.array(v, np.int32).reshape(-1)  # noqa: E731
+    run = lambda a, b: np.arange(a, b, dtype=np.int32)  # noqa: E731
+    if name == "decode":  # one token a slot; slot 3 into its shared page
+        return table, [(run(0, 4), i32(0, ps - 1, ps, 3))]
+    if name == "chunk":
+        # three prompts packed: slot 0 starts mid-group and crosses the
+        # page boundary, slot 1 is three rows inside one group, slot 2
+        # ends at the last row of its capacity
+        a, b, c = run(ps - 5, ps + 6), run(1, 4), run(2 * ps - 7, 2 * ps)
+        slots = np.concatenate(
+            [np.full(len(a), 0), np.full(len(b), 1), np.full(len(c), 2)]
+        ).astype(np.int32)
+        return table, [(slots, np.concatenate([a, b, c]))]
+    if name == "two_ticks":  # one group filled by two chunks, then a decode
+        return table, [
+            (i32(1, 1, 1), run(1, 4)),
+            (i32(1, 1, 1, 1, 1), run(4, 9)),
+            (i32(1), i32(9)),
+        ]
+    if name == "drops":
+        # live rows beside: a pad slot (== and > num_slots), a negative
+        # slot, a position AT capacity, past it, and an unmapped entry
+        # whose clamped page (9) and whose slot's shared page 6 must
+        # both stay as they are
+        return table, [(
+            i32(0, 4, 7, -1, 1, 2, 3, 1),
+            i32(2, 2, 0, 1, 2 * ps, 2 * ps + 3, ps + 1, 5),
+        )]
+    if name == "all_dropped":
+        return table, [(i32(4, 4, 5, 3, 0), i32(0, 1, 2, ps, 2 * ps))]
+    raise KeyError(name)
+
+
+WRITE_CASES = [
+    ("decode", None), ("chunk", None), ("two_ticks", None),
+    ("drops", None), ("all_dropped", None),
+    # tile rows do not divide the page: the gcd makes the group smaller
+    ("chunk", 12), ("drops", 12), ("chunk", 5),
+]
+
+
+def _drive(write, name, page_size, dtype):
+    if page_size is None:  # two whole tile groups a page
+        page_size = 2 * (32 // jnp.dtype(dtype).itemsize)
+    table, ticks = write_case(name, page_size)
+    rng = np.random.RandomState(7)
+    shape = (10, W_HEADS, page_size, W_HD)
+    pool = ref = start = jnp.asarray(rng.randn(*shape), dtype)
+    for slots, positions in ticks:
+        x = jnp.asarray(
+            rng.randn(len(slots), W_HEADS, W_HD), jnp.float32)
+        pool = write(pool, table, slots, positions, x)
+        ref = row_scatter(ref, table, slots, positions, x)
+    assert pool.dtype == ref.dtype and pool.shape == ref.shape
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    assert np.array_equal(
+        np.asarray(pool).view(bits), np.asarray(ref).view(bits))
+    if name == "all_dropped":
+        assert np.array_equal(
+            np.asarray(pool).view(bits), np.asarray(start).view(bits))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize(
+    "name,page_size", WRITE_CASES,
+    ids=[n if p is None else f"{n}-page{p}" for n, p in WRITE_CASES])
+def test_group_write_equals_row_scatter(name, page_size, dtype):
+    _drive(paged_scatter, name, page_size, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("name", ["decode", "chunk", "drops"])
+def test_group_write_equals_row_scatter_head_sharded(name, dtype):
+    """tp 2 shards the pools' HEAD axis under shard_map; the group view
+    splits the row axis only, so each rank writes its own heads."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    heads, rep = P(None, "tp"), P()
+    write = jit_shmap(
+        paged_scatter, mesh=mesh,
+        in_specs=(heads, rep, rep, rep, heads), out_specs=heads,
+        check_vma=False,
+    )
+    _drive(write, name, None, dtype)
 
 
 # ---------------------------------------------------------------------------
