@@ -431,6 +431,36 @@ class InferenceEngine:
                 )
         self.model = model
         self.params = params
+        # The served model DECLARES what its layers keep per request
+        # (`cache_spec`) and the paged cache is built from that. A
+        # recurrent state lives in the slot, not in pages: whatever
+        # shares, ships or defers pages cannot carry it, so those
+        # options are refused here and not at their first use.
+        self._stateful = any(
+            layer["kind"] != "kv" for layer in model.cache_spec()
+        )
+        if self._stateful:
+            refused = {
+                "paged=False (its attention layers' K/V is paged; the "
+                "contiguous cache has no per-slot state)": not paged,
+                "prefix_sharing (a shared page holds K/V, not the state "
+                "a borrower would need at the prefix's end)": prefix_sharing,
+                "spec_k > 0 (a rejected draft row cannot be unwound from "
+                "a state that was overwritten in place)": spec_k > 0,
+                "tensor_parallel_size > 1": tp > 1,
+                "adapter_pool": adapter_pool is not None,
+                "kv_dtype=int8": (
+                    kv_dtype is not None
+                    and jnp.dtype(kv_dtype) == jnp.int8
+                ),
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{type(model).__name__} keeps a recurrent state "
+                        f"per slot beside its paged K/V; it does not "
+                        f"serve with {what}"
+                    )
         self.capacity = int(capacity or cfg.max_position_embeddings)
         if self.capacity > cfg.max_position_embeddings:
             raise ValueError(
@@ -577,20 +607,19 @@ class InferenceEngine:
             quantized = (
                 kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
             )
-            self.cache = PagedKVCache.for_model(
-                cfg, num_slots, self.capacity,
-                page_size=page_size, num_pages=num_pages,
-                dtype=(
-                    kv_dtype if (kv_dtype is not None and not quantized)
-                    else cache_dtype
-                ),
-                quantized=quantized,
+            self.cache = PagedKVCache.from_spec(
                 # tp>1: GLOBAL head count in the pools; the NamedSharding
                 # below splits dim 1 (heads) over the tensor axis, so
                 # each chip physically holds 1/tp of the KV bytes while
                 # host fetches (page shipping, debugging) still see
                 # full-head arrays — shipped pages are tp-agnostic.
-                full_heads=(tp > 1),
+                model.cache_spec(), num_slots, self.capacity,
+                page_size=page_size, num_pages=num_pages,
+                dtype=(
+                    kv_dtype if (kv_dtype is not None and not quantized)
+                    else cache_dtype or cfg.dtype
+                ),
+                quantized=quantized,
             )
             if tp > 1:
                 self.cache = jax.device_put(
@@ -876,6 +905,11 @@ class InferenceEngine:
         is_paged = self.paged
         dev_capacity = self.cache.capacity
 
+        def _start_tick(cache):
+            # the paged cache's counters of what its layers do in a tick
+            # start from zero; the sums ride the tick's one fetch
+            return cache.start_tick() if is_paged else cache
+
         def _decode_body(params, cache, tokens, active, poison, rng,
                          adapters=None):
             # `poison` is a per-slot fp32 addend on the logits — zeros
@@ -919,7 +953,8 @@ class InferenceEngine:
 
         def _decode(params, cache, tokens, active, poison, rng):
             self._traces["decode"] += 1
-            return _decode_body(params, cache, tokens, active, poison, rng)
+            return _decode_body(
+                params, _start_tick(cache), tokens, active, poison, rng)
 
         def _mixed(
             params, cache, chunk_tokens, chunk_slots, chunk_pos,
@@ -939,7 +974,7 @@ class InferenceEngine:
             prefill."""
             self._traces["mixed"] += 1
             rng_c, rng_d = jax.random.split(rng)
-            cache = cache.replace(lengths=lengths_before)
+            cache = _start_tick(cache).replace(lengths=lengths_before)
             logits_c, cache = chunk_model.apply(
                 params,
                 chunk_tokens[None, :],
@@ -2160,6 +2195,7 @@ class InferenceEngine:
         `_export_slot_pages` payload): feed the whole record to another
         engine's `resume_request(pages=...)` and the destination skips
         the recompute prefill, token-identically."""
+        self._refuse_page_shipping(ship_pages)
         recs = self.outstanding()
         by_id = {rec["request_id"]: rec for rec in recs}
         for slot in range(self.num_slots - 1, -1, -1):
@@ -2189,6 +2225,14 @@ class InferenceEngine:
         self._evacuated += len(recs)
         return recs
 
+    def _refuse_page_shipping(self, asked: bool) -> None:
+        if asked and self._stateful:
+            raise ValueError(
+                f"{type(self.model).__name__} keeps a recurrent state per "
+                f"slot: shipped pages would arrive without it (evacuate "
+                f"and resume by tokens; the prefill recomputes the state)"
+            )
+
     def evacuate_request(
         self, request_id: int, ship_pages: bool = False,
     ) -> Optional[Dict[str, Any]]:
@@ -2200,6 +2244,7 @@ class InferenceEngine:
         attached when ``ship_pages`` and the request holds a slot — is
         the caller's to deliver; this engine forgets the request
         entirely. Returns None when the request is not owned here."""
+        self._refuse_page_shipping(ship_pages)
         for slot in range(self.num_slots):
             st = self._slots[slot]
             if st is None or st.req.request_id != request_id:
@@ -2297,6 +2342,7 @@ class InferenceEngine:
         mismatch, pool pressure, or an injected ``page_ship`` fault)
         admission silently falls back to the token-replay path above,
         with identical greedy output."""
+        self._refuse_page_shipping(pages is not None)
         if self._draining:
             raise RuntimeError(
                 "engine is draining: admission is closed "
@@ -3439,6 +3485,7 @@ class InferenceEngine:
         chunk_bad = None
         dec_bad = None
         chunk_kv = None
+        layer_counts = None
         spec_t0 = spec_t1 = 0.0
         program = "none"
         # ``engine.rng`` is the tick's key split (eager dispatches of
@@ -3550,7 +3597,8 @@ class InferenceEngine:
                 # the same fetch
                 with self.tracer.phase("engine.fetch", track="engine"):
                     return jax.device_get(
-                        (chunk_tok, dec_tok, cbad, dbad)
+                        (chunk_tok, dec_tok, cbad, dbad,
+                         cache.counters if self.paged else None)
                     ), cache, adapters
 
             fetched, self.cache, new_adp = self._call_device(
@@ -3560,7 +3608,7 @@ class InferenceEngine:
                 # re-bind the donated adapter buffers (like the cache,
                 # they only move forward on step success)
                 pool.buffers = new_adp
-            chunk_out, dec_out, chunk_bad, dec_bad = fetched
+            chunk_out, dec_out, chunk_bad, dec_bad, layer_counts = fetched
             t1 = time.perf_counter()
             self._prefill_seconds += t1 - t0
             self._mixed_steps += 1
@@ -3594,14 +3642,16 @@ class InferenceEngine:
                         )
                     self._maybe_fail_fetch()
                 with self.tracer.phase("engine.fetch", track="engine"):
-                    return jax.device_get((tok, bad)), cache, adapters
+                    return jax.device_get(
+                        (tok, bad, cache.counters if self.paged else None)
+                    ), cache, adapters
 
             fetched, self.cache, new_adp = self._call_device(
                 _decode_thunk
             )
             if new_adp is not None:
                 pool.buffers = new_adp
-            dec_out, dec_bad = fetched
+            dec_out, dec_bad, layer_counts = fetched
             t1 = time.perf_counter()
             self._decode_seconds += t1 - t0
             self._decode_steps += 1
@@ -3630,6 +3680,11 @@ class InferenceEngine:
                 ),
                 "slots_busy": self.num_active,
             }
+            if layer_counts is not None:
+                counts.update(zip(
+                    self.cache.COUNTER_NAMES,
+                    (int(c) for c in layer_counts),
+                ))
             # the device step committed: NOW the tick's full prompt pages
             # may register in the prefix store (see reg_pending above)
             for st, slot in reg_pending:

@@ -337,6 +337,29 @@ class PagedKVCache:
     `write`/`write_at` keep the contiguous cache's signatures — the
     indirection is resolved inside (ops/paging.py) — so the model's
     cached attention calls the same protocol either way.
+
+    The engine builds the cache from what the served model DECLARES
+    each layer keeps per request (`from_spec` over
+    ``model.cache_spec()``). ``k``/``v`` hold one pool per layer that
+    ATTENDS, in layer order, so the page methods and the engine's
+    allocator do not care how many layers that is. ``ssm``/``conv``
+    hold, per layer that SCANS, the slots' recurrent state ``(slots,
+    state dim, heads * head dim)`` and the rows that last entered its
+    convolution ``(slots, d_conv - 1, conv dim)``: neither lives in
+    pages (a slot's state is overwritten in place), so nothing that
+    shares, ships or defers pages can carry it, and the engine refuses
+    those options for a model that declares such a layer. A slot's
+    state needs no reset call: ``lengths`` is the engine's own cursor,
+    and the model starts a slot whose length is 0 from zero.
+
+    ``counters`` (None unless a layer declares ``counters``): the
+    tick's sums over layers and over the step program's applies, named
+    by ``COUNTER_NAMES``; `start_tick` zeroes them and the engine
+    fetches them with the tick's tokens. ``routes`` (None unless a
+    layer declares ``route_words``, a model's debug option): one more
+    pool, ``(num_pages, 1, page_size, lanes)`` uint32 behind the same
+    page table, a position's row holding layer after layer the words
+    of the mask of experts its router chose.
     """
 
     k: Tuple[jnp.ndarray, ...]
@@ -346,6 +369,15 @@ class PagedKVCache:
     page_table: jnp.ndarray
     lengths: jnp.ndarray
     page_size: int = struct.field(pytree_node=False, default=16)
+    ssm: Tuple[jnp.ndarray, ...] = ()
+    conv: Tuple[jnp.ndarray, ...] = ()
+    routes: Optional[jnp.ndarray] = None
+    counters: Optional[jnp.ndarray] = None
+
+    COUNTER_NAMES = (
+        "moe_assignments", "moe_experts_touched", "moe_load_max",
+        "state_slots_live",
+    )
 
     # ------------------------------------------------------------------
     # construction
@@ -447,6 +479,62 @@ class PagedKVCache:
             quantized=quantized,
         )
 
+    @classmethod
+    def from_spec(
+        cls,
+        spec: Sequence[Dict[str, Any]],
+        num_slots: int,
+        capacity: int,
+        page_size: int = 16,
+        num_pages: Optional[int] = None,
+        dtype: Any = jnp.bfloat16,
+        quantized: bool = False,
+    ) -> "PagedKVCache":
+        """The cache of a model whose layers declare what they keep:
+        ``dict(kind="kv", heads=, head_dim=)`` for a layer that attends
+        (the GLOBAL head count: the engine shards the pools itself),
+        ``dict(kind="ssm", state=, conv=, state_dtype=)`` for one that
+        scans; any layer may add ``counters=True`` and
+        ``route_words=<n>``."""
+        kv_layers = [s for s in spec if s["kind"] == "kv"]
+        ssm_layers = [s for s in spec if s["kind"] == "ssm"]
+        if len(kv_layers) + len(ssm_layers) != len(spec):
+            raise ValueError("a layer declares a kind other than kv or ssm")
+        if not kv_layers:
+            raise ValueError(
+                "the engine schedules by pages: a model with no attending "
+                "layer has none to schedule by")
+        shapes = {(s["heads"], s["head_dim"]) for s in kv_layers}
+        if len(shapes) != 1:
+            raise ValueError(f"attention layers differ in shape: {shapes}")
+        heads, head_dim = shapes.pop()
+        cache = cls.create(
+            len(kv_layers), num_slots, capacity, heads, head_dim,
+            page_size=page_size, num_pages=num_pages, dtype=dtype,
+            quantized=quantized,
+        )
+        words = sum(s.get("route_words", 0) for s in spec)
+        return cache.replace(
+            ssm=tuple(
+                jnp.zeros((num_slots,) + tuple(s["state"]), s["state_dtype"])
+                for s in ssm_layers),
+            conv=tuple(
+                jnp.zeros((num_slots,) + tuple(s["conv"]), dtype)
+                for s in ssm_layers),
+            routes=jnp.zeros(
+                (cache.num_pages, 1, page_size, -(-words // 128) * 128),
+                jnp.uint32) if words else None,
+            counters=jnp.zeros((len(cls.COUNTER_NAMES),), jnp.int32)
+            if any(s.get("counters") for s in spec) else None,
+        )
+
+    def start_tick(self) -> "PagedKVCache":
+        """The tick's counters from zero (a cache that keeps none is
+        returned as it is)."""
+        if self.counters is None:
+            return self
+        return self.replace(counters=jnp.zeros_like(self.counters))
+
     # ------------------------------------------------------------------
     # shape facts
     # ------------------------------------------------------------------
@@ -483,7 +571,10 @@ class PagedKVCache:
         + table + lengths) — the number the bench's cache-bytes line
         reports against the contiguous equivalent."""
         total = 0
-        for arrs in (self.k, self.v, self.k_scale or (), self.v_scale or ()):
+        extra = tuple(
+            a for a in (self.routes, self.counters) if a is not None)
+        for arrs in (self.k, self.v, self.k_scale or (), self.v_scale or (),
+                     self.ssm, self.conv, extra):
             for a in arrs:
                 total += a.size * a.dtype.itemsize
         total += self.page_table.size * self.page_table.dtype.itemsize

@@ -1492,6 +1492,17 @@ class GPTModel(nn.Module):
 
     cfg: GPTConfig
 
+    def cache_spec(self):
+        """What each layer keeps per request, for the serving engine to
+        build its paged cache from: K/V pages in every layer (the
+        GLOBAL head count; the engine shards the pools under tp)."""
+        cfg = self.cfg
+        return [
+            dict(kind="kv", heads=cfg.num_attention_heads,
+                 head_dim=cfg.head_dim)
+            for _ in range(cfg.num_layers)
+        ]
+
     def setup(self):
         cfg = self.cfg
         self.embedding = TransformerEmbedding(cfg, name="embedding")

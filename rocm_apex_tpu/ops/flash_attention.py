@@ -808,6 +808,10 @@ flash_attention_varlen.defvjp(_fav_fwd, _fav_bwd)
 # MXU work per k block than riding the general forward's 128-row
 # minimum q block.
 DECODE_BLOCK_T = 16
+# grouped K/V heads: query heads of one group fold into the paged decode
+# kernel's row axis while the folded block stays at or under this many
+# rows (a 512-row block of scores against a 512-row page is 1 MiB)
+GROUP_FOLD_ROWS = 512
 
 
 def _decode_kernel(
@@ -948,7 +952,7 @@ def flash_attention_decode(
 
 
 def _decode_paged_kernel(
-    scale, nh, ps, num_pages, block_t, quantized,
+    scale, nh, ps, num_pages, block_t, quantized, row_blocks,
     tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 ):
     """Online-softmax decode against a PAGED cache for grid point
@@ -972,8 +976,10 @@ def _decode_paged_kernel(
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
-    slot = b // nh
-    head = b % nh
+    # grouped heads: `row_blocks` query row blocks share a K/V head
+    bh = b if row_blocks == 1 else b // row_blocks
+    slot = bh // nh
+    head = bh % nh
 
     @pl.when(j == 0)
     def _init():
@@ -1045,6 +1051,7 @@ def flash_attention_decode_paged(
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     return_lse: bool = False,
+    _row_blocks: int = 1,
 ):
     """`flash_attention_decode` reading through a block table.
 
@@ -1062,6 +1069,15 @@ def flash_attention_decode_paged(
     actually live — the paged answer to the contiguous kernel's
     fixed-capacity tail DMA.
 
+    GROUPED K/V heads: ``q`` may hold ``g`` query heads per pool head,
+    (num_slots·heads·g, t, head_dim) with query head ``n·g + i``
+    reading pool head n. The g heads of a group differ only in their
+    query rows, so they are folded into the kernel's row axis (a free
+    reshape: as many as keep a row block at or under
+    ``GROUP_FOLD_ROWS``) and a K/V page is fetched once for all of
+    them; what does not fold walks the grid as further row blocks of
+    the same pool head. With g = 1 nothing changes.
+
     ``k_scale``/``v_scale`` ((num_pages, heads) fp32) switch the pools
     to int8 with per-(page, head) dequantization inside the kernel's
     inner loop (the cache-bytes half of the EQuARX trade). Forward
@@ -1076,11 +1092,26 @@ def flash_attention_decode_paged(
         raise ValueError(
             f"pool head_dim {dp} != query head_dim {d0}"
         )
-    if bh != num_slots * nh:
+    if bh % (num_slots * nh * _row_blocks):
         raise ValueError(
-            f"q rows {bh} must equal num_slots {num_slots} * pool "
-            f"heads {nh} (slot-major)"
+            f"q rows {bh} must be num_slots {num_slots} * pool "
+            f"heads {nh} (slot-major) times a whole number of query "
+            f"heads per pool head"
         )
+    group = bh // (num_slots * nh * _row_blocks)
+    if group > 1:
+        fold = max(
+            f for f in range(1, group + 1)
+            if group % f == 0 and (f == 1 or f * t <= GROUP_FOLD_ROWS)
+        )
+        out = flash_attention_decode_paged(
+            q.reshape(bh // fold, fold * t, d0), k_pool, v_pool,
+            page_table, kv_lengths, scale, k_scale, v_scale,
+            return_lse=True, _row_blocks=group // fold,
+        )
+        o, lse = out[0].reshape(bh, t, d0), out[1].reshape(bh, t)
+        return (o, lse) if return_lse else o
+    rb = _row_blocks
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("pass both k_scale and v_scale or neither")
@@ -1094,10 +1125,11 @@ def flash_attention_decode_paged(
     def _page_map(b, j, tab, lens):
         # clamp dead/unmapped steps onto the last LIVE page: a repeated
         # block index is not refetched, so the dead tail costs no DMA
-        slot = b // nh
+        bh = b if rb == 1 else b // rb  # the row block's pool-head row
+        slot, head = bh // nh, bh % nh
         live = jnp.maximum((lens[slot] + ps - 1) // ps, 1)
         jeff = jnp.minimum(j, live - 1)
-        return (jnp.minimum(tab[slot, jeff], num_pages - 1), b % nh, 0, 0)
+        return (jnp.minimum(tab[slot, jeff], num_pages - 1), head, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, block_t, d), lambda b, j, tab, lens: (b, 0, 0)),
@@ -1133,7 +1165,7 @@ def flash_attention_decode_paged(
     o, lse = pallas_call(
         functools.partial(
             _decode_paged_kernel, s, nh, ps, num_pages, block_t,
-            quantized,
+            quantized, rb,
         ),
         grid_spec=grid_spec,
         out_shape=[

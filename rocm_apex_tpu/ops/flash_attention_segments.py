@@ -246,6 +246,16 @@ def _pad3(x, tp, d):
 
 def _seg_fwd(q, k, v, seg, causal, scale, block_q, block_k):
     h, total, d0 = q.shape
+    # grouped K/V heads (forward only): query head b reads K/V head
+    # b // g; with g = 1 the index maps are what they were
+    hk = k.shape[0]
+    if h % hk or v.shape[0] != hk:
+        raise ValueError(f"{h} query heads over {hk}/{v.shape[0]} K/V heads")
+    g = h // hk
+    kv_map = (
+        (lambda b, i, j: (b, j, 0)) if g == 1
+        else (lambda b, i, j: (b // g, j, 0))
+    )
     d, block_q, block_k, tp, segp, ranges = _prepare(q, seg, block_q, block_k)
     qp, kp, vp = (_pad3(x, tp, d) for x in (q, k, v))
     qmin, qmax, kmin, kmax = ranges
@@ -255,8 +265,8 @@ def _seg_fwd(q, k, v, seg, causal, scale, block_q, block_k):
         grid=(h, tp // block_q, tp // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((block_q, 1), lambda b, i, j: (i, 0)),
             pl.BlockSpec((block_k, 1), lambda b, i, j: (j, 0)),
             smem, smem, smem, smem,
@@ -362,6 +372,11 @@ def flash_attention_segments(
     Output rows are specified for every real token (all tokens belong
     to some segment); differentiable in q/k/v.
     """
+    if k.shape[0] != q.shape[0]:
+        raise ValueError(
+            "grouped K/V heads are forward-only "
+            "(flash_attention_segments_with_lse): the backward kernels "
+            "walk one K/V head per query head")
     o, _ = _seg_fwd(
         q, k, v, segment_ids, causal,
         scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]),

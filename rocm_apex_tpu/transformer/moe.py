@@ -29,7 +29,10 @@ import numpy as np
 
 from rocm_apex_tpu.transformer import parallel_state
 
-__all__ = ["SwitchMLP", "switch_route", "load_balancing_loss"]
+__all__ = [
+    "SwitchMLP", "switch_route", "load_balancing_loss",
+    "HeldExperts", "route_top_k",
+]
 
 
 def switch_route(gate_logits: jnp.ndarray, capacity: int):
@@ -153,3 +156,129 @@ class SwitchMLP(nn.Module):
             "tec,ech->th", combine.astype(self.dtype), ye
         )
         return y.reshape(*batch, h), aux
+
+
+# ---------------------------------------------------------------------------
+# routed experts, several per token, none dropped; a share of them held
+# ---------------------------------------------------------------------------
+
+
+def route_top_k(logits: jnp.ndarray, k: int):
+    """The ``k`` largest of each row of float32 router logits and the
+    softmax over those k: (ids (T, k), weights (T, k))."""
+    top, ids = jax.lax.top_k(logits.astype(jnp.float32), k)
+    return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+class HeldExperts(nn.Module):
+    """Routed gated experts plus one shared expert, for a chip that
+    holds experts ``held = (lo, hi)`` of ``num_experts``.
+
+    The router scores all ``num_experts`` and every token goes to its
+    ``top_k``, weighted by the softmax over those k logits. The part of
+    the result that the experts held here give is computed, and no
+    assignment is dropped: each (token, expert) pair that fell on a held
+    expert is one row of a group-by-group layout
+    (`ops/grouped_matmul.py`: a counting sort, then one grouped product
+    into the gate and up halves and one back), so an expert that is
+    given every token computes every token. What the experts held
+    elsewhere would add is left out; the chips that share a layer sum
+    their parts (one all-reduce of the routed output, absent on one
+    chip), so the shared expert, which every chip computes alike, is
+    added by whoever owns that sum: here, since one chip is the whole
+    of it.
+
+    ``live`` (T,) marks the rows that are tokens (padding of the packed
+    chunk and dead rows of the decode grid are not): the others are
+    routed nowhere, cost nothing and count nowhere. Returns the output
+    and the layer's counts: pairs on held experts, held experts that
+    received any, the most one received, and with ``log_chosen`` (a
+    debugging option) the mask of chosen experts per token
+    (``ceil(num_experts / 32)`` words of 32 bits).
+    """
+
+    hidden_size: int
+    num_experts: int
+    held: Tuple[int, int]
+    top_k: int
+    expert_width: int
+    shared_width: int
+    dtype: Any = jnp.bfloat16
+    params_dtype: Any = jnp.bfloat16
+    init_std: float = 0.02
+    log_chosen: bool = False
+
+    @nn.compact
+    def __call__(self, u, live):
+        from rocm_apex_tpu.ops.grouped_matmul import (
+            group_layout, grouped_matmul, row_tile,
+        )
+
+        t, h = u.shape
+        lo, hi = self.held
+        g, f, k = hi - lo, self.expert_width, self.top_k
+        init = nn.initializers.normal(self.init_std)
+        router = self.param(
+            "router", init, (h, self.num_experts), self.params_dtype)
+        w_in = self.param("w_in", init, (g, h, 2 * f), self.params_dtype)
+        w_out = self.param("w_out", init, (g, f, h), self.params_dtype)
+
+        with jax.named_scope("moe_router"):
+            logits = jnp.dot(
+                u.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            ids, weights = route_top_k(logits, k)
+            flat = ids.reshape(t * k) - lo
+            valid = (
+                (flat >= 0) & (flat < g) & jnp.repeat(live, k)
+            )
+            block_m = row_tile(t * k)
+            dest, tile_group, num_live, sizes = group_layout(
+                flat, valid, g, block_m)
+            rows = tile_group.shape[0] * block_m
+            token = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
+            src = jnp.full((rows,), t, jnp.int32).at[dest].set(
+                token, mode="drop")
+        with jax.named_scope("moe_experts"):
+            xs = jnp.take(u, src, axis=0, mode="fill", fill_value=0)
+            ab = grouped_matmul(
+                xs, w_in, tile_group, num_live, block_m=block_m)
+            act = (
+                jax.nn.silu(ab[:, :f].astype(jnp.float32))
+                * ab[:, f:].astype(jnp.float32)
+            ).astype(self.dtype)
+            ys = grouped_matmul(
+                act, w_out, tile_group, num_live, block_m=block_m,
+                block_n=1024)
+            per = jnp.take(
+                ys, dest, axis=0, mode="fill", fill_value=0
+            ).reshape(t, k, h)
+            gate = jnp.where(valid.reshape(t, k), weights, 0.0)
+            out = jnp.einsum(
+                "tk,tkh->th", gate, per.astype(jnp.float32))
+        fs = self.shared_width
+        s_in = self.param("shared_in", init, (h, 2 * fs), self.params_dtype)
+        s_out = self.param("shared_out", init, (fs, h), self.params_dtype)
+        with jax.named_scope("moe_shared"):
+            ab = jnp.dot(u, s_in.astype(self.dtype))
+            act = (
+                jax.nn.silu(ab[:, :fs].astype(jnp.float32))
+                * ab[:, fs:].astype(jnp.float32)
+            ).astype(self.dtype)
+            out = out + jnp.dot(
+                act, s_out.astype(self.dtype),
+                preferred_element_type=jnp.float32)
+        counts = dict(
+            assignments=jnp.sum(sizes),
+            experts_touched=jnp.sum((sizes > 0).astype(jnp.int32)),
+            load_max=jnp.max(sizes),
+        )
+        if self.log_chosen:
+            words = -(-self.num_experts // 32)
+            bit = jnp.left_shift(jnp.uint32(1), (ids % 32).astype(jnp.uint32))
+            counts["chosen"] = jnp.stack([
+                jnp.sum(jnp.where(ids // 32 == w, bit, jnp.uint32(0)), axis=1)
+                for w in range(words)
+            ], axis=0)  # (words, T); the k ids are distinct, so sum == or
+        return out.astype(self.dtype), counts
