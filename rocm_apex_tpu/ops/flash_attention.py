@@ -951,24 +951,89 @@ def flash_attention_decode(
 # ---------------------------------------------------------------------------
 
 
+# What one grid step of the paged decode kernel may hold in VMEM: the K
+# and V tiles of its head block (double-buffered by the pipeline), the
+# query/output/lse blocks and the accumulators of those heads, and their
+# float32 scores (counted for every head, though the heads run in
+# turn). The head block is the largest divisor of the pool's
+# heads that stays inside it (`_paged_head_block`); the call's own
+# scoped-VMEM limit leaves Mosaic its relayout room above it (a v5e
+# core has 128 MiB).
+PAGED_VMEM_BUDGET = 16 << 20
+PAGED_VMEM_LIMIT = 32 << 20
+
+
+def _paged_block_bytes(hb, ps, d, block_t, kv_itemsize, q_itemsize,
+                       quantized):
+    """VMEM bytes of one grid step that takes ``hb`` heads."""
+    kv = 2 * 2 * hb * ps * d * kv_itemsize  # K and V, double-buffered
+    if quantized:
+        kv += 2 * hb * ps * d * q_itemsize  # their dequantized tiles
+    per_row = (
+        2 * 2 * d * q_itemsize  # q and o blocks, double-buffered
+        + 2 * 128 * 4  # the lse block (lane-padded), double-buffered
+        + (2 * 128 + d) * 4  # m, l and acc scratch
+        + ps * 4  # float32 scores
+    )
+    return kv + hb * block_t * per_row
+
+
+def _paged_head_block(nh, ps, d, block_t, kv_itemsize, q_itemsize,
+                      quantized):
+    """Heads a grid step takes: from the shapes alone, never a user's
+    choice. One head is always allowed (the same kernel, head axis 1)."""
+    return max(
+        h for h in range(1, nh + 1)
+        if nh % h == 0 and (
+            h == 1
+            or _paged_block_bytes(
+                h, ps, d, block_t, kv_itemsize, q_itemsize, quantized
+            ) <= PAGED_VMEM_BUDGET
+        )
+    )
+
+
+def _paged_grid_row(b, nhb, row_blocks):
+    """Grid row b of the paged kernel as (slot, head block, row block):
+    slot-major, row blocks innermost (grouped heads: they share a K/V
+    head block, which then stays put across them). b is never negative,
+    so truncating division, and none where a factor is 1."""
+    r = 0
+    if row_blocks > 1:
+        b, r = (
+            jax.lax.div(b, jnp.int32(row_blocks)),
+            jax.lax.rem(b, jnp.int32(row_blocks)),
+        )
+    if nhb == 1:
+        return b, 0, r
+    return (
+        jax.lax.div(b, jnp.int32(nhb)), jax.lax.rem(b, jnp.int32(nhb)), r
+    )
+
+
 def _decode_paged_kernel(
-    scale, nh, ps, num_pages, block_t, quantized, row_blocks,
-    tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+    scale, hb, nhb, ps, num_pages, block_t, quantized, row_blocks,
+    tab_ref, len_ref, src_ref, q_ref, k_ref, v_ref, *rest,
 ):
     """Online-softmax decode against a PAGED cache for grid point
-    (b, j): batch row b = slot·nh + head, j walks the slot's page
-    list. The kv tile for (b, j) was fetched by the scalar-prefetch
-    index maps through the page table, so the kernel sees exactly the
-    pages the slot owns — the fixed-capacity dead tail the contiguous
-    `_decode_kernel` still DMAs (its skip is compute-only) never
-    leaves HBM here: past-the-prefix grid steps re-point their fetch
-    at the last live page, and Pallas elides the DMA for a repeated
-    block index. Same accumulation as `_decode_kernel` (base-2 online
-    softmax, natural-log lse at the boundary).
+    (b, j): b = (slot, head block, row block), slot-major, and j walks
+    the slot's page list. One step takes ``hb`` heads of ONE page: the
+    K and V tiles are the `(hb, page_size, head_dim)` slab of the pool
+    as it is stored, fetched by the scalar-prefetch index maps through
+    the page table, so the kernel sees exactly the pages the slot owns.
+    What the contiguous `_decode_kernel` still DMAs (its skip is
+    compute-only) never leaves HBM here: a step past the slot's live
+    prefix, and every step of a slot with nothing to read, holds the
+    block index of the step before it, and Pallas elides the DMA of a
+    repeated block index. Each head runs the accumulation of
+    `_decode_kernel` (base-2 online softmax, natural-log lse at the
+    boundary) over its own rows of the head-major scratch.
 
     ``quantized`` adds per-(page, head) fp32 dequantization: int8
     tiles are scaled into the score/value dots from SMEM-resident
-    scale tables (one scalar read per tile)."""
+    scale tables (``hb`` scalar reads a step). ``src_ref`` is only the
+    index maps' (`flash_attention_decode_paged`)."""
+    del src_ref
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -976,10 +1041,9 @@ def _decode_paged_kernel(
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
-    # grouped heads: `row_blocks` query row blocks share a K/V head
-    bh = b if row_blocks == 1 else b // row_blocks
-    slot = bh // nh
-    head = bh % nh
+    slot, hblk, _ = _paged_grid_row(b, nhb, row_blocks)
+    head0 = hblk * hb
+    ln = len_ref[slot]
 
     @pl.when(j == 0)
     def _init():
@@ -988,55 +1052,68 @@ def _decode_paged_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _body():
-        ln = len_ref[slot]
-        q = q_ref[0]
-        k = k_ref[0, 0]  # (ps, d)
-        v = v_ref[0, 0]
-        if quantized:
-            # the page this grid step actually fetched (the index-map
-            # clamp replayed in-body so tile and scale can't disagree)
-            live = jnp.maximum((ln + ps - 1) // ps, 1)
-            jeff = jnp.minimum(j, live - 1)
-            page = jnp.minimum(tab_ref[slot, jeff], num_pages - 1)
-            k = (k.astype(jnp.float32) * ks_ref[page, head]).astype(
-                q.dtype
-            )
-            v = (v.astype(jnp.float32) * vs_ref[page, head]).astype(
-                q.dtype
-            )
-        s = jax.lax.dot_general(
-            (q * jnp.asarray(scale * LOG2E, q.dtype)), k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_PREC,
-        )
         col = j * ps + jax.lax.broadcasted_iota(
             jnp.int32, (block_t, ps), 1
         )
-        s = jnp.where(col < ln, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        corr = jnp.exp2(m_prev - m_new)
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
-            p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32, precision=_PREC,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        if quantized:
+            # j is inside the live prefix here, so this is the page the
+            # index map fetched
+            page = jnp.minimum(tab_ref[slot, j], num_pages - 1)
+
+        def _head(h, carry):
+            q = q_ref[0, h, 0]  # (block_t, d)
+            k = k_ref[0, h]  # (ps, d)
+            v = v_ref[0, h]
+            if quantized:
+                k = (
+                    k.astype(jnp.float32) * ks_ref[page, head0 + h]
+                ).astype(q.dtype)
+                v = (
+                    v.astype(jnp.float32) * vs_ref[page, head0 + h]
+                ).astype(q.dtype)
+            s = jax.lax.dot_general(
+                (q * jnp.asarray(scale * LOG2E, q.dtype)), k,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_PREC,
+            )
+            s = jnp.where(col < ln, s, NEG_INF)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(s, axis=1, keepdims=True)
+            )
+            p = jnp.exp2(s - m_new)
+            corr = jnp.exp2(m_prev - m_new)
+            l_new = l_scr[h, :, :1] * corr + jnp.sum(
+                p, axis=1, keepdims=True
+            )
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot(
+                p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32, precision=_PREC,
+            )
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            return carry
+
+        # one traced body for all heads of the block: a Python loop
+        # traces and lowers it hb times at every call site (16 heads x
+        # 72 sites: 115 s of the serving cell's set-up, PERF.md PR 27)
+        jax.lax.fori_loop(0, hb, _head, 0)
 
     # pages wholly past the live prefix: no compute AND no fetch (the
-    # index map re-pointed their DMA at an already-resident page)
-    pl.when(j * ps < len_ref[slot])(_body)
+    # index map held their DMA on an already-resident block)
+    pl.when(j * ps < ln)(_body)
 
     @pl.when(j == nj - 1)
     def _finish():
-        l = l_scr[:, :1]
+        # every step writes its own output block, live or not: a dead
+        # slot's rows are zeros at the -inf tier, which the chunk
+        # read's log-sum-exp merge weighs to exactly zero
+        l = l_scr[:, :, :1]
         safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(
+        o_ref[0, :, 0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
+        lse_ref[0, :, 0] = jnp.where(
             l > 0.0,
-            (m_scr[:, :1] + jnp.log2(safe_l)) * LN2,
+            (m_scr[:, :, :1] + jnp.log2(safe_l)) * LN2,
             NEG_INF,
         )
 
@@ -1061,13 +1138,31 @@ def flash_attention_decode_paged(
     pools, (num_pages, heads, page_size, head_dim); ``page_table`` is
     (num_slots, pages_per_slot) int32 mapping each slot's page list
     into the pool (unmapped entries carry the ``num_pages`` sentinel
-    and are never fetched within a live prefix); ``kv_lengths`` is
-    (num_slots,) int32 — slot s attends cache positions
-    ``[0, kv_lengths[s])``. The grid walks (slot·head, page): each kv
-    tile is ONE page, fetched via a scalar-prefetch index map that
-    resolves the table on the fly, so HBM reads are bounded by pages
-    actually live — the paged answer to the contiguous kernel's
-    fixed-capacity tail DMA.
+    and are never fetched); ``kv_lengths`` is (num_slots,) int32 —
+    slot s attends cache positions ``[0, kv_lengths[s])``, and no
+    further than the pages its table row maps before the first
+    sentinel: a slot that owns no page reads nothing, whatever length
+    it carries (the engine's dead rows carry the capacity sentinel),
+    and emits zeros at the -inf tier.
+
+    The grid walks (slot · head block, page). A grid step takes a
+    BLOCK OF HEADS of one page: all heads of a page are contiguous in
+    the pool, so the K and V tiles are one `(hb, page_size, head_dim)`
+    slab each, fetched via a scalar-prefetch index map that resolves
+    the table on the fly. ``hb`` follows the shapes of the call (the
+    largest divisor of the pool's heads whose blocks fit
+    ``PAGED_VMEM_BUDGET``: all 16 heads of a 512-row bf16 page at the
+    decode step, 8 under a 256-row chunk), so the grid is
+    ``num_slots · heads / hb · pages_per_slot`` steps. HBM reads are
+    bounded by pages actually live — the paged answer to the
+    contiguous kernel's fixed-capacity tail DMA: a step past a slot's
+    live prefix holds the slot's last live page, and a slot with
+    NOTHING to read (length 0) holds whatever block the step before it
+    held (the last block of the nearest live slot before it; the
+    first block of the first live slot when none is), so no tile of a
+    page nobody owns is fetched. Every step still costs its fixed
+    overhead and the slot's small query/output blocks: the grid does
+    not shrink with the load.
 
     GROUPED K/V heads: ``q`` may hold ``g`` query heads per pool head,
     (num_slots·heads·g, t, head_dim) with query head ``n·g + i``
@@ -1076,7 +1171,7 @@ def flash_attention_decode_paged(
     reshape: as many as keep a row block at or under
     ``GROUP_FOLD_ROWS``) and a K/V page is fetched once for all of
     them; what does not fold walks the grid as further row blocks of
-    the same pool head. With g = 1 nothing changes.
+    the same head block. With g = 1 nothing changes.
 
     ``k_scale``/``v_scale`` ((num_pages, heads) fp32) switch the pools
     to int8 with per-(page, head) dequantization inside the kernel's
@@ -1118,23 +1213,66 @@ def flash_attention_decode_paged(
     s = scale if scale is not None else 1.0 / np.sqrt(d0)
     d = _round_up(d0, 128)
     block_t = _round_up(t, DECODE_BLOCK_T)
-    qp = jnp.pad(q, ((0, 0), (0, block_t - t), (0, d - d0)))
+    hb = _paged_head_block(
+        nh, ps, d, block_t, k_pool.dtype.itemsize, q.dtype.itemsize,
+        quantized,
+    )
+    nhb = nh // hb
+    qp = jnp.pad(q, ((0, 0), (0, block_t - t), (0, d - d0))).reshape(
+        num_slots, nh, rb, block_t, d
+    )
     kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d - d0)))
     vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d - d0)))
+    table = jnp.asarray(page_table, jnp.int32)
+    # the table bounds the read: no slot reads past its mapped pages, so
+    # a slot that owns none has nothing to read whatever length it
+    # carries (the engine's dead rows carry the capacity sentinel)
+    mapped = jnp.sum(
+        jnp.cumprod((table < num_pages).astype(jnp.int32), axis=1), axis=1
+    )
+    lens = jnp.minimum(jnp.asarray(kv_lengths, jnp.int32), mapped * ps)
+    # the slot whose block a slot's steps hold: itself when it has
+    # something to read, else the last live slot before it, else the
+    # first live slot after it (the last slot when nothing is live)
+    idx = jnp.arange(num_slots, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(lens > 0, idx, -1))
+    after = jax.lax.cummin(
+        jnp.where(lens > 0, idx, num_slots - 1), reverse=True
+    )
+    src = jnp.where(before >= 0, before, after)
 
-    def _page_map(b, j, tab, lens):
-        # clamp dead/unmapped steps onto the last LIVE page: a repeated
-        # block index is not refetched, so the dead tail costs no DMA
-        bh = b if rb == 1 else b // rb  # the row block's pool-head row
-        slot, head = bh // nh, bh % nh
-        live = jnp.maximum((lens[slot] + ps - 1) // ps, 1)
-        jeff = jnp.minimum(j, live - 1)
-        return (jnp.minimum(tab[slot, jeff], num_pages - 1), head, 0, 0)
+    def _row_map(b, j, tab, lens, src):
+        return (*_paged_grid_row(b, nhb, rb), 0, 0)
+
+    def _page_map(b, j, tab, lens, src):
+        # a repeated block index is not refetched. Past a slot's live
+        # prefix: its last live page. A slot with nothing to read: the
+        # block of the step before its first (the LAST block of the
+        # live slot before it), else the block of the step after its
+        # last (the FIRST block of the live slot after it). Plain lax
+        # primitives: an index map is lowered at every call site.
+        slot, hblk, _ = _paged_grid_row(b, nhb, rb)
+        held = src[slot]
+        dead = lens[slot] == 0
+        first = jnp.logical_and(dead, held >= slot)
+        last_page = jax.lax.max(
+            jax.lax.div(lens[held] + (ps - 1), jnp.int32(ps)), 1
+        ) - 1
+        jeff = jax.lax.select(
+            first, jnp.int32(0),
+            jax.lax.select(dead, last_page, jax.lax.min(j, last_page)),
+        )
+        if nhb > 1:
+            hblk = jax.lax.select(
+                first, jnp.int32(0),
+                jax.lax.select(dead, jnp.int32(nhb - 1), hblk),
+            )
+        return (jax.lax.min(tab[held, jeff], num_pages - 1), hblk, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, block_t, d), lambda b, j, tab, lens: (b, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d), _page_map),
-        pl.BlockSpec((1, 1, ps, d), _page_map),
+        pl.BlockSpec((1, hb, 1, block_t, d), _row_map),
+        pl.BlockSpec((1, hb, ps, d), _page_map),
+        pl.BlockSpec((1, hb, ps, d), _page_map),
     ]
     ins = [qp, kp, vp]
     if quantized:
@@ -1145,41 +1283,39 @@ def flash_attention_decode_paged(
             jnp.asarray(v_scale, jnp.float32),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bh, pages_per_slot),
+        # the page table stays FIRST and two-dimensional: the trace's
+        # readers tell this kernel by it
+        num_scalar_prefetch=3,
+        grid=(num_slots * nhb * rb, pages_per_slot),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec(
-                (1, block_t, d), lambda b, j, tab, lens: (b, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_t, 1), lambda b, j, tab, lens: (b, 0, 0)
-            ),
+            pl.BlockSpec((1, hb, 1, block_t, d), _row_map),
+            pl.BlockSpec((1, hb, 1, block_t, 1), _row_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_t, 128), jnp.float32),
-            pltpu.VMEM((block_t, 128), jnp.float32),
-            pltpu.VMEM((block_t, d), jnp.float32),
+            pltpu.VMEM((hb, block_t, 128), jnp.float32),
+            pltpu.VMEM((hb, block_t, 128), jnp.float32),
+            pltpu.VMEM((hb, block_t, d), jnp.float32),
         ],
     )
     o, lse = pallas_call(
         functools.partial(
-            _decode_paged_kernel, s, nh, ps, num_pages, block_t,
+            _decode_paged_kernel, s, hb, nhb, ps, num_pages, block_t,
             quantized, rb,
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, block_t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, block_t, 1), jnp.float32),
+            jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            jax.ShapeDtypeStruct(qp.shape[:-1] + (1,), jnp.float32),
         ],
-    )(
-        jnp.asarray(page_table, jnp.int32),
-        jnp.asarray(kv_lengths, jnp.int32),
-        *ins,
-    )
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PAGED_VMEM_LIMIT
+        ),
+    )(table, lens, src, *ins)
+    o = o.reshape(bh, block_t, d)[:, :t, :d0]
     if return_lse:
-        return o[:, :t, :d0], lse[:, :t, 0]
-    return o[:, :t, :d0]
+        return o, lse.reshape(bh, block_t)[:, :t]
+    return o
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,9 @@
 """The serving programs, compiled for a described `v5e:2x2`, hold no
 whole-pool copy: a paged K/V write reaches the pool in the layout the
-pool is stored in (`ops/paging.py::paged_scatter`).
+pool is stored in (`ops/paging.py::paged_scatter`). And their paged
+attention reads take a block of heads a grid step
+(`ops/flash_attention.py::flash_attention_decode_paged`): the grid's
+length follows (slot, page), not (slot, head, page).
 
 A row scatter into the `(page, head, row, head_dim)` pool made the TPU
 compiler re-lay every pool twice per write (`copy` to `{3,1,2,0}` and
@@ -27,6 +30,7 @@ HEADS, HEAD_DIM, DEPTH = 16, 128, 2
 SLOTS, PAGES, PAGE_SIZE, CAPACITY, BUDGET = 16, 40, 512, 2048, 256
 POOL_SHAPE = (PAGES, HEADS, PAGE_SIZE, HEAD_DIM)
 POOL_BYTES = PAGES * HEADS * PAGE_SIZE * HEAD_DIM * 2
+PAGES_PER_SLOT = CAPACITY // PAGE_SIZE
 
 
 @pytest.fixture(scope="module")
@@ -77,19 +81,42 @@ def serving_programs(sharding):
     i32, f32 = jnp.int32, jnp.float32
     p, cache = abstract(params), abstract(engine.cache)
     rng = arr((2,), jnp.uint32)
-    return {
-        "mixed": jax.jit(engine._mixed_fn, donate_argnums=(1,)).lower(
+    traced = {
+        "mixed": jax.jit(engine._mixed_fn, donate_argnums=(1,)).trace(
             p, cache, arr((BUDGET,), i32), arr((BUDGET,), i32),
             arr((BUDGET,), i32), arr((SLOTS,), i32), arr((SLOTS,), i32),
             arr((SLOTS,), i32), arr((SLOTS,), i32),
             arr((SLOTS,), jnp.bool_), arr((BUDGET,), f32),
             arr((SLOTS,), f32), rng,
-        ).compile(),
-        "decode": jax.jit(engine._decode_fn, donate_argnums=(1,)).lower(
+        ),
+        "decode": jax.jit(engine._decode_fn, donate_argnums=(1,)).trace(
             p, cache, arr((SLOTS,), i32), arr((SLOTS,), jnp.bool_),
             arr((SLOTS,), f32), rng,
-        ).compile(),
+        ),
     }
+    return (
+        {name: t.lower().compile() for name, t in traced.items()},
+        {name: paged_grids(t.jaxpr.jaxpr) for name, t in traced.items()},
+    )
+
+
+def paged_grids(jaxpr):
+    """Every `pallas_call` under ``jaxpr`` whose first operand is the
+    page table, `s32[slots, pages_per_slot]`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        first = eqn.invars[0].aval if eqn.invars else None
+        if (eqn.primitive.name == "pallas_call"
+                and first.shape == (SLOTS, PAGES_PER_SLOT)
+                and first.dtype == jnp.int32):
+            rows = eqn.outvars[0].aval.shape[-2]
+            found.append((rows, tuple(eqn.params["grid_mapping"].grid)))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(paged_grids(sub))
+    return found
 
 
 _SHAPE = re.compile(r"= \(?(?:bf16|f32|s8|s32|u32)\[([\d,]+)\]")
@@ -115,10 +142,12 @@ def pool_sized(text, opcode):
 
 
 @pytest.fixture(scope="module")
-def programs(one_chip):
-    """Both programs, compiled once. `ops._pallas.on_tpu` is steered to
-    its chip branch, and the suite's persistent compile cache is off
-    meanwhile (a chip program cannot be read back without a chip)."""
+def built(one_chip):
+    """Both programs, compiled once, and from their traces the (query
+    rows, grid) of each `pallas_call` that reads through the page table.
+    `ops._pallas.on_tpu` is steered to its chip branch, and the suite's
+    persistent compile cache is off meanwhile (a chip program cannot be
+    read back without a chip)."""
     from jax.experimental.compilation_cache import compilation_cache
     from rocm_apex_tpu.ops import _pallas
 
@@ -131,6 +160,11 @@ def programs(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def programs(built):
+    return built[0]
 
 
 @pytest.mark.parametrize("name", ["decode", "mixed"])
@@ -147,3 +181,33 @@ def test_no_whole_pool_copy(programs, name):
     aliased = compiled.memory_analysis().alias_size_in_bytes
     pools = 2 * DEPTH * POOL_BYTES
     assert pools <= aliased < pools + (1 << 20), (name, aliased, pools)
+
+
+@pytest.mark.parametrize("name", ["decode", "mixed"])
+def test_paged_reads_take_a_block_of_heads_a_grid_step(built, name):
+    """At the decode shape (one row padded to 16) a paged read walks at
+    most slots x pages-per-slot x heads / 8 grid steps (it walked
+    slots x heads x pages-per-slot = 1,024 with a head a step); the
+    chunk's read of the cache, whose 256 rows bound the block, at most
+    half of that walk. The page table stays the first operand,
+    two-dimensional: `benchmarks/layer_metrics/decode_paged_roofline.py`
+    tells the kernel by it."""
+    grids = built[1][name]
+    steps = {}
+    for rows, grid in grids:
+        steps.setdefault(rows, []).append(grid[0] * grid[1])
+    decode = steps.pop(16)
+    assert len(decode) == DEPTH, grids
+    assert max(decode) <= SLOTS * PAGES_PER_SLOT * HEADS // 8, decode
+    if name == "mixed":
+        chunk = steps.pop(BUDGET)
+        assert len(chunk) == DEPTH, grids
+        assert max(chunk) <= SLOTS * PAGES_PER_SLOT * HEADS // 2, chunk
+    assert not steps, steps
+    text = built[0][name].as_text()
+    table_first = re.findall(
+        r'custom_call_target="tpu_custom_call", '
+        r"operand_layout_constraints=\{s32\[(\d+),(\d+)\]", text)
+    assert table_first == (
+        [(str(SLOTS), str(PAGES_PER_SLOT))] * len(grids)), table_first
+    assert not pool_sized(text, "copy")
