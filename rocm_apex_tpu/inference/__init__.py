@@ -28,6 +28,11 @@ nothing is forked:
                over the engine's failure sites — page allocation,
                device step, logits (NaN/Inf poisoning), host fetch —
                with the shared `NO_FAULTS` null plan on the hot path
+    programs   `StepPrograms`: the ONE definition of the tick's
+               compiled programs (fused chunk+decode, decode alone,
+               whole-prompt prefill, speculative commit, page fork);
+               speculation and an adapter pool add operands to the one
+               body, and ``step_source=`` replicas share the object
     engine     continuous-batching serving loop: fixed slot grid,
                request queue, per-step admit/evict, and the chunked-
                prefill token-budget scheduler — ONE compiled mixed
@@ -74,6 +79,7 @@ from rocm_apex_tpu.inference.paging import (  # noqa: F401
     PagedKVCache,
     PrefixStore,
 )
+from rocm_apex_tpu.inference.programs import StepPrograms  # noqa: F401
 from rocm_apex_tpu.inference.router import (  # noqa: F401
     REPLICA_CLASSES,
     REPLICA_STATES,
@@ -95,6 +101,7 @@ __all__ = [
     "PageAllocator",
     "PrefixStore",
     "InferenceEngine",
+    "StepPrograms",
     "ReplicaRouter",
     "SharedPrefixRegistry",
     "REPLICA_STATES",

@@ -61,7 +61,7 @@ from rocm_apex_tpu.inference.paging import (
     PagedKVCache,
     PrefixStore,
 )
-from rocm_apex_tpu.inference.sampling import sample
+from rocm_apex_tpu.inference.programs import FETCHED, StepPrograms
 from rocm_apex_tpu.monitor.trace import (
     NULL_TRACER,
     mint_trace_id,
@@ -439,6 +439,7 @@ class InferenceEngine:
         self._stateful = any(
             layer["kind"] != "kv" for layer in model.cache_spec()
         )
+        quantized = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
         if self._stateful:
             refused = {
                 "paged=False (its attention layers' K/V is paged; the "
@@ -449,10 +450,7 @@ class InferenceEngine:
                 "a state that was overwritten in place)": spec_k > 0,
                 "tensor_parallel_size > 1": tp > 1,
                 "adapter_pool": adapter_pool is not None,
-                "kv_dtype=int8": (
-                    kv_dtype is not None
-                    and jnp.dtype(kv_dtype) == jnp.int8
-                ),
+                "kv_dtype=int8": quantized,
             }
             for what, asked in refused.items():
                 if asked:
@@ -518,7 +516,7 @@ class InferenceEngine:
         )
         # ---- multi-LoRA serving (ISSUE 18) ---------------------------
         # adapter_pool: an `inference.adapters.AdapterPool` whose
-        # packed device buffers the lora step closures below gather
+        # packed device buffers the step programs (`programs.py`) gather
         # per-token deltas from (ops/lora.py). The pool is engine-owned
         # state like the KV cache: its buffers are donated through the
         # jits and re-bound every tick. Admission acquires one ref per
@@ -526,9 +524,6 @@ class InferenceEngine:
         # `_pick_queued`); every teardown path releases exactly once.
         self.adapter_pool = adapter_pool
         self.tier_preemption = bool(tier_preemption)
-        self._adapter_stalls = 0
-        self._tier_preemptions = 0
-        self._tier_sheds = 0
         # host-side per-tenant completion accounting (the chaos
         # isolation identity: sums across tenants == the global
         # counters) — keyed by TRUE tenant name, unlike the labeled
@@ -570,28 +565,16 @@ class InferenceEngine:
         self.prefix_sharing = bool(prefix_sharing)
         self._allocator = None
         self._store = None
-        self._cow_forks = 0
-        self._prefix_hits = 0
-        self._prefix_hit_tokens = 0
-        self._page_stalls = 0
-        self._preemptions = 0
         # preempted-request carryover: request_id -> (generated tokens,
         # first_token_at, chunk count) restored on re-admission
         self._preempted: Dict[int, Any] = {}
         # page-shipping migration: payloads handed to resume_request(pages=...)
         # wait here until the request leases a slot; fallbacks replay tokens
         self._shipped: Dict[int, Any] = {}
-        self._page_ships = 0
-        self._page_ship_fallbacks = 0
-        # speculative-decoding accounting: every drafted token ends up
-        # either accepted (emitted) or rolled back
-        self._tokens_drafted = 0
-        self._tokens_accepted = 0
-        self._rollbacks = 0
         if not self.paged:
             if prefix_sharing:
                 raise ValueError("prefix_sharing requires paged=True")
-            if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
+            if quantized:
                 raise ValueError("kv_dtype=int8 requires paged=True")
             self.cache = KVCache.for_model(
                 cfg, num_slots, self.capacity, dtype=cache_dtype
@@ -604,9 +587,6 @@ class InferenceEngine:
                     "needs contiguous slot rows); set "
                     "prefill_token_budget"
                 )
-            quantized = (
-                kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
-            )
             self.cache = PagedKVCache.from_spec(
                 # tp>1: GLOBAL head count in the pools; the NamedSharding
                 # below splits dim 1 (heads) over the tensor axis, so
@@ -636,36 +616,14 @@ class InferenceEngine:
                 self.cache.num_pages, np.int32,
             )
             self._table_dirty = False
-            self._fork_jit = jax.jit(
-                lambda cache, src, dst: cache.fork_page(src, dst)
-            )
         self._rng = jax.random.PRNGKey(seed)
         self._queue: collections.deque = collections.deque()
         self._slots: List[Optional[_Slot]] = [None] * num_slots
         self._next_id = 0
-        # Trace counters live in ONE mutable cell so replicas built
-        # with `step_source=` (see below) share it: the fleet traced
-        # each program once, and every replica's `*_trace_count`
-        # reports that shared truth — a retrace anywhere still trips
-        # the `== 1` invariant the tests pin.
-        self._traces = {"prefill": 0, "decode": 0, "mixed": 0,
-                        "commit": 0}
-        # serving telemetry (read via `stats()`, fed to a
-        # monitor.MetricsLogger): monotonic counters + wall-time sums.
-        # Latencies include the result fetch, which waits for the
-        # device (the Timers rule), so these are true end-to-end
-        # numbers, not dispatch times. Per-request
-        # queue waits (enqueue -> slot lease) and TTFTs (enqueue ->
-        # first token) feed the p50/p95 fields that surface the
-        # head-of-line blocking the chunked scheduler removes.
-        self._admitted = 0
-        self._evicted = 0
-        self._prompt_tokens = 0
-        self._generated_tokens = 0
-        self._prefill_seconds = 0.0
-        self._decode_seconds = 0.0
-        self._decode_steps = 0
-        self._mixed_steps = 0
+        self._zero_counters()
+        # Per-request queue waits (enqueue -> slot lease) and TTFTs
+        # (enqueue -> first token) feed the p50/p95 fields that surface
+        # the head-of-line blocking the chunked scheduler removes.
         # Raw per-request samples keep EXACT percentiles while they
         # fit; `stats_retention` caps them (oldest drop) so a
         # long-lived engine has O(1) stats memory. The registry
@@ -800,13 +758,6 @@ class InferenceEngine:
         self.watchdog_timeout = watchdog_timeout
         self.watchdog_dump_path = watchdog_dump_path
         self.flight_recorder = flight_recorder
-        self._cancelled = 0
-        self._deadline_exceeded = 0
-        self._quarantined = 0
-        self._step_retries = 0
-        self._shed = 0
-        self._watchdog_fires = 0
-        self._evacuated = 0
         self._draining = False
         self._tick = 0  # step() count — the fault plans' tick domain
         # queue_full results awaiting delivery through the next step()
@@ -816,259 +767,9 @@ class InferenceEngine:
         self._last_progress = time.perf_counter()
         self._progress_mark = (0, 0, 0)
 
-        if step_source is not None:
-            # Replica fast-path: adopt an existing engine's compiled
-            # step programs instead of re-tracing identical ones. The
-            # traced graphs close over the model object, the sampling
-            # config, the cache geometry, and the donation flag — so
-            # adoption is refused unless all of them match. Used by
-            # ReplicaRouter: an N-replica fleet warms up once, not N
-            # times, and the shared trace-counter cell keeps every
-            # replica's `mixed_trace_count == 1` invariant honest.
-            if donate_buffers is None:
-                donate_buffers = on_tpu()
-            self.donate_buffers = bool(donate_buffers)
-            self._adopt_steps(step_source)
-            return
-
-        sp = self.sampling
-
-        # Model variants for the tp>1 split: the CHUNK apply rides the
-        # sequence-parallel + collective-matmul layout (the packed
-        # stream scatters to (1, budget/tp, h) rows per chip and the
-        # TP-edge collectives fuse into ppermute rings), while the
-        # DECODE apply keeps plain tensor parallelism (a width-1 seq
-        # axis cannot be sequence-sharded). sequence_parallel changes
-        # ZERO parameter shapes, so both variants consume the same
-        # params pytree; at tp=1 both are the caller's model.
-        decode_model = model
-        chunk_model = model
-        if tp > 1:
-            chunk_model = type(model)(
-                cfg=dataclasses.replace(
-                    cfg, sequence_parallel=True, collective_matmul=True
-                )
-            )
-            if cfg.sequence_parallel:
-                decode_model = type(model)(
-                    cfg=dataclasses.replace(
-                        cfg, sequence_parallel=False,
-                        collective_matmul=False,
-                    )
-                )
-
-        if tp > 1:
-            from rocm_apex_tpu.transformer.tensor_parallel import mappings
-
-            tensor_axis = cfg.tensor_axis
-
-            def _full_logits(logits):
-                # the tied head returns VOCAB-PARALLEL logits
-                # (..., vocab/tp); sampling needs the full vocab row.
-                # The gather is replicated-in, replicated-out, so the
-                # sample below is bit-identical on every rank.
-                return mappings.gather_from_tensor_model_parallel_region(
-                    logits, tensor_axis
-                )
-        else:
-            def _full_logits(logits):
-                return logits
-
-        def _sample(rng, logits):
-            return sample(
-                rng,
-                logits,
-                temperature=sp.temperature,
-                top_k=sp.top_k,
-                top_p=sp.top_p,
-            )
-
-        def _prefill(params, cache, tokens, slot, length, rng):
-            # trace-time side effect: counts COMPILES, not calls
-            self._traces["prefill"] += 1
-            sub = cache.slot_view(slot)
-            sub = sub.replace(lengths=jnp.zeros((1,), jnp.int32))
-            logits, sub = decode_model.apply(params, tokens, cache=sub)
-            # the model advanced by the PADDED width; the live prefix
-            # is the real prompt — decode overwrites the pad positions
-            # one by one and never attends past `lengths`
-            sub = sub.replace(
-                lengths=jnp.reshape(length, (1,)).astype(jnp.int32)
-            )
-            cache = cache.write_back(slot, sub)
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], length - 1, 0, keepdims=False
-            )
-            first_tok = _sample(rng, _full_logits(last)[None, :])[0]
-            return first_tok, cache
-
-        is_paged = self.paged
-        dev_capacity = self.cache.capacity
-
-        def _start_tick(cache):
-            # the paged cache's counters of what its layers do in a tick
-            # start from zero; the sums ride the tick's one fetch
-            return cache.start_tick() if is_paged else cache
-
-        def _decode_body(params, cache, tokens, active, poison, rng,
-                         adapters=None):
-            # `poison` is a per-slot fp32 addend on the logits — zeros
-            # on the fault-free path (x + 0.0 leaves the greedy argmax
-            # and the sampling distribution untouched), NaN/Inf when
-            # the chaos harness poisons one slot. The per-slot
-            # nonfinite flag is computed IN-GRAPH and rides the same
-            # batched fetch as the sampled tokens, so fault isolation
-            # costs no extra device sync and no extra trace — it also
-            # catches a genuine model blow-up for free.
-            lengths0 = cache.lengths
-            if is_paged:
-                # dead rows write at the device capacity sentinel: the
-                # paged scatter DROPS the write (a contiguous cache
-                # tolerates dead-row junk because the next prefill
-                # overwrites it, but a paged junk write could land in
-                # a live — even SHARED — page, and under int8 would
-                # inflate that page's running scale)
-                cache = cache.replace(
-                    lengths=jnp.where(
-                        active, lengths0,
-                        jnp.full_like(lengths0, dev_capacity),
-                    )
-                )
-            logits, new_cache = decode_model.apply(
-                params, tokens[:, None], cache=cache, adapters=adapters
-            )
-            # pin inactive slots' lengths (their dead-row writes drop
-            # (paged) or land in junk the next prefill overwrites
-            # (contiguous), but unbounded drift would saturate the
-            # clamp)
-            new_cache = new_cache.replace(
-                lengths=jnp.where(
-                    active, new_cache.lengths, lengths0
-                )
-            )
-            last = _full_logits(logits[:, -1, :]) + poison[:, None]
-            bad = jnp.any(~jnp.isfinite(last), axis=-1)
-            tok = _sample(rng, last)
-            return jnp.where(active, tok, 0), bad, new_cache
-
-        def _decode(params, cache, tokens, active, poison, rng):
-            self._traces["decode"] += 1
-            return _decode_body(
-                params, _start_tick(cache), tokens, active, poison, rng)
-
-        def _mixed(
-            params, cache, chunk_tokens, chunk_slots, chunk_pos,
-            lengths_before, lengths_after, completion_idx,
-            dec_tokens, dec_active, chunk_poison, dec_poison, rng,
-        ):
-            """ONE compiled program per tick: packed prefill chunk +
-            the whole decode grid. The host is the source of truth for
-            per-slot lengths (a freed slot's stale device length must
-            never bound a successor's reads), so the cursor vectors
-            ride in as arguments. ``completion_idx[slot]`` is the chunk
-            index of the slot's LAST prompt token when its prefill
-            completes this tick (else -1): its sampled first token is
-            fed STRAIGHT into the decode grid, so a completing request
-            gets its second token in the same tick — exactly the
-            whole-prompt path's admit-tick cadence, with no padded
-            prefill."""
-            self._traces["mixed"] += 1
-            rng_c, rng_d = jax.random.split(rng)
-            cache = _start_tick(cache).replace(lengths=lengths_before)
-            logits_c, cache = chunk_model.apply(
-                params,
-                chunk_tokens[None, :],
-                cache=cache,
-                chunk=(chunk_slots, chunk_pos),
-            )
-            logits_c = _full_logits(logits_c)
-            # sample EVERY chunk position (fixed shape); the host keeps
-            # only the positions that completed a prompt this tick.
-            # `chunk_poison` follows the decode-grid poison contract:
-            # zeros normally, NaN/Inf on a quarantine-test row — the
-            # per-row nonfinite flags share the tick's one fetch.
-            logits_p = logits_c[0] + chunk_poison[:, None]
-            chunk_bad = jnp.any(~jnp.isfinite(logits_p), axis=-1)
-            chunk_tok = _sample(rng_c, logits_p)
-            # commit the chunk: cursors advance by what was packed
-            cache = cache.replace(lengths=lengths_after)
-            budget = chunk_tokens.shape[0]
-            has_comp = completion_idx >= 0
-            first_tok = chunk_tok[
-                jnp.clip(completion_idx, 0, budget - 1)
-            ]
-            dec_tokens = jnp.where(has_comp, first_tok, dec_tokens)
-            dec_active = dec_active | has_comp
-            dec_tok, dec_bad, cache = _decode_body(
-                params, cache, dec_tokens, dec_active, dec_poison, rng_d
-            )
-            return chunk_tok, dec_tok, chunk_bad, dec_bad, cache
-
-        def _mixed_spec(
-            params, cache, chunk_tokens, chunk_slots, chunk_pos,
-            commit_slots, lengths_before, lengths_after, completion_idx,
-            dec_tokens, dec_active, chunk_poison, dec_poison, rng,
-        ):
-            """Speculative variant of `_mixed`: the chunk may carry,
-            per decoding slot, that slot's last generated token plus up
-            to k drafted continuations. Those rows score against the
-            slot's committed prefix in the SAME fused trace (they are
-            just budget tokens — no per-k shapes), but their K/V must
-            NOT commit in-trace: a rejected draft can never be unwound
-            from a shared page or an int8 scale that only grows, and
-            the contiguous decode grid's dead-row write would clobber
-            an eagerly-committed row. So every speculative row carries
-            the pad sentinel in ``commit_slots`` (the scatter drops
-            it), the model hands back the packed per-layer chunk K/V,
-            and the host commits exactly the accepted prefix afterwards
-            (`_commit`). One compiled program per engine run:
-            ``mixed_trace_count`` stays 1 at any k."""
-            self._traces["mixed"] += 1
-            rng_c, rng_d = jax.random.split(rng)
-            cache = cache.replace(lengths=lengths_before)
-            logits_c, cache, chunk_kv = chunk_model.apply(
-                params,
-                chunk_tokens[None, :],
-                cache=cache,
-                chunk=(chunk_slots, chunk_pos, commit_slots),
-            )
-            logits_c = _full_logits(logits_c)
-            # sample EVERY chunk position: for a draft row the sample
-            # IS the verifier's token — greedy accepts on equality,
-            # and under temperature the sample-vs-draft equality test
-            # is exact rejection sampling for a point-mass drafter
-            logits_p = logits_c[0] + chunk_poison[:, None]
-            chunk_bad = jnp.any(~jnp.isfinite(logits_p), axis=-1)
-            chunk_tok = _sample(rng_c, logits_p)
-            cache = cache.replace(lengths=lengths_after)
-            budget = chunk_tokens.shape[0]
-            has_comp = completion_idx >= 0
-            first_tok = chunk_tok[
-                jnp.clip(completion_idx, 0, budget - 1)
-            ]
-            dec_tokens = jnp.where(has_comp, first_tok, dec_tokens)
-            dec_active = dec_active | has_comp
-            dec_tok, dec_bad, cache = _decode_body(
-                params, cache, dec_tokens, dec_active, dec_poison, rng_d
-            )
-            return chunk_tok, dec_tok, chunk_bad, dec_bad, cache, chunk_kv
-
-        n_layers = len(self.cache.k)
-
-        def _commit(cache, chunk_kv, slots, positions):
-            """Post-verification commit: write the accepted rows'
-            packed chunk K/V into the cache (`write_at` drops the pad
-            sentinel rows). Fixed (budget,) shapes — ONE compiled
-            commit program per engine run."""
-            self._traces["commit"] += 1
-            ck, cv = chunk_kv
-            for i in range(n_layers):
-                cache = cache.write_at(i, slots, positions, ck[i], cv[i])
-            return cache
-
         # cache buffers are DONATED: the step updates them in place on
         # TPU. On CPU (the test platform) the default is NO donation —
-        # the fault-retry path (`_call_device`) re-runs a step from the
+        # the fault-retry path (`_run_program`) re-runs a step from the
         # caller's still-live buffers, which donation would have
         # deleted. `donate_buffers` overrides the gate both ways (the
         # graph-contract linter lowers a donating engine to verify the
@@ -1076,215 +777,44 @@ class InferenceEngine:
         if donate_buffers is None:
             donate_buffers = on_tpu()
         self.donate_buffers = bool(donate_buffers)
-        donate = (1,) if self.donate_buffers else ()
-        self._prefill_fn = _prefill
-        self._decode_fn = _decode_body
-        self._mixed_fn = _mixed
-        self._mixed_spec_fn = _mixed_spec
-        self._commit_fn = _commit
-        if tp > 1:
-            # One shard_map per step program, jitted around the whole
-            # region: replicated host inputs (token buffers, masks,
-            # cursors, rng) ride in with P(); the cache rides its
-            # head-sharded spec; params are the repo's fake-replicated
-            # idiom (global shape == local shape, per-rank contents),
-            # so P() hands each rank its own shard. check_vma=False:
-            # the sampled tokens are replicated by construction (the
-            # vocab gather), not by anything the rep checker can see.
-            from jax import shard_map
-
-            P = jax.sharding.PartitionSpec
-            rep = P()
-            cspec = self._cache_pspec()
-            kv_spec = tuple(
-                P(None, cfg.tensor_axis, None) for _ in range(n_layers)
-            )
-            mesh = self._mesh
-
-            def _shmap(f, n_rep_in, out_specs):
-                return shard_map(
-                    f, mesh=mesh,
-                    in_specs=(rep, cspec) + (rep,) * n_rep_in,
-                    out_specs=out_specs,
-                    check_vma=False,
-                )
-
-            _decode = _shmap(_decode, 4, (rep, rep, cspec))
-            _mixed = _shmap(_mixed, 11, (rep, rep, rep, rep, cspec))
-            _mixed_spec = _shmap(
-                _mixed_spec, 12,
-                (rep, rep, rep, rep, cspec, (kv_spec, kv_spec)),
-            )
-            _commit = shard_map(
-                _commit, mesh=mesh,
-                in_specs=(cspec, (kv_spec, kv_spec), rep, rep),
-                out_specs=cspec,
-                check_vma=False,
-            )
-        self._prefill_jit = jax.jit(_prefill, donate_argnums=donate)
-        self._decode_jit = jax.jit(_decode, donate_argnums=donate)
-        self._mixed_jit = jax.jit(_mixed, donate_argnums=donate)
-        self._mixed_spec_jit = jax.jit(_mixed_spec, donate_argnums=donate)
-        self._commit_jit = jax.jit(
-            _commit, donate_argnums=(0,) if self.donate_buffers else ()
+        # The compiled step programs, defined once in `programs.py`.
+        # `spec` and `lora` are features this engine DERIVES (spec_k >
+        # 0, an adapter pool), not options of their own: each adds
+        # operands to the one tick body. Building the object traces
+        # nothing (jit is lazy), so only what a tick runs is compiled.
+        self.programs = StepPrograms(
+            model, self.sampling, self.cache,
+            budget=self.prefill_token_budget, spec_k=self.spec_k,
+            adapter_buffers=(
+                adapter_pool.buffers if adapter_pool is not None else None
+            ),
+            mesh=self._mesh,
+            cache_pspec=self._cache_pspec() if tp > 1 else None,
+            donate_buffers=self.donate_buffers,
         )
-
-        # ---- multi-LoRA step programs (adapter_pool engines only).
-        # Separate closures with the adapter-buffer pytree as argument
-        # 2 — the BASE programs above are byte-identical with or
-        # without a pool (their graphlint fingerprints never move).
-        # The buffers are donated alongside the cache and returned
-        # pass-through, so the output aliases the input allocation and
-        # the host re-binds `pool.buffers` each tick exactly like
-        # `self.cache`. Adapter IDS are data: any tenant mix, any
-        # park/reclaim churn, and any adapter registration all ride
-        # ONE compiled program (`mixed_trace_count` stays 1).
-        self._decode_lora_fn = None
-        self._mixed_lora_fn = None
-        self._decode_lora_jit = None
-        self._mixed_lora_jit = None
-        if self.adapter_pool is not None:
-            def _decode_lora(
-                params, cache, adapters, tokens, active, dec_adp,
-                poison, rng,
-            ):
-                self._traces["decode"] += 1
-                full = dict(
-                    adapters, ids=dec_adp,
-                    active=jnp.any(dec_adp != 0),
-                )
-                tok, bad, cache = _decode_body(
-                    params, cache, tokens, active, poison, rng,
-                    adapters=full,
-                )
-                return tok, bad, cache, adapters
-
-            def _mixed_lora(
-                params, cache, adapters, chunk_tokens, chunk_slots,
-                chunk_pos, chunk_adp, lengths_before, lengths_after,
-                completion_idx, dec_tokens, dec_active, dec_adp,
-                chunk_poison, dec_poison, rng,
-            ):
-                """`_mixed` with per-token adapter ids riding next to
-                the slot ids/positions: ``chunk_adp`` (budget,) maps
-                each packed prompt token to its pool buffer slot,
-                ``dec_adp`` (S,) each decode row. ``active`` flags
-                (any id != 0, computed in-trace) arm the `apply_lora`
-                skip branch — a pure-base tick runs zero adapter
-                FLOPs in this same program."""
-                self._traces["mixed"] += 1
-                rng_c, rng_d = jax.random.split(rng)
-                cache = cache.replace(lengths=lengths_before)
-                chunk_full = dict(
-                    adapters, ids=chunk_adp,
-                    active=jnp.any(chunk_adp != 0),
-                )
-                logits_c, cache = chunk_model.apply(
-                    params,
-                    chunk_tokens[None, :],
-                    cache=cache,
-                    chunk=(chunk_slots, chunk_pos),
-                    adapters=chunk_full,
-                )
-                logits_c = _full_logits(logits_c)
-                logits_p = logits_c[0] + chunk_poison[:, None]
-                chunk_bad = jnp.any(~jnp.isfinite(logits_p), axis=-1)
-                chunk_tok = _sample(rng_c, logits_p)
-                cache = cache.replace(lengths=lengths_after)
-                budget = chunk_tokens.shape[0]
-                has_comp = completion_idx >= 0
-                first_tok = chunk_tok[
-                    jnp.clip(completion_idx, 0, budget - 1)
-                ]
-                dec_tokens = jnp.where(has_comp, first_tok, dec_tokens)
-                dec_active = dec_active | has_comp
-                dec_full = dict(
-                    adapters, ids=dec_adp,
-                    active=jnp.any(dec_adp != 0),
-                )
-                dec_tok, dec_bad, cache = _decode_body(
-                    params, cache, dec_tokens, dec_active, dec_poison,
-                    rng_d, adapters=dec_full,
-                )
-                return (
-                    chunk_tok, dec_tok, chunk_bad, dec_bad, cache,
-                    adapters,
-                )
-
-            donate_l = (1, 2) if self.donate_buffers else ()
-            self._decode_lora_fn = _decode_lora
-            self._mixed_lora_fn = _mixed_lora
-            self._decode_lora_jit = jax.jit(
-                _decode_lora, donate_argnums=donate_l
-            )
-            self._mixed_lora_jit = jax.jit(
-                _mixed_lora, donate_argnums=donate_l
-            )
+        if step_source is not None:
+            self._adopt_steps(step_source)
 
     def _adopt_steps(self, src: "InferenceEngine") -> None:
-        """Alias `src`'s compiled step programs (and the trace-counter
-        cell they increment) into this engine. The traced graphs bake
-        in everything checked here; a mismatch would silently retrace
-        per call or, worse, run the wrong geometry — so refuse loudly.
-        """
-        def _shapes(tree):
-            return jax.tree_util.tree_map(
-                lambda a: (
-                    tuple(getattr(a, "shape", ())),
-                    str(getattr(a, "dtype", type(a).__name__)),
-                ),
-                tree,
-            )
+        """Replica fast-path: run `src`'s step programs (and count on
+        the trace counters they increment) instead of re-tracing
+        identical ones. Used by ReplicaRouter: an N-replica fleet warms
+        up once, not N times, and the shared counters keep every
+        replica's `mixed_trace_count == 1` invariant honest. Refused
+        (ValueError) unless the programs this engine would build are
+        the ones `src` built."""
+        src.programs.compatible_with(self.programs)
+        self.programs = src.programs
 
-        mismatches = []
-        if src.model is not self.model:
-            mismatches.append("model (must be the SAME object)")
-        if src.sampling != self.sampling:
-            mismatches.append("sampling")
-        if src.prefill_token_budget != self.prefill_token_budget:
-            mismatches.append("prefill_token_budget")
-        if src.spec_k != self.spec_k:
-            mismatches.append("spec_k")
-        if src.paged != self.paged:
-            mismatches.append("paged")
-        if src.donate_buffers != self.donate_buffers:
-            mismatches.append("donate_buffers")
-        if type(src.cache) is not type(self.cache):
-            mismatches.append("cache layout")
-        elif _shapes(src.cache) != _shapes(self.cache):
-            mismatches.append(
-                "cache geometry (num_slots/capacity/page_size/dtype)"
-            )
-        if (src.adapter_pool is None) != (self.adapter_pool is None):
-            mismatches.append("adapter_pool presence")
-        elif self.adapter_pool is not None and _shapes(
-            src.adapter_pool.buffers
-        ) != _shapes(self.adapter_pool.buffers):
-            mismatches.append(
-                "adapter pool geometry (max_resident/max_rank)"
-            )
-        if mismatches:
-            raise ValueError(
-                "step_source engine is incompatible; differs in: "
-                + ", ".join(mismatches)
-            )
-        self._traces = src._traces
-        self._prefill_fn = src._prefill_fn
-        self._decode_fn = src._decode_fn
-        self._mixed_fn = src._mixed_fn
-        self._mixed_spec_fn = src._mixed_spec_fn
-        self._commit_fn = src._commit_fn
-        self._prefill_jit = src._prefill_jit
-        self._decode_jit = src._decode_jit
-        self._mixed_jit = src._mixed_jit
-        self._mixed_spec_jit = src._mixed_spec_jit
-        self._commit_jit = src._commit_jit
-        self._decode_lora_fn = src._decode_lora_fn
-        self._mixed_lora_fn = src._mixed_lora_fn
-        self._decode_lora_jit = src._decode_lora_jit
-        self._mixed_lora_jit = src._mixed_lora_jit
-        if self.paged:
-            self._fork_jit = src._fork_jit
+    # `tests/benchmarks/test_compile_v5e.py` lowers these two for a
+    # described v5e; everything else reads `engine.programs`
+    @property
+    def _mixed_fn(self):
+        return self.programs.mixed_fn
+
+    @property
+    def _decode_fn(self):
+        return self.programs.decode_fn
 
     # ------------------------------------------------------------------
     # tp>1 cache layout
@@ -1299,45 +829,25 @@ class InferenceEngine:
         `_cache_sharding`) as the initial device layout."""
         P = jax.sharding.PartitionSpec
         axis = self.model.cfg.tensor_axis
-        n = len(self.cache.k)
-        pool = P(None, axis, None, None)
-        sc = P(None, axis)
+        cache = self.cache
+
+        def each(arrays, spec):
+            return None if arrays is None else tuple(spec for _ in arrays)
+
+        pool, scale = P(None, axis, None, None), P(None, axis)
         return PagedKVCache(
-            k=tuple(pool for _ in range(n)),
-            v=tuple(pool for _ in range(n)),
-            k_scale=(
-                None if self.cache.k_scale is None
-                else tuple(sc for _ in range(n))
-            ),
-            v_scale=(
-                None if self.cache.v_scale is None
-                else tuple(sc for _ in range(n))
-            ),
-            page_table=P(),
-            lengths=P(),
-            page_size=self.cache.page_size,
+            k=each(cache.k, pool), v=each(cache.v, pool),
+            k_scale=each(cache.k_scale, scale),
+            v_scale=each(cache.v_scale, scale),
+            page_table=P(), lengths=P(), page_size=cache.page_size,
         )
 
     def _cache_sharding(self):
         """`NamedSharding` pytree for `jax.device_put` of the cache."""
-        mesh = self._mesh
-        spec = self._cache_pspec()
-        ns = lambda s: jax.sharding.NamedSharding(mesh, s)
-        n = len(self.cache.k)
-        return PagedKVCache(
-            k=tuple(ns(s) for s in spec.k),
-            v=tuple(ns(s) for s in spec.v),
-            k_scale=(
-                None if spec.k_scale is None
-                else tuple(ns(s) for s in spec.k_scale)
-            ),
-            v_scale=(
-                None if spec.v_scale is None
-                else tuple(ns(s) for s in spec.v_scale)
-            ),
-            page_table=ns(spec.page_table),
-            lengths=ns(spec.lengths),
-            page_size=spec.page_size,
+        return jax.tree_util.tree_map(
+            lambda s: jax.sharding.NamedSharding(self._mesh, s),
+            self._cache_pspec(),
+            is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec),
         )
 
     def per_chip_kv_bytes(self) -> int:
@@ -1378,15 +888,15 @@ class InferenceEngine:
 
     @property
     def prefill_trace_count(self) -> int:
-        return self._traces["prefill"]
+        return self.programs.traces["prefill"]
 
     @property
     def decode_trace_count(self) -> int:
-        return self._traces["decode"]
+        return self.programs.traces["decode"]
 
     @property
     def mixed_trace_count(self) -> int:
-        return self._traces["mixed"]
+        return self.programs.traces["mixed"]
 
     def has_work(self) -> bool:
         return (
@@ -1697,19 +1207,37 @@ class InferenceEngine:
             "ttft_ms_p95": _pct_ms(self._ttfts, self._h_ttft, 95),
         }
 
+    def _zero_counters(self) -> None:
+        """The monotonic counters and wall-time sums `stats()` reports,
+        named in ONE place: the constructor and `reset_stats` both
+        start from here. The latencies include the result fetch, which
+        waits for the device (the Timers rule), so they are true
+        end-to-end numbers, not dispatch times."""
+        self._admitted = self._evicted = 0
+        self._prompt_tokens = self._generated_tokens = 0
+        self._prefill_seconds = self._decode_seconds = 0.0
+        self._decode_steps = self._mixed_steps = 0
+        # paging
+        self._cow_forks = self._prefix_hits = self._prefix_hit_tokens = 0
+        self._page_stalls = self._preemptions = 0
+        self._page_ships = self._page_ship_fallbacks = 0
+        # speculation: every drafted token ends up either accepted
+        # (emitted) or rolled back
+        self._tokens_drafted = self._tokens_accepted = self._rollbacks = 0
+        # robustness
+        self._cancelled = self._deadline_exceeded = self._quarantined = 0
+        self._step_retries = self._shed = self._watchdog_fires = 0
+        self._evacuated = 0
+        # adapters
+        self._adapter_stalls = 0
+        self._tier_preemptions = self._tier_sheds = 0
+
     def reset_stats(self) -> None:
         """Zero the telemetry counters and per-request distributions.
         Compiled programs, trace counters, and cache state are
         untouched — benchmarks warm the compiles up on the same engine,
         then measure a clean window."""
-        self._admitted = 0
-        self._evicted = 0
-        self._prompt_tokens = 0
-        self._generated_tokens = 0
-        self._prefill_seconds = 0.0
-        self._decode_seconds = 0.0
-        self._decode_steps = 0
-        self._mixed_steps = 0
+        self._zero_counters()
         self._queue_waits.clear()
         self._ttfts.clear()
         self._completions.clear()
@@ -1732,26 +1260,6 @@ class InferenceEngine:
                 self._h_ttft.labels(tenant="other")
                 self._tenant_label_ok = {"other"}
                 self._tenant_overflowed = set()
-        self._cow_forks = 0
-        self._prefix_hits = 0
-        self._prefix_hit_tokens = 0
-        self._page_stalls = 0
-        self._preemptions = 0
-        self._page_ships = 0
-        self._page_ship_fallbacks = 0
-        self._tokens_drafted = 0
-        self._tokens_accepted = 0
-        self._rollbacks = 0
-        self._cancelled = 0
-        self._deadline_exceeded = 0
-        self._quarantined = 0
-        self._step_retries = 0
-        self._shed = 0
-        self._watchdog_fires = 0
-        self._evacuated = 0
-        self._adapter_stalls = 0
-        self._tier_preemptions = 0
-        self._tier_sheds = 0
         self._tenant_counts.clear()
         # the watchdog's progress snapshot tracks counters just zeroed
         self._progress_mark = (0, 0, 0)
@@ -2675,7 +2183,7 @@ class InferenceEngine:
             # device copy first (one compiled program for every fork),
             # then remap: the sharers keep reading the source page —
             # their bytes are never touched
-            self.cache = self._fork_jit(
+            self.cache = self.programs.fork(
                 self.cache, jnp.int32(page), jnp.int32(dst)
             )
             self._allocator.decref(page, park=self._page_registered(page))
@@ -2999,30 +2507,48 @@ class InferenceEngine:
 
     # -- robustness internals ------------------------------------------
 
-    def _maybe_fail_fetch(self) -> None:
-        """The ``host_fetch`` fault site: between the device call and
-        the value fetch — the retry wrapper sees it like any other
-        transient failure."""
-        if self.faults.enabled and self.faults.fire(
-            "host_fetch", tick=self._tick,
-        ) is not None:
-            raise FaultInjected(
-                f"injected host_fetch fault (tick {self._tick})"
-            )
+    def _fetch(self, values):
+        """``engine.fetch``: ONE batched `device_get` (= the device
+        sync) — never a per-request scalar pull."""
+        with self.tracer.phase("engine.fetch", track="engine"):
+            return jax.device_get(values)
 
-    def _call_device(self, thunk):
-        """Run one compiled step (+ its fetch) with the ``device_step``
-        fault site and capped exponential-backoff retry. ``thunk``
-        performs the jitted call and the fetch and RETURNS the new
-        cache instead of assigning it — `self.cache` only moves
-        forward on success, so a retry re-runs against the pre-step
-        cache and the rng split already made (bitwise-deterministic
-        recovery on CPU, where buffers are not donated; on TPU a
-        genuine mid-step failure consumes the donated cache and the
-        retry surfaces that — the requeue path below still runs).
-        On exhaustion every in-flight slot preempts-and-requeues via
-        the PR-8 path, then the failure propagates."""
+    def _run_program(self, name: str, operands, fetch: bool = True):
+        """The one way a tick reaches the device: run step program
+        ``name`` of `self.programs` on ``operands`` (host arrays in the
+        program's positional order, between the engine's state and the
+        key) and return ``(values, counters, kept, t0, t1)``.
+
+        ``engine.rng`` is the key split — eager dispatches of its own,
+        made ONCE so that a retry replays the same key.
+        ``engine.dispatch`` is the uploads and the jitted call until it
+        returns; ``engine.fetch`` the one `device_get` of the program's
+        leading `FETCHED` outputs (sampled tokens, nonfinite flags) and
+        of the new cache's tick counters (None where it keeps none) —
+        left to the caller with ``fetch=False``, for one fetch over
+        several calls. ``kept`` is what else the program returned and
+        stays on the device (the speculative chunk's K/V; else None);
+        ``t0``/``t1`` bracket the device call for the caller's books.
+
+        The call and its fetch retry with capped exponential backoff
+        (the ``device_step`` and ``host_fetch`` fault sites fail them
+        on purpose). What the program DONATES and returns — the cache,
+        and a pool's adapter buffers — is re-bound only on success, so
+        a retry re-runs against the pre-step state and the split
+        already made: bitwise-deterministic recovery on CPU, where
+        buffers are not donated; on TPU a genuine mid-step failure
+        consumes the donated cache and the retry surfaces that. On
+        exhaustion every in-flight slot preempts-and-requeues
+        (`_requeue_in_flight`), then the failure propagates."""
+        with self.tracer.phase("engine.rng", track="engine"):
+            self._rng, rng = jax.random.split(self._rng)
+        program = getattr(self.programs, name)
+        pool = self.adapter_pool
+        state = (self.cache,) if pool is None else (self.cache, pool.buffers)
+        n = FETCHED[name]
+        m = n + len(state)
         attempt = 0
+        t0 = time.perf_counter()
         while True:
             try:
                 if self.faults.enabled and self.faults.fire(
@@ -3031,7 +2557,22 @@ class InferenceEngine:
                     raise FaultInjected(
                         f"injected device_step fault (tick {self._tick})"
                     )
-                return thunk()
+                with self.tracer.phase("engine.dispatch", track="engine"):
+                    out = program(
+                        self.params, *state,
+                        *(jnp.asarray(a) for a in operands), rng,
+                    )
+                    # between the device call and the value fetch
+                    if self.faults.enabled and self.faults.fire(
+                        "host_fetch", tick=self._tick,
+                    ) is not None:
+                        raise FaultInjected(
+                            f"injected host_fetch fault (tick {self._tick})"
+                        )
+                values = out[:n], out[n].counters if self.paged else None
+                if fetch:
+                    values = self._fetch(values)
+                break
             except Exception:
                 if attempt >= self.max_step_retries:
                     self._requeue_in_flight()
@@ -3047,6 +2588,11 @@ class InferenceEngine:
                         self.step_retry_backoff * (2 ** (attempt - 1)),
                         1.0,
                     ))
+        self.cache = out[n]
+        if pool is not None:
+            pool.buffers = out[n + 1]
+        return (*values, out[m] if len(out) > m else None,
+                t0, time.perf_counter())
 
     def _requeue_in_flight(self) -> None:
         """Device-step retries exhausted: hand every in-flight request
@@ -3480,179 +3026,51 @@ class InferenceEngine:
             with self.tracer.phase("engine.table_push", track="engine"):
                 self._push_table()
 
-        chunk_out = None
-        dec_out = None
-        chunk_bad = None
-        dec_bad = None
-        chunk_kv = None
-        layer_counts = None
-        spec_t0 = spec_t1 = 0.0
+        chunk_out = dec_out = chunk_bad = dec_bad = None
+        chunk_kv = layer_counts = None
         program = "none"
-        # ``engine.rng`` is the tick's key split (eager dispatches of
-        # its own, made once so that a retry replays the same key);
-        # each thunk is the tick's ``engine.dispatch`` (the uploads
-        # and the jitted call until it returns) and ``engine.fetch``
-        # (the one batched `device_get`, = the device sync)
-        if self.spec_k > 0 and (used > 0 or active.any()):
-            # speculative engines ALWAYS run the (single) spec mixed
-            # program, even on draft-free ticks: the decode-only fast
-            # path reads device-resident lengths, which the host-side
-            # accept walk outruns — here the host cursors ride in as
-            # arguments every tick, and one program means
-            # mixed_trace_count == 1 at any k
-            program = "spec"
-            with self.tracer.phase("engine.rng", track="engine"):
-                self._rng, rng = jax.random.split(self._rng)
-            t0 = time.perf_counter()
-
-            def _spec_thunk():
-                with self.tracer.phase("engine.dispatch", track="engine"):
-                    chunk_tok, dec_tok, cbad, dbad, cache, kv = (
-                        self._mixed_spec_jit(
-                            self.params, self.cache,
-                            jnp.asarray(chunk_tokens),
-                            jnp.asarray(chunk_slots),
-                            jnp.asarray(chunk_pos),
-                            jnp.asarray(commit_slots),
-                            jnp.asarray(lengths_before),
-                            jnp.asarray(lengths_after),
-                            jnp.asarray(completion_idx),
-                            jnp.asarray(dec_tokens),
-                            jnp.asarray(active),
-                            jnp.asarray(chunk_poison),
-                            jnp.asarray(dec_poison), rng,
-                        )
-                    )
-                    self._maybe_fail_fetch()
-                # ONE batched value fetch per tick; chunk_kv stays on
-                # device for the commit program. The nonfinite flags
-                # ride the same fetch.
-                with self.tracer.phase("engine.fetch", track="engine"):
-                    fetched = jax.device_get(
-                        (chunk_tok, dec_tok, cbad, dbad)
-                    )
-                return fetched, cache, kv
-
-            fetched, self.cache, chunk_kv = self._call_device(
-                _spec_thunk
+        # a feature's operands ride where `StepPrograms.mixed_operands`
+        # puts them: the adapter ids of an engine with a pool, the
+        # `commit_slots` of a speculative one
+        dec_extra = () if pool is None else (dec_adp,)
+        if used > 0 or (self.spec_k > 0 and active.any()):
+            # speculative engines ALWAYS run the mixed program, even on
+            # draft-free ticks: the decode-only fast path reads
+            # device-resident lengths, which the host-side accept walk
+            # outruns — here the host cursors ride in as arguments
+            # every tick, and one program means mixed_trace_count == 1
+            # at any k. The SAME fused chunk+decode program serves any
+            # adapter mix — ids are data, so adapter add / park /
+            # reclaim churn never retraces.
+            program = "spec" if self.spec_k > 0 else "mixed"
+            chunk_extra = (
+                ((commit_slots,) if self.spec_k > 0 else ())
+                + (() if pool is None else (chunk_adp,))
+            )
+            fetched, layer_counts, chunk_kv, t0, t1 = self._run_program(
+                "mixed", (
+                    chunk_tokens, chunk_slots, chunk_pos, *chunk_extra,
+                    lengths_before, lengths_after, completion_idx,
+                    dec_tokens, active, *dec_extra,
+                    chunk_poison, dec_poison,
+                ),
             )
             chunk_out, dec_out, chunk_bad, dec_bad = fetched
-            t1 = time.perf_counter()
-            spec_t0, spec_t1 = t0, t1
             if prefill_used > 0:
                 self._prefill_seconds += t1 - t0
                 self._mixed_steps += 1
-            else:
+            else:  # a speculative tick with no prompt token in it
                 self._decode_seconds += t1 - t0
             if active.any() or completions or spec_entries:
                 self._decode_steps += 1
-        elif used > 0:
-            program = "mixed"
-            with self.tracer.phase("engine.rng", track="engine"):
-                self._rng, rng = jax.random.split(self._rng)
-            t0 = time.perf_counter()
-
-            def _mixed_thunk():
-                with self.tracer.phase("engine.dispatch", track="engine"):
-                    if pool is None:
-                        chunk_tok, dec_tok, cbad, dbad, cache = (
-                            self._mixed_jit(
-                                self.params, self.cache,
-                                jnp.asarray(chunk_tokens),
-                                jnp.asarray(chunk_slots),
-                                jnp.asarray(chunk_pos),
-                                jnp.asarray(lengths_before),
-                                jnp.asarray(lengths_after),
-                                jnp.asarray(completion_idx),
-                                jnp.asarray(dec_tokens),
-                                jnp.asarray(active),
-                                jnp.asarray(chunk_poison),
-                                jnp.asarray(dec_poison), rng,
-                            )
-                        )
-                        adapters = None
-                    else:
-                        # the SAME fused chunk+decode program for any
-                        # adapter mix — ids are data, so adapter add /
-                        # park / reclaim churn never retraces
-                        (chunk_tok, dec_tok, cbad, dbad, cache,
-                         adapters) = self._mixed_lora_jit(
-                            self.params, self.cache, pool.buffers,
-                            jnp.asarray(chunk_tokens),
-                            jnp.asarray(chunk_slots),
-                            jnp.asarray(chunk_pos),
-                            jnp.asarray(chunk_adp),
-                            jnp.asarray(lengths_before),
-                            jnp.asarray(lengths_after),
-                            jnp.asarray(completion_idx),
-                            jnp.asarray(dec_tokens),
-                            jnp.asarray(active),
-                            jnp.asarray(dec_adp),
-                            jnp.asarray(chunk_poison),
-                            jnp.asarray(dec_poison), rng,
-                        )
-                    self._maybe_fail_fetch()
-                # ONE batched value fetch per tick — never a
-                # per-request scalar pull; the nonfinite flags ride
-                # the same fetch
-                with self.tracer.phase("engine.fetch", track="engine"):
-                    return jax.device_get(
-                        (chunk_tok, dec_tok, cbad, dbad,
-                         cache.counters if self.paged else None)
-                    ), cache, adapters
-
-            fetched, self.cache, new_adp = self._call_device(
-                _mixed_thunk
-            )
-            if new_adp is not None:
-                # re-bind the donated adapter buffers (like the cache,
-                # they only move forward on step success)
-                pool.buffers = new_adp
-            chunk_out, dec_out, chunk_bad, dec_bad, layer_counts = fetched
-            t1 = time.perf_counter()
-            self._prefill_seconds += t1 - t0
-            self._mixed_steps += 1
-            if active.any() or completions:
-                self._decode_steps += 1
         elif active.any():
             program = "decode"
-            with self.tracer.phase("engine.rng", track="engine"):
-                self._rng, rng = jax.random.split(self._rng)
-            t0 = time.perf_counter()
-
-            def _decode_thunk():
-                with self.tracer.phase("engine.dispatch", track="engine"):
-                    if pool is None:
-                        tok, bad, cache = self._decode_jit(
-                            self.params, self.cache,
-                            jnp.asarray(dec_tokens),
-                            jnp.asarray(active),
-                            jnp.asarray(dec_poison), rng,
-                        )
-                        adapters = None
-                    else:
-                        tok, bad, cache, adapters = (
-                            self._decode_lora_jit(
-                                self.params, self.cache, pool.buffers,
-                                jnp.asarray(dec_tokens),
-                                jnp.asarray(active),
-                                jnp.asarray(dec_adp),
-                                jnp.asarray(dec_poison), rng,
-                            )
-                        )
-                    self._maybe_fail_fetch()
-                with self.tracer.phase("engine.fetch", track="engine"):
-                    return jax.device_get(
-                        (tok, bad, cache.counters if self.paged else None)
-                    ), cache, adapters
-
-            fetched, self.cache, new_adp = self._call_device(
-                _decode_thunk
+            (dec_out, dec_bad), layer_counts, _, t0, t1 = (
+                self._run_program(
+                    "decode",
+                    (dec_tokens, active, *dec_extra, dec_poison),
+                )
             )
-            if new_adp is not None:
-                pool.buffers = new_adp
-            dec_out, dec_bad, layer_counts = fetched
-            t1 = time.perf_counter()
             self._decode_seconds += t1 - t0
             self._decode_steps += 1
         if self.tracer.enabled and packed:
@@ -3810,7 +3228,7 @@ class InferenceEngine:
                             "draft", t_d0, t_d1, track=track, tokens=n,
                         )
                         self.tracer.add_span(
-                            "verify", spec_t0, spec_t1, track=track,
+                            "verify", t0, t1, track=track,
                             drafted=n, accepted=accepted, slot=slot,
                         )
                         if n - accepted > 0:
@@ -3836,7 +3254,7 @@ class InferenceEngine:
                 if any_commit:
                     if self.paged:
                         self._push_table()  # CoW forks from the clamp above
-                    self.cache = self._commit_jit(
+                    self.cache = self.programs.commit(
                         self.cache, chunk_kv,
                         jnp.asarray(commit_np), jnp.asarray(commit_pos_np),
                     )
@@ -3866,12 +3284,11 @@ class InferenceEngine:
                 )
             toks = np.zeros((1, self.max_prompt_len), np.int32)
             toks[0, : len(req.prompt)] = req.prompt
-            self._rng, rng = jax.random.split(self._rng)
-            with self.tracer.phase("engine.dispatch", track="engine"):
-                tok, self.cache = self._prefill_jit(
-                    self.params, self.cache, jnp.asarray(toks),
-                    slot, len(req.prompt), rng,
-                )
+            # not fetched here: every admit of the tick shares ONE
+            # fetch below
+            (tok,), _, _, _, _ = self._run_program(
+                "prefill", (toks, slot, len(req.prompt)), fetch=False,
+            )
             self._admitted += 1
             self._prompt_tokens += len(req.prompt)
             prefilled += len(req.prompt)
@@ -3885,8 +3302,7 @@ class InferenceEngine:
             # ONE batched value fetch for every admit this tick (the
             # device sync) — the per-request int(tok) pull serialized
             # host and device once per admitted request
-            with self.tracer.phase("engine.fetch", track="engine"):
-                first_toks = jax.device_get([t for _, t in pending])
+            first_toks = self._fetch([t for _, t in pending])
             now = time.perf_counter()
             self._prefill_seconds += now - t_admit
             for (slot, _), tok in zip(pending, first_toks):
@@ -3917,18 +3333,11 @@ class InferenceEngine:
                  for s in self._slots],
                 np.int32,
             )
-            self._rng, rng = jax.random.split(self._rng)
-            t0 = time.perf_counter()
             poison = np.zeros((self.num_slots,), np.float32)
-            with self.tracer.phase("engine.dispatch", track="engine"):
-                tok, bad, self.cache = self._decode_jit(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(active), jnp.asarray(poison), rng,
-                )
-            # value fetch = device sync
-            with self.tracer.phase("engine.fetch", track="engine"):
-                toks, bad_h = jax.device_get((tok, bad))
-            self._decode_seconds += time.perf_counter() - t0
+            (toks, bad_h), _, _, t0, t1 = self._run_program(
+                "decode", (tokens, active, poison),
+            )
+            self._decode_seconds += t1 - t0
             self._decode_steps += 1
         with self.tracer.phase("engine.commit", track="engine"):
             counts = {

@@ -428,7 +428,7 @@ class TestEngineLora:
         register(twin_pool, "t1")
         twin = make_engine(model_and_params, twin_pool,
                            step_source=src_l)
-        assert twin._mixed_lora_jit is src_l._mixed_lora_jit
+        assert twin.programs is src_l.programs
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ The ISSUE-17 acceptance bar as executable checks:
 
 Engine tests reuse the test_inference shape tuple (fp32_cfg model,
 slots=2, capacity=24, budget=4, page_size=4) so the persistent compile
-cache pays each paged program once (tools/tier1_budget.json contract).
+cache pays each paged program once.
 The tp=2 programs are a new geometry and compile cold once per cache
 generation.
 """

@@ -246,7 +246,7 @@ class TestWholePromptMode:
             e["name"] for e in tracer.events()
             if e["ph"] == "X" and e["name"].startswith("engine.")}
         assert phases == {
-            "engine.tick", "engine.admit", "engine.dispatch",
+            "engine.tick", "engine.admit", "engine.rng", "engine.dispatch",
             "engine.fetch", "engine.commit"}
 
 

@@ -21,7 +21,7 @@ import pytest
 from benchmarks.families import granite_hybrid as fam
 from benchmarks.harness import rehearsal
 from rocm_apex_tpu.inference import InferenceEngine, SamplingParams
-from rocm_apex_tpu.inference import engine as engine_mod
+from rocm_apex_tpu.inference import programs as programs_mod
 from rocm_apex_tpu.models.hybrid import HybridModel
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -83,7 +83,7 @@ def recorded(monkeypatch):
             lambda x: rows.extend(np.asarray(x, np.float32)), logits)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    monkeypatch.setattr(engine_mod, "sample", recording_sample)
+    monkeypatch.setattr(programs_mod, "sample", recording_sample)
     return rows
 
 

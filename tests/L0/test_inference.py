@@ -14,7 +14,7 @@ activation in the mixed step (`monitor.audit.assert_no_intermediate`).
 
 Every engine in this file shares ONE shape tuple (slots=2, capacity=24,
 budget=4, the fp32_cfg model) so the persistent compile cache pays each
-program once — the tier-1 wall-time contract (tools/tier1_budget.json).
+program once.
 """
 
 import jax
@@ -564,7 +564,7 @@ class TestChunkedPrefill:
         )
         h, v = cfg.hidden_size, cfg.vocab_size
         report = assert_no_intermediate(
-            eng._mixed_fn, (1, 18, h), *args
+            eng.programs.mixed_fn, (1, 18, h), *args
         )
         for shape in [
             (S, 18, h), (1, 18, v), (1, 24, h), (S, 24, h), (1, 24, v),
@@ -573,7 +573,7 @@ class TestChunkedPrefill:
         # contrast: the whole-prompt prefill materializes its pad width
         weng = whole_engine(model, params)
         wreport = audit(
-            weng._prefill_fn, weng.params, weng.cache,
+            weng.programs.prefill_fn, weng.params, weng.cache,
             jnp.zeros((1, 24), i32), 0, 18, rng,
         )
         assert wreport.has_intermediate((1, 24, h))

@@ -81,15 +81,16 @@ def serving_programs(sharding):
     i32, f32 = jnp.int32, jnp.float32
     p, cache = abstract(params), abstract(engine.cache)
     rng = arr((2,), jnp.uint32)
+    programs = engine.programs
     traced = {
-        "mixed": jax.jit(engine._mixed_fn, donate_argnums=(1,)).trace(
+        "mixed": jax.jit(programs.mixed_fn, donate_argnums=(1,)).trace(
             p, cache, arr((BUDGET,), i32), arr((BUDGET,), i32),
             arr((BUDGET,), i32), arr((SLOTS,), i32), arr((SLOTS,), i32),
             arr((SLOTS,), i32), arr((SLOTS,), i32),
             arr((SLOTS,), jnp.bool_), arr((BUDGET,), f32),
             arr((SLOTS,), f32), rng,
         ),
-        "decode": jax.jit(engine._decode_fn, donate_argnums=(1,)).trace(
+        "decode": jax.jit(programs.decode_fn, donate_argnums=(1,)).trace(
             p, cache, arr((SLOTS,), i32), arr((SLOTS,), jnp.bool_),
             arr((SLOTS,), f32), rng,
         ),

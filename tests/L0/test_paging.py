@@ -12,7 +12,7 @@ ROADMAP item exists for.
 
 Engine tests reuse test_inference's exact shape tuple (fp32_cfg model,
 slots=2, capacity=24, budget=4) so the persistent compile cache pays
-each paged program once (tools/tier1_budget.json contract).
+each paged program once.
 """
 
 import jax

@@ -14,8 +14,8 @@ step still traces exactly ONCE — the poison/flag plumbing adds
 
 Every engine here shares test_inference's shape tuple (slots=2,
 capacity=24, budget=4, the fp32_cfg model; page_size=4 for the paged
-layouts) so the persistent compile cache pays each program once — the
-tier-1 wall-time contract (tools/tier1_budget.json). The fault-free
+layouts) so the persistent compile cache pays each program once. The
+fault-free
 references are TWO module-scoped runs (contiguous + paged) at
 ``MAX_REF`` tokens: greedy decoding is a deterministic per-slot stream,
 so every shorter or truncated run in this file compares against a

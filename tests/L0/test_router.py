@@ -15,7 +15,7 @@ streams bucket-for-bucket.
 Every engine here shares test_inference/test_robustness's shape tuple
 (slots=2, capacity=24, budget=4, the fp32_cfg model; page_size=4 for
 the paged layouts) so the persistent compile cache pays each program
-once — the tier-1 wall-time contract (tools/tier1_budget.json). The
+once. The
 fault-free references are module-scoped single-engine runs at
 ``MAX_REF`` tokens: greedy decoding is a deterministic per-slot
 stream, so every shorter run compares against a bitwise PREFIX of the

@@ -15,9 +15,9 @@ wedging, with greedy output unchanged.
 
 Engines reuse test_inference's model config (fp32_cfg, slots=2,
 capacity=24); speculative engines share ONE budget (6 = slots × (k+1)
-at k=2) so the persistent compile cache pays each spec program once
-(tools/tier1_budget.json contract), and baselines use the budget-4
-tuple the rest of the suite already compiled.
+at k=2) so the persistent compile cache pays each spec program once,
+and baselines use the budget-4 tuple the rest of the suite already
+compiled.
 """
 
 import jax
@@ -349,7 +349,7 @@ class TestSpecAudit:
         )
         h, v = cfg.hidden_size, cfg.vocab_size
         report = assert_no_intermediate(
-            eng._mixed_spec_fn, (1, 24, h), *args
+            eng.programs.mixed_fn, (1, 24, h), *args
         )
         for shape in [(S, 24, h), (1, 24, v), (1, 18, h)]:
             assert not report.has_intermediate(shape), shape

@@ -53,15 +53,14 @@ import shutil  # noqa: E402
 
 from rocm_apex_tpu.utils.compile_cache import (  # noqa: E402
     DEFAULT_CACHE_DIR,
-    CompileCacheCounters,
     enable_compile_cache,
 )
 
 # The suite's wall time is dominated by XLA compiles of configs that do
 # not change between runs; with the cache a re-run skips straight to
-# execution. A cold suite measured 1116 s on this 8-core box, against
-# the tier-1 timeout of 870 s, so a checkout whose cache is still empty
-# adopts, once, the cache that conftests before PR 21 kept under /tmp.
+# execution. A cold suite measured 1116 s in one process on this 8-core
+# box, so a checkout whose cache is still empty adopts, once, the cache
+# that conftests before PR 21 kept under /tmp.
 # Delete this block when no box holds such a directory any more.
 _LEGACY_CACHE = "/tmp/rocm_apex_tpu_jax_cache"
 if (
@@ -93,64 +92,7 @@ else:
     if not os.environ.get("APEX_TPU_TEST_KEEP_OPTS"):
         jax.config.update("jax_disable_most_optimizations", True)
 
-import json  # noqa: E402
-
 import pytest  # noqa: E402
-
-# ---------------------------------------------------------------------
-# Wall-time observability: the tier-1 suite lives ~25 s under the
-# driver's 870 s kill (ROADMAP open items), and every PR so far has
-# re-discovered that by timing out. Dump per-test durations
-# (setup+call+teardown) after every session; tools/check_tier1_budget.py
-# diffs the dump against the checked-in tools/tier1_budget.json and
-# fails when NEW tests add more than the budgeted cold seconds —
-# turning the recurring wall-time fire into a tracked metric.
-_DURATIONS_PATH = os.environ.get(
-    "APEX_TPU_TEST_DURATIONS", "/tmp/_t1_durations.json"
-)
-_durations = {}
-
-# Persistent-compile-cache observability: the budget above assumes the
-# cache works. Count the backend's own cache events so every durations
-# dump says how much of the run actually compiled — a silently cold
-# cache (cleared directory, bumped jax, changed XLA flags) shows up as
-# hit_ratio 0 in tools/check_tier1_budget.py instead of as a mystery
-# wall-time regression.
-_compile_cache = CompileCacheCounters()
-
-
-def pytest_runtest_logreport(report):
-    _durations[report.nodeid] = (
-        _durations.get(report.nodeid, 0.0) + report.duration
-    )
-
-
-def pytest_sessionfinish(session, exitstatus):
-    if not _durations:
-        return
-    try:
-        with open(_DURATIONS_PATH, "w") as f:
-            json.dump(
-                {
-                    "total_seconds": round(sum(_durations.values()), 3),
-                    "compile_cache": {
-                        **_compile_cache.counts,
-                        "hit_ratio": round(
-                            _compile_cache.counts["hits"]
-                            / max(1, _compile_cache.counts["requests"]),
-                            3,
-                        ),
-                    },
-                    "durations": {
-                        k: round(v, 3) for k, v in _durations.items()
-                    },
-                },
-                f,
-                indent=0,
-                sort_keys=True,
-            )
-    except OSError:
-        pass  # a read-only /tmp must not fail the suite
 
 
 @pytest.fixture(autouse=True)

@@ -235,7 +235,7 @@ def _build_serve_mixed():
     budget, ns = eng.prefill_token_budget, eng.num_slots
     i32 = lambda shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     subject = LintSubject.from_jit(
-        "serve_mixed", eng._mixed_jit,
+        "serve_mixed", eng.programs.mixed,
         eng.params, eng.cache,
         i32((budget,)), i32((budget,)), i32((budget,)),   # tokens/slots/pos
         i32((ns,)), i32((ns,)),                           # lengths before/after
@@ -295,7 +295,7 @@ def _build_serve_mixed_lora():
     h, pp = cfg.hidden_size, 4
     i32 = lambda shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     subject = LintSubject.from_jit(
-        "serve_mixed_lora", eng._mixed_lora_jit,
+        "serve_mixed_lora", eng.programs.mixed,
         eng.params, eng.cache, pool.buffers,
         i32((budget,)), i32((budget,)), i32((budget,)),   # tokens/slots/pos
         i32((budget,)),                                   # chunk adapter ids
@@ -363,7 +363,7 @@ def _build_serve_mixed_tp2():
     budget, ns = eng.prefill_token_budget, eng.num_slots
     i32 = lambda shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     subject = LintSubject.from_jit(
-        "serve_mixed_tp2", eng._mixed_jit,
+        "serve_mixed_tp2", eng.programs.mixed,
         eng.params, eng.cache,
         i32((budget,)), i32((budget,)), i32((budget,)),   # tokens/slots/pos
         i32((ns,)), i32((ns,)),                           # lengths before/after
