@@ -40,8 +40,9 @@ discarded and their lengths pinned) — uniform shapes beat ragged
 dispatch, the same padded-slot trade the training stack's pipeline
 microbatching makes.
 
-Determinism: one engine-owned PRNG key, split once per compiled call;
-a fixed seed replays the exact token stream for the same arrival
+Determinism: one engine-owned PRNG key, device state like the cache,
+split once per compiled call INSIDE the call (`programs.py`); a fixed
+seed replays the exact token stream for the same arrival
 order regardless of wall-clock timing.
 """
 
@@ -278,8 +279,8 @@ class InferenceEngine:
     Whatever the tracer, every tick is one ``apex/engine.tick``
     profiler annotation (`monitor.trace.phase`) tiled by its phases
     ``engine.admit`` → ``engine.pack`` → ``engine.table_push`` →
-    ``engine.rng`` → ``engine.dispatch`` → ``engine.fetch`` →
-    ``engine.commit``, the tick's counts (program, decodes, chunk
+    ``engine.dispatch`` → ``engine.fetch`` → ``engine.commit``, the
+    tick's counts (program, decodes, chunk
     tokens, slots, pages, queue depth, admitted, finished) riding as
     the tick's metadata; `add_request` is ``apex/engine.enqueue``. A
     `jax.profiler` capture holds them on the device planes' clock;
@@ -616,7 +617,10 @@ class InferenceEngine:
                 self.cache.num_pages, np.int32,
             )
             self._table_dirty = False
-        self._rng = jax.random.PRNGKey(seed)
+        # the sampling key is device state like the cache: every step
+        # program splits it inside itself and hands the new state back
+        # (`_run_program` re-binds it); the host never splits it
+        self._rng = self._replicated(jax.random.PRNGKey(seed))
         self._queue: collections.deque = collections.deque()
         self._slots: List[Optional[_Slot]] = [None] * num_slots
         self._next_id = 0
@@ -841,6 +845,16 @@ class InferenceEngine:
             v_scale=each(cache.v_scale, scale),
             page_table=P(), lengths=P(), page_size=cache.page_size,
         )
+
+    def _replicated(self, x):
+        """``x`` on the tp mesh's replicated layout (as it is where
+        there is no mesh): the step pytree never mixes device
+        assignments, and a state that comes back from a program goes in
+        with the type it came back with (no second trace)."""
+        if self._mesh is None:
+            return x
+        return jax.device_put(x, jax.sharding.NamedSharding(
+            self._mesh, jax.sharding.PartitionSpec()))
 
     def _cache_sharding(self):
         """`NamedSharding` pytree for `jax.device_put` of the cache."""
@@ -2002,17 +2016,8 @@ class InferenceEngine:
         """Sync the host page-table mirror to the device pytree (once
         per tick, only when the mapping changed)."""
         if self._table_dirty:
-            table = jnp.asarray(self._table)
-            if self._mesh is not None:
-                # keep the replacement on the mesh layout (replicated)
-                # so the step pytree never mixes device assignments
-                table = jax.device_put(
-                    table,
-                    jax.sharding.NamedSharding(
-                        self._mesh, jax.sharding.PartitionSpec()
-                    ),
-                )
-            self.cache = self.cache.replace(page_table=table)
+            self.cache = self.cache.replace(
+                page_table=self._replicated(jnp.asarray(self._table)))
             self._table_dirty = False
 
     def _export_slot_pages(self, st: _Slot, slot: int):
@@ -2519,34 +2524,36 @@ class InferenceEngine:
         program's positional order, between the engine's state and the
         key) and return ``(values, counters, kept, t0, t1)``.
 
-        ``engine.rng`` is the key split — eager dispatches of its own,
-        made ONCE so that a retry replays the same key.
-        ``engine.dispatch`` is the uploads and the jitted call until it
-        returns; ``engine.fetch`` the one `device_get` of the program's
-        leading `FETCHED` outputs (sampled tokens, nonfinite flags) and
-        of the new cache's tick counters (None where it keeps none) —
-        left to the caller with ``fetch=False``, for one fetch over
-        several calls. ``kept`` is what else the program returned and
-        stays on the device (the speculative chunk's K/V; else None);
-        ``t0``/``t1`` bracket the device call for the caller's books.
+        ONE call into the runtime on the way in, the jitted program,
+        and no eager device operation. ``engine.dispatch`` is that call
+        until it returns: the `numpy` operands go to it as they are (its
+        own argument path puts them on the device) and the sampling key
+        is split INSIDE the program, which hands the new key state back
+        beside the cache. ``engine.fetch`` is the one `device_get` of
+        the program's leading `FETCHED` outputs (sampled tokens,
+        nonfinite flags) and of the new cache's tick counters (None
+        where it keeps none) — left to the caller with ``fetch=False``,
+        for one fetch over several calls. ``kept`` is what else the
+        program returned and stays on the device (the speculative
+        chunk's K/V; else None); ``t0``/``t1`` bracket the device call
+        for the caller's books.
 
         The call and its fetch retry with capped exponential backoff
         (the ``device_step`` and ``host_fetch`` fault sites fail them
-        on purpose). What the program DONATES and returns — the cache,
-        and a pool's adapter buffers — is re-bound only on success, so
-        a retry re-runs against the pre-step state and the split
-        already made: bitwise-deterministic recovery on CPU, where
-        buffers are not donated; on TPU a genuine mid-step failure
-        consumes the donated cache and the retry surfaces that. On
-        exhaustion every in-flight slot preempts-and-requeues
-        (`_requeue_in_flight`), then the failure propagates."""
-        with self.tracer.phase("engine.rng", track="engine"):
-            self._rng, rng = jax.random.split(self._rng)
+        on purpose). What the program returns to be kept — the key
+        state, the DONATED cache and a pool's adapter buffers — is
+        re-bound only on success, so a retry re-runs against the
+        pre-step state and replays the same key: bitwise-deterministic
+        recovery on CPU, where buffers are not donated; on TPU a genuine
+        mid-step failure consumes the donated cache and the retry
+        surfaces that. On exhaustion every in-flight slot
+        preempts-and-requeues (`_requeue_in_flight`), then the failure
+        propagates — and the key has NOT advanced (when the host split
+        it, before ISSUE 29, a failed tick consumed one split)."""
         program = getattr(self.programs, name)
         pool = self.adapter_pool
         state = (self.cache,) if pool is None else (self.cache, pool.buffers)
         n = FETCHED[name]
-        m = n + len(state)
         attempt = 0
         t0 = time.perf_counter()
         while True:
@@ -2559,9 +2566,7 @@ class InferenceEngine:
                     )
                 with self.tracer.phase("engine.dispatch", track="engine"):
                     out = program(
-                        self.params, *state,
-                        *(jnp.asarray(a) for a in operands), rng,
-                    )
+                        self.params, *state, *operands, self._rng)
                     # between the device call and the value fetch
                     if self.faults.enabled and self.faults.fire(
                         "host_fetch", tick=self._tick,
@@ -2569,7 +2574,7 @@ class InferenceEngine:
                         raise FaultInjected(
                             f"injected host_fetch fault (tick {self._tick})"
                         )
-                values = out[:n], out[n].counters if self.paged else None
+                values = out[:n], out[n + 1].counters if self.paged else None
                 if fetch:
                     values = self._fetch(values)
                 break
@@ -2588,10 +2593,10 @@ class InferenceEngine:
                         self.step_retry_backoff * (2 ** (attempt - 1)),
                         1.0,
                     ))
-        self.cache = out[n]
+        self._rng, self.cache, *rest = out[n:]
         if pool is not None:
-            pool.buffers = out[n + 1]
-        return (*values, out[m] if len(out) > m else None,
+            pool.buffers = rest.pop(0)
+        return (*values, rest[0] if rest else None,
                 t0, time.perf_counter())
 
     def _requeue_in_flight(self) -> None:

@@ -9,9 +9,18 @@ feature set the ENGINE derives from its configuration, never a user's
 flag: ``spec`` (``spec_k > 0``) and ``lora`` (an adapter pool). A
 feature adds OPERANDS to the one body (`_mixed` says which and
 why); it never forks the body. `mixed_operands` / `decode_operands`
-are the positional signatures; every tick program returns ``(*fetched,
+are the positional signatures; every program returns ``(*fetched, key,
 cache[, adapters][, chunk_kv])``, the `FETCHED` leading values being
-the tick's one `device_get`.
+the tick's one `device_get` and what follows them the state the engine
+re-binds on success.
+
+The sampling key is such a state. Every program takes the engine's key
+where a host-made subkey would go, begins with ``key, rng =
+jax.random.split(key)`` and returns the new ``key``: the stream is the
+one a host-side ``key, rng = split(key)`` per call gives, bit for bit,
+with no eager dispatch on the host. The programs hold no key: engines
+sharing them pass each its own, and a call that fails has advanced
+nothing.
 
 Engines sharing a `StepPrograms` (``step_source=``) share its jitted
 callables and its trace counters: a fleet traces each program once,
@@ -31,7 +40,7 @@ __all__ = ["StepPrograms", "FETCHED"]
 
 #: how many leading outputs of each tick program the host fetches (the
 #: sampled tokens and the per-row nonfinite flags); what follows is the
-#: state the engine re-binds on success
+#: state the engine re-binds on success, the sampling key first
 FETCHED = {"prefill": 1, "decode": 2, "mixed": 4}
 
 
@@ -140,8 +149,9 @@ class StepPrograms:
                 top_p=sampling.top_p,
             )
 
-        def _prefill(params, cache, tokens, slot, length, rng):
+        def _prefill(params, cache, tokens, slot, length, key):
             traces["prefill"] += 1
+            key, rng = jax.random.split(key)
             sub = cache.slot_view(slot)
             sub = sub.replace(lengths=jnp.zeros((1,), jnp.int32))
             logits, sub = decode_model.apply(params, tokens, cache=sub)
@@ -156,7 +166,7 @@ class StepPrograms:
                 logits[0], length - 1, 0, keepdims=False
             )
             first_tok = _sample(rng, _full_logits(last)[None, :])[0]
-            return first_tok, cache
+            return first_tok, key, cache
 
         dev_capacity = cache.capacity
 
@@ -217,11 +227,11 @@ class StepPrograms:
 
         # The positional signatures, in one place (a tuple times a bool:
         # there or not). An engine with neither feature passes 6 and 13
-        # operands, the raw key last.
+        # operands, its raw key state last.
         self.decode_operands = (
             ("params", "cache") + ("adapters",) * lora
             + ("tokens", "active") + ("dec_adp",) * lora
-            + ("poison", "rng")
+            + ("poison", "key")
         )
         self.mixed_operands = (
             ("params", "cache") + ("adapters",) * lora
@@ -230,22 +240,23 @@ class StepPrograms:
             + ("lengths_before", "lengths_after", "completion_idx",
                "dec_tokens", "dec_active")
             + ("dec_adp",) * lora
-            + ("chunk_poison", "dec_poison", "rng")
+            + ("chunk_poison", "dec_poison", "key")
         )
 
-        def _decode(params, cache, tokens, active, poison, rng,
-                         adapters=None, dec_adp=None):
+        def _decode(params, cache, tokens, active, poison, key,
+                    adapters=None, dec_adp=None):
             traces["decode"] += 1
+            key, rng = jax.random.split(key)
             tok, bad, cache = _decode_body(
                 params, _start_tick(cache), tokens, active, poison, rng,
                 adapters=_with_ids(adapters, dec_adp),
             )
-            return (tok, bad, cache) + (adapters,) * lora
+            return (tok, bad, key, cache) + (adapters,) * lora
 
         def _mixed(
             params, cache, chunk_tokens, chunk_slots, chunk_pos,
             lengths_before, lengths_after, completion_idx,
-            dec_tokens, dec_active, chunk_poison, dec_poison, rng,
+            dec_tokens, dec_active, chunk_poison, dec_poison, key,
             commit_slots=None, adapters=None, chunk_adp=None,
             dec_adp=None,
         ):
@@ -279,6 +290,7 @@ class StepPrograms:
             prompt token to its pool buffer slot and ``dec_adp`` (S,)
             each decode row."""
             traces["mixed"] += 1
+            key, rng = jax.random.split(key)
             rng_c, rng_d = jax.random.split(rng)
             cache = _start_tick(cache).replace(lengths=lengths_before)
             chunk_adapters = _with_ids(adapters, chunk_adp)
@@ -316,7 +328,7 @@ class StepPrograms:
                 adapters=_with_ids(adapters, dec_adp),
             )
             return (
-                (chunk_tok, dec_tok, chunk_bad, dec_bad, cache)
+                (chunk_tok, dec_tok, chunk_bad, dec_bad, key, cache)
                 + (adapters,) * lora + tuple(chunk_kv)
             )
 
@@ -373,7 +385,7 @@ class StepPrograms:
                     f, mesh=mesh,
                     in_specs=(rep, cache_pspec) + (rep,) * (len(names) - 2),
                     out_specs=(
-                        (rep,) * FETCHED[name] + (cache_pspec,)
+                        (rep,) * (FETCHED[name] + 1) + (cache_pspec,)
                         + (rep,) * lora
                         + (kv_specs,) * (spec and name == "mixed")
                     ),

@@ -19,7 +19,7 @@ import statistics
 import jax
 import jax.numpy as jnp
 import pytest
-from _helpers import PAGE
+from _helpers import PAGE, hybrid_toy
 
 from rocm_apex_tpu import profiler
 from rocm_apex_tpu.inference import InferenceEngine, SamplingParams
@@ -35,7 +35,7 @@ from rocm_apex_tpu.optimizers.mixed import (
 PROMPTS = [[1, 2, 3], [4, 5, 6, 7, 8], list(range(10, 19)), [20, 21]]
 MAX_NEW = 4
 CHILDREN = (
-    "engine.admit", "engine.pack", "engine.table_push", "engine.rng",
+    "engine.admit", "engine.pack", "engine.table_push",
     "engine.dispatch", "engine.fetch", "engine.commit",
 )
 
@@ -153,7 +153,7 @@ class TestTickSpans:
                 order[n] for n in names)
             assert len(set(names)) == len(names)
             ran = t["counts"]["program"] != "none"
-            for name in ("engine.rng", "engine.dispatch", "engine.fetch"):
+            for name in ("engine.dispatch", "engine.fetch"):
                 assert (name in names) == ran
             for a, b in zip(kids, kids[1:]):
                 assert a["end"] <= b["start"]  # no overlap
@@ -218,6 +218,111 @@ class TestTickSpans:
         assert max(s["end"] for s in enq) <= recording["ticks"][0]["start"]
 
 
+def hand_off_engine(case, tracer):
+    if case == "hybrid-counters":
+        model, params = hybrid_toy()
+        return InferenceEngine(
+            model, params, num_slots=3, capacity=64, paged=True,
+            page_size=PAGE, prefill_token_budget=16, tracer=tracer,
+            sampling=SamplingParams(temperature=0.0))
+    if case == "adapter-pool":
+        from rocm_apex_tpu.inference import AdapterPool
+
+        pool = AdapterPool(2, 32, max_resident=4, max_rank=4)
+        return make_engine(
+            tracer=tracer, paged=False, page_size=None, adapter_pool=pool)
+    if case == "contiguous":
+        return make_engine(tracer=tracer, paged=False, page_size=None)
+    return make_engine(tracer=tracer)
+
+
+class TestHandOff:
+    """ISSUE 29: a tick that runs a program makes ONE call into the
+    runtime on the way in (the jitted step program, host arrays as they
+    are, the key split inside it) and one `device_get` on the way
+    out."""
+
+    @pytest.mark.parametrize(
+        "case", ["paged", "contiguous", "hybrid-counters", "adapter-pool"])
+    def test_one_call_in_one_fetch_out(self, case, monkeypatch):
+        tracer = Tracer()
+        eng = hand_off_engine(case, tracer)
+        serve(eng, 0)  # warm-up: both programs exist from here on
+        tracer.clear()
+
+        inside, eager, fetched = [], [], []
+        run_program, fetch = eng._run_program, eng._fetch
+
+        def watched_run(*args, **kw):
+            inside.append(True)
+            try:
+                return run_program(*args, **kw)
+            finally:
+                inside.pop()
+
+        def watched_fetch(values):
+            fetched.append(values)
+            return fetch(values)
+
+        def counting(name, fn):
+            def wrapper(*args, **kw):
+                if inside:
+                    eager.append(name)
+                return fn(*args, **kw)
+            return wrapper
+
+        monkeypatch.setattr(eng, "_run_program", watched_run)
+        monkeypatch.setattr(eng, "_fetch", watched_fetch)
+        monkeypatch.setattr(
+            jax.random, "split", counting("split", jax.random.split))
+        monkeypatch.setattr(jnp, "asarray", counting("asarray", jnp.asarray))
+        monkeypatch.setattr(
+            jax, "device_put", counting("device_put", jax.device_put))
+        results, _ = serve(eng, 100)
+        monkeypatch.undo()
+
+        assert len(results) == len(PROMPTS)
+        assert eager == []
+        # one fetch a tick: the program's own outputs (tokens, flags)
+        # and the counters of a cache that keeps them
+        counted = case == "hybrid-counters"
+        for values, counters in fetched:
+            assert len(values) in (2, 4)
+            assert (counters is not None) == counted
+            assert all(
+                isinstance(v, jax.Array)
+                for v in jax.tree_util.tree_leaves((values, counters)))
+        assert eng.mixed_trace_count == 1 and eng.decode_trace_count == 1
+
+        ring = sorted(
+            (e for e in tracer.events()
+             if e["ph"] == "X" and e["name"].startswith("engine.")),
+            key=lambda e: (e["ts"], -e["dur"]))
+        ticks = [e for e in ring if e["name"] == "engine.tick"]
+        assert {t["args"]["program"] for t in ticks} >= {"mixed", "decode"}
+        assert len([t for t in ticks if t["args"]["program"] != "none"]) == (
+            len(fetched))
+        if case == "hybrid-counters":
+            # the cache's counters came back in the same fetch
+            assert sum(t["args"]["moe_assignments"] for t in ticks) > 0
+        uncovered = []
+        for t in ticks:
+            kids = [
+                e for e in ring if e["name"] in CHILDREN
+                and e["ts"] >= t["ts"]
+                and e["ts"] + e["dur"] <= t["ts"] + t["dur"]]
+            names = [k["name"] for k in kids]
+            ran = t["args"]["program"] != "none"
+            assert (
+                names.count("engine.dispatch") == names.count("engine.fetch")
+                == int(ran))
+            for a, b in zip(kids, kids[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"]
+            uncovered.append(1.0 - sum(k["dur"] for k in kids) / t["dur"])
+        # the five phases still tile the tick
+        assert statistics.median(uncovered) < 0.1
+
+
 class TestWholePromptMode:
     def test_legacy_engine_counts_its_own_ticks(self):
         """The whole-prompt A/B baseline admits inside its step and has
@@ -246,7 +351,7 @@ class TestWholePromptMode:
             e["name"] for e in tracer.events()
             if e["ph"] == "X" and e["name"].startswith("engine.")}
         assert phases == {
-            "engine.tick", "engine.admit", "engine.rng", "engine.dispatch",
+            "engine.tick", "engine.admit", "engine.dispatch",
             "engine.fetch", "engine.commit"}
 
 

@@ -11,8 +11,6 @@ must refuse. The GPT cases reuse test_inference.py's shape tuple
 test_hybrid_serving.py's toy.
 """
 
-import json
-import pathlib
 import re
 
 import jax
@@ -20,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _helpers import PAGE
+from _helpers import PAGE, hybrid_toy
 from rocm_apex_tpu.inference import (
     AdapterPool,
     KVCache,
@@ -30,7 +28,6 @@ from rocm_apex_tpu.inference import (
 )
 from rocm_apex_tpu.models.gpt import GPTConfig, GPTModel
 
-ROOT = pathlib.Path(__file__).resolve().parents[2]
 GREEDY = SamplingParams(temperature=0.0)
 PROMPT = 3  # slot 0's whole prompt; slot 1 takes the rest of the budget
 STALE = 10 ** 6
@@ -62,21 +59,8 @@ def gpt():
 
 @pytest.fixture(scope="module")
 def hybrid():
-    from benchmarks.families import granite_hybrid as fam
-    from benchmarks.harness import rehearsal
-    from rocm_apex_tpu.models.hybrid import HybridModel
-
-    raw = json.loads(
-        (ROOT / "benchmarks/configs/granite-4.0-h-small.json").read_text())
-    config = dict(
-        rehearsal.shrink(raw), embedding_multiplier=1.0,
-        residual_multiplier=1.5, logits_scaling=1.0)
-    model = HybridModel(fam.model_config(
-        config, params_dtype=jnp.float32, dtype=jnp.float32,
-        attention_impl="flash", log_routes=False))
-    return dict(
-        model=model, params=fam.make_params(config, 5, jnp.float32),
-        slots=3, capacity=64, budget=16)
+    model, params = hybrid_toy()
+    return dict(model=model, params=params, slots=3, capacity=64, budget=16)
 
 
 def make_cache(world, paged, slots=None):
@@ -139,13 +123,15 @@ def one_tick_then_a_decode(world, programs, cache, adapters):
         completion_idx=done, dec_tokens=np.zeros((S,), np.int32),
         dec_active=np.zeros((S,), bool), dec_adp=np.zeros((S,), np.int32),
         chunk_poison=np.zeros((B,), np.float32),
-        dec_poison=np.zeros((S,), np.float32), rng=jax.random.PRNGKey(0),
+        dec_poison=np.zeros((S,), np.float32), key=jax.random.PRNGKey(0),
     )
     for _ in range(2):
         out = programs.mixed(*(operands[n] for n in programs.mixed_operands))
-    chunk_tok, dec_tok, chunk_bad, dec_bad, cache = out[:5]
+    chunk_tok, dec_tok, chunk_bad, dec_bad, key, cache, *rest_out = out
+    # the key state comes back split once, as the host would have
+    np.testing.assert_array_equal(
+        np.asarray(key), np.asarray(jax.random.split(operands["key"])[0]))
     assert not np.asarray(chunk_bad).any() and not np.asarray(dec_bad).any()
-    rest_out = list(out[5:])
     if programs.lora:  # the donated buffers come back as they went in
         assert rest_out.pop(0) is not None
     if programs.spec:  # the chunk's K/V, per layer, for `commit`
@@ -161,11 +147,11 @@ def one_tick_then_a_decode(world, programs, cache, adapters):
     for _ in range(2):
         out = programs.decode(
             *(operands[n] for n in programs.decode_operands))
-    assert len(out) == 3 + programs.lora
+    assert len(out) == 4 + programs.lora
     return dict(
         chunk_tok=np.asarray(chunk_tok), dec_tok=np.asarray(dec_tok),
         next_tok=np.asarray(out[0]), counters=getattr(cache, "counters", None),
-        next_counters=getattr(out[2], "counters", None))
+        next_counters=getattr(out[3], "counters", None))
 
 
 @pytest.mark.parametrize("case", list(CASES))
