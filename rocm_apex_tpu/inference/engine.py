@@ -436,12 +436,16 @@ class InferenceEngine:
         # (`cache_spec`) and the paged cache is built from that. A
         # recurrent state lives in the slot, not in pages: whatever
         # shares, ships or defers pages cannot carry it, so those
-        # options are refused here and not at their first use.
-        self._stateful = any(
-            layer["kind"] != "kv" for layer in model.cache_spec()
-        )
+        # options are refused here and not at their first use. A LATENT
+        # row lives in pages as K/V does, so a model that keeps one is
+        # refused only what its own code does not do.
+        kinds = {layer["kind"] for layer in model.cache_spec()}
+        self._stateful = "ssm" in kinds  # keeps per-slot state
+        self._latent = "latent" in kinds
         quantized = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
+        refused = {}
         if self._stateful:
+            keeps = "keeps a recurrent state per slot beside its paged K/V"
             refused = {
                 "paged=False (its attention layers' K/V is paged; the "
                 "contiguous cache has no per-slot state)": not paged,
@@ -453,13 +457,25 @@ class InferenceEngine:
                 "adapter_pool": adapter_pool is not None,
                 "kv_dtype=int8": quantized,
             }
-            for what, asked in refused.items():
-                if asked:
-                    raise ValueError(
-                        f"{type(model).__name__} keeps a recurrent state "
-                        f"per slot beside its paged K/V; it does not "
-                        f"serve with {what}"
-                    )
+        elif self._latent:
+            keeps = "keeps latent rows in pages"
+            refused = {
+                "paged=False (the contiguous cache has no latent row)":
+                    not paged,
+                "spec_k > 0 (the commit program writes K and V pools; "
+                "a latent block hands back no deferred rows)": spec_k > 0,
+                "tensor_parallel_size > 1 (every head reads the one "
+                "latent row: the pool has no head axis to shard)": tp > 1,
+                "adapter_pool (its projections take no adapters)":
+                    adapter_pool is not None,
+                "kv_dtype=int8 (a latent pool has no int8 form)": quantized,
+            }
+        for what, asked in refused.items():
+            if asked:
+                raise ValueError(
+                    f"{type(model).__name__} {keeps}; it does not serve "
+                    f"with {what}"
+                )
         self.capacity = int(capacity or cfg.max_position_embeddings)
         if self.capacity > cfg.max_position_embeddings:
             raise ValueError(
@@ -1753,6 +1769,12 @@ class InferenceEngine:
                 f"{type(self.model).__name__} keeps a recurrent state per "
                 f"slot: shipped pages would arrive without it (evacuate "
                 f"and resume by tokens; the prefill recomputes the state)"
+            )
+        if asked and self._latent:
+            raise ValueError(
+                f"{type(self.model).__name__} keeps latent rows in pages: "
+                f"the shipped payload is the K and V pools' and does not "
+                f"carry them (evacuate and resume by tokens)"
             )
 
     def evacuate_request(

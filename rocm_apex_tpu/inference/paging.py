@@ -351,6 +351,15 @@ class PagedKVCache:
     those options for a model that declares such a layer. A slot's
     state needs no reset call: ``lengths`` is the engine's own cursor,
     and the model starts a slot whose length is 0 from zero.
+    ``latent`` holds one pool per LATENT-attention block, in block
+    order: ``(num_pages, 1, page_size, width)``, a position's row being
+    the block's compressed K/V latent (``rank`` values) then its rotated
+    positional key (``rope`` values), shared by all of the block's
+    heads, then zeros up to ``width`` (`ops/mla.py::latent_width`: a
+    multiple of 128 lanes, or the chip would store the pool with its
+    rows along the lanes). A latent pool lives behind the same page
+    table as K/V and is forked with it; a model may declare latent
+    blocks and no K/V layer at all.
 
     ``counters`` (None unless a layer declares ``counters``): the
     tick's sums over layers and over the step program's applies, named
@@ -373,10 +382,11 @@ class PagedKVCache:
     conv: Tuple[jnp.ndarray, ...] = ()
     routes: Optional[jnp.ndarray] = None
     counters: Optional[jnp.ndarray] = None
+    latent: Tuple[jnp.ndarray, ...] = ()
 
     COUNTER_NAMES = (
         "moe_assignments", "moe_experts_touched", "moe_load_max",
-        "state_slots_live",
+        "state_slots_live", "moe_zero_assignments", "latent_rows_read",
     )
 
     # ------------------------------------------------------------------
@@ -490,31 +500,53 @@ class PagedKVCache:
         dtype: Any = jnp.bfloat16,
         quantized: bool = False,
     ) -> "PagedKVCache":
-        """The cache of a model whose layers declare what they keep:
-        ``dict(kind="kv", heads=, head_dim=)`` for a layer that attends
-        (the GLOBAL head count: the engine shards the pools itself),
-        ``dict(kind="ssm", state=, conv=, state_dtype=)`` for one that
-        scans; any layer may add ``counters=True`` and
-        ``route_words=<n>``."""
-        kv_layers = [s for s in spec if s["kind"] == "kv"]
-        ssm_layers = [s for s in spec if s["kind"] == "ssm"]
-        if len(kv_layers) + len(ssm_layers) != len(spec):
-            raise ValueError("a layer declares a kind other than kv or ssm")
-        if not kv_layers:
+        """The cache of a model whose layers declare what they keep, a
+        list with one entry per thing kept (a layer with two attention
+        blocks declares two): ``dict(kind="kv", heads=, head_dim=)`` for
+        one that attends over K and V (the GLOBAL head count: the engine
+        shards the pools itself), ``dict(kind="latent", rank=, rope=)``
+        for one that attends over a shared latent row, ``dict(kind="ssm",
+        state=, conv=, state_dtype=)`` for one that scans; any entry may
+        add ``counters=True`` and ``route_words=<n>``. Something has to
+        live in pages (``kv`` or ``latent``): the engine schedules by
+        them."""
+        from rocm_apex_tpu.ops.mla import latent_width
+
+        by_kind = {"kv": [], "latent": [], "ssm": []}
+        for entry in spec:
+            if entry["kind"] not in by_kind:
+                raise ValueError(
+                    f"a layer declares kind {entry['kind']!r}; the cache "
+                    f"knows {sorted(by_kind)}")
+            by_kind[entry["kind"]].append(entry)
+        kv_layers, ssm_layers = by_kind["kv"], by_kind["ssm"]
+        if not kv_layers and not by_kind["latent"]:
             raise ValueError(
-                "the engine schedules by pages: a model with no attending "
-                "layer has none to schedule by")
+                "the engine schedules by pages: a model that keeps "
+                "neither K/V nor latent rows has none to schedule by")
         shapes = {(s["heads"], s["head_dim"]) for s in kv_layers}
-        if len(shapes) != 1:
-            raise ValueError(f"attention layers differ in shape: {shapes}")
-        heads, head_dim = shapes.pop()
+        if len(shapes) > 1:
+            raise ValueError(
+                f"K/V layers differ in (heads, head_dim): {sorted(shapes)}; "
+                f"one pool shape serves them all")
+        heads, head_dim = shapes.pop() if shapes else (1, 1)
+        if quantized and by_kind["latent"]:
+            raise ValueError("a latent pool has no int8 form")
         cache = cls.create(
             len(kv_layers), num_slots, capacity, heads, head_dim,
             page_size=page_size, num_pages=num_pages, dtype=dtype,
             quantized=quantized,
         )
+        pages = (
+            num_slots * cache.pages_per_slot if num_pages is None
+            else num_pages)
         words = sum(s.get("route_words", 0) for s in spec)
         return cache.replace(
+            latent=tuple(
+                jnp.zeros(
+                    (pages, 1, page_size, latent_width(s["rank"], s["rope"])),
+                    dtype)
+                for s in by_kind["latent"]),
             ssm=tuple(
                 jnp.zeros((num_slots,) + tuple(s["state"]), s["state_dtype"])
                 for s in ssm_layers),
@@ -522,11 +554,22 @@ class PagedKVCache:
                 jnp.zeros((num_slots,) + tuple(s["conv"]), dtype)
                 for s in ssm_layers),
             routes=jnp.zeros(
-                (cache.num_pages, 1, page_size, -(-words // 128) * 128),
+                (pages, 1, page_size, -(-words // 128) * 128),
                 jnp.uint32) if words else None,
             counters=jnp.zeros((len(cls.COUNTER_NAMES),), jnp.int32)
             if any(s.get("counters") for s in spec) else None,
         )
+
+    def count(self, **sums) -> "PagedKVCache":
+        """The tick's counters with ``sums`` (by their `COUNTER_NAMES`)
+        added in; ``moe_load_max`` is a running maximum."""
+        new = list(self.counters)
+        for name, value in sums.items():
+            i = self.COUNTER_NAMES.index(name)
+            new[i] = (
+                jnp.maximum(new[i], value) if name == "moe_load_max"
+                else new[i] + value)
+        return self.replace(counters=jnp.stack(new).astype(jnp.int32))
 
     def start_tick(self) -> "PagedKVCache":
         """The tick's counters from zero (a cache that keeps none is
@@ -553,7 +596,7 @@ class PagedKVCache:
 
     @property
     def num_pages(self) -> int:
-        return self.k[0].shape[0]
+        return (self.k or self.latent)[0].shape[0]
 
     @property
     def capacity(self) -> int:
@@ -574,7 +617,7 @@ class PagedKVCache:
         extra = tuple(
             a for a in (self.routes, self.counters) if a is not None)
         for arrs in (self.k, self.v, self.k_scale or (), self.v_scale or (),
-                     self.ssm, self.conv, extra):
+                     self.latent, self.ssm, self.conv, extra):
             for a in arrs:
                 total += a.size * a.dtype.itemsize
         total += self.page_table.size * self.page_table.dtype.itemsize
@@ -675,19 +718,20 @@ class PagedKVCache:
 
     def fork_page(self, src, dst) -> "PagedKVCache":
         """Copy-on-write device half: duplicate page ``src`` onto
-        ``dst`` in every layer's pools (and scales). ``src``/``dst``
-        may be traced — the engine jits this once and calls it for
-        every fork."""
-        k = tuple(paged_fork(b, src, dst) for b in self.k)
-        v = tuple(paged_fork(b, src, dst) for b in self.v)
+        ``dst`` in every pool behind the page table (K/V and their
+        scales, latent rows, the routing log). ``src``/``dst`` may be
+        traced — the engine jits this once and calls it for every
+        fork."""
+        def fork(pools):
+            return tuple(paged_fork(b, src, dst) for b in pools)
+
+        cache = self.replace(
+            k=fork(self.k), v=fork(self.v), latent=fork(self.latent),
+            routes=None if self.routes is None
+            else paged_fork(self.routes, src, dst))
         if not self.quantized:
-            return self.replace(k=k, v=v)
-        return self.replace(
-            k=k, v=v,
-            k_scale=tuple(
-                s.at[dst].set(s[src]) for s in self.k_scale
-            ),
-            v_scale=tuple(
-                s.at[dst].set(s[src]) for s in self.v_scale
-            ),
+            return cache
+        return cache.replace(
+            k_scale=tuple(s.at[dst].set(s[src]) for s in self.k_scale),
+            v_scale=tuple(s.at[dst].set(s[src]) for s in self.v_scale),
         )

@@ -37,7 +37,7 @@ from rocm_apex_tpu.ops import ssm
 from rocm_apex_tpu.ops.paging import paged_scatter, paged_view
 from rocm_apex_tpu.transformer.moe import HeldExperts
 
-__all__ = ["HybridConfig", "HybridModel"]
+__all__ = ["HybridConfig", "HybridModel", "ServedDecoder"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,26 +247,157 @@ class GroupedAttention(nn.Module):
         return jnp.dot(ctx, o_w.astype(cfg.dtype)), (k_buf, v_buf)
 
 
+class ServedDecoder(nn.Module):
+    """The frame a served model of declared layers runs in: it checks
+    what the engine hands over (a ``(1, budget)`` packed chunk with
+    ``chunk=(slot_ids, positions)`` or a ``(slots, 1)`` decode grid),
+    embeds, runs the layers over what each keeps in the cache, norms,
+    projects onto the vocabulary, adds up the tick's counters, writes
+    the routing log and advances the decode grid's lengths. A model
+    declares its layers (`layer`, `layer_states`, `with_states`) and,
+    where it has them, rows of its own (`own_rows`), counters of its own
+    (`tick_counts`) and multipliers (`embed`, `project`).
+
+    A layer is ``(h, state, rows) -> (h, state, counts)``. ``rows``
+    says where the tick's rows live: ``paged`` (page table, page size,
+    lengths), ``chunk`` (as given, None in the decode grid), each row's
+    ``slots`` and ``positions``, and ``live`` (the decode grid's rows
+    that are not DEAD; None in a chunk). ``counts`` are `HeldExperts`'.
+    """
+
+    cfg: Any
+    untied_head = False  # True: an ``lm_head`` of its own
+    # why a chunk's third element (rows whose commit waits) is refused
+    no_deferred_commit = "this model's layers defer no row's commit"
+
+    # -- what a model declares ------------------------------------------
+
+    def layer(self, i):
+        raise NotImplementedError
+
+    def layer_states(self, cache):
+        """Per layer, what it keeps in ``cache``."""
+        raise NotImplementedError
+
+    def with_states(self, cache, states):
+        raise NotImplementedError
+
+    def own_rows(self, rows, cache):
+        return rows
+
+    def tick_counts(self, rows, cache):
+        return {}
+
+    def embed(self, x):
+        return x.astype(self.cfg.dtype)
+
+    def project(self, h, head):
+        return jnp.dot(h, head, preferred_element_type=jnp.float32)
+
+    # -- the frame ------------------------------------------------------
+
+    @nn.compact
+    def __call__(self, tokens, cache=None, chunk=None, adapters=None):
+        cfg, who = self.cfg, type(self).__name__
+        if cache is None:
+            raise ValueError(
+                f"{who} serves through a cache (chunk= or the decode "
+                f"grid); it has no cache-less forward")
+        if adapters is not None:
+            raise ValueError(f"{who} takes no adapters")
+        table = self.param(
+            "embedding", nn.initializers.normal(cfg.init_std),
+            (cfg.vocab_size, cfg.hidden_size), cfg.params_dtype)
+        lengths = cache.lengths
+        if chunk is not None:
+            if len(chunk) != 2:
+                raise ValueError(
+                    f"{self.no_deferred_commit}: chunk=(slot_ids, "
+                    f"positions) only")
+            if tokens.shape[0] != 1:
+                raise ValueError("a packed chunk is one stream (batch 1)")
+            ids = tokens[0]
+            slots, positions = chunk
+            live = None
+        else:
+            if tokens.shape[1] != 1:
+                raise ValueError(
+                    f"{who} takes a packed chunk or one token per slot, "
+                    f"not a whole-prompt window")
+            ids = tokens[:, 0]
+            slots = jnp.arange(cache.num_slots, dtype=jnp.int32)
+            positions = lengths
+            live = lengths < cache.capacity
+        rows = self.own_rows(dict(
+            paged=dict(
+                page_table=cache.page_table, page_size=cache.page_size,
+                lengths=lengths),
+            chunk=chunk, slots=slots, positions=positions, live=live,
+        ), cache)
+        h = self.embed(table[ids])
+        states = self.layer_states(cache)
+        chosen = []
+        sums = dict(
+            moe_assignments=jnp.int32(0), moe_experts_touched=jnp.int32(0),
+            moe_load_max=jnp.int32(0), moe_zero_assignments=jnp.int32(0))
+        for i in range(cfg.num_layers):
+            h, states[i], counts = self.layer(i)(h, states[i], rows)
+            if cfg.log_routes:
+                chosen.append(counts["chosen"])
+            sums["moe_assignments"] += counts["assignments"]
+            sums["moe_experts_touched"] += counts["experts_touched"]
+            sums["moe_zero_assignments"] += counts["zero_assignments"]
+            sums["moe_load_max"] = jnp.maximum(
+                sums["moe_load_max"], counts["load_max"])
+        h = RMSNorm(
+            cfg.hidden_size, cfg.rms_norm_eps, cfg.dtype, cfg.params_dtype,
+            name="final_norm")(h)
+        if self.untied_head:
+            head = self.param(
+                "lm_head", nn.initializers.normal(cfg.init_std),
+                (cfg.hidden_size, cfg.vocab_size), cfg.params_dtype,
+            ).astype(cfg.dtype)
+        else:
+            head = table.astype(cfg.dtype).T
+        logits = self.project(h, head)
+        cache = self.with_states(cache, states).count(
+            **sums, **self.tick_counts(rows, cache))
+        if cfg.log_routes:
+            # every layer's mask of chosen experts, one row a position,
+            # in one paged write beside the layers' own
+            masks = jnp.concatenate(chosen, axis=0).T  # (rows, layers * words)
+            lanes = cache.routes.shape[-1]
+            masks = jnp.pad(masks, ((0, 0), (0, lanes - masks.shape[1])))
+            cache = cache.replace(routes=paged_scatter(
+                cache.routes, cache.page_table, slots, positions,
+                masks[:, None, :]))
+        if chunk is not None:
+            return logits[None], cache
+        return logits[:, None, :], cache.replace(
+            lengths=jnp.minimum(lengths + 1, cache.capacity))
+
+
 class HybridLayer(nn.Module):
     cfg: HybridConfig
     kind: str
 
     @nn.compact
-    def __call__(self, h, state, paged, chunk, chunk_geo, fresh, live):
+    def __call__(self, h, state, rows):
         cfg = self.cfg
         norm = dict(
             size=cfg.hidden_size, eps=cfg.rms_norm_eps, dtype=cfg.dtype,
             params_dtype=cfg.params_dtype)
+        geo = rows["geo"]
         u = RMSNorm(**norm, name="norm1")(h)
         if self.kind == "mamba":
             y, state = MambaMixer(cfg, name="mamba")(
-                u, state, chunk_geo, fresh, live)
+                u, state, geo, rows["fresh"], rows["live"])
         else:
             y, state = GroupedAttention(cfg, name="self_attention")(
-                u, state, paged, chunk)
+                u, state, rows["paged"], rows["chunk"])
         h = h + (cfg.residual_multiplier * y).astype(cfg.dtype)
         u = RMSNorm(**norm, name="norm2")(h)
-        tokens = chunk_geo["valid"] if chunk_geo is not None else live
+        tokens = geo["valid"] if geo is not None else rows["live"]
         y, counts = HeldExperts(
             hidden_size=cfg.hidden_size, num_experts=cfg.num_experts,
             held=cfg.experts_held, top_k=cfg.num_experts_per_tok,
@@ -278,8 +409,10 @@ class HybridLayer(nn.Module):
         return h, state, counts
 
 
-class HybridModel(nn.Module):
+class HybridModel(ServedDecoder):
     cfg: HybridConfig
+    no_deferred_commit = (
+        "a recurrent state cannot defer a speculative row's commit")
 
     def cache_spec(self):
         """What each layer keeps per request, for the engine to build
@@ -304,97 +437,44 @@ class HybridModel(nn.Module):
             out.append(dict(layer, counters=True, route_words=words))
         return out
 
-    @nn.compact
-    def __call__(self, tokens, cache=None, chunk=None, adapters=None):
-        cfg = self.cfg
-        if cache is None:
-            raise ValueError(
-                "HybridModel serves through a cache (chunk= or the decode "
-                "grid); it has no cache-less forward")
-        if adapters is not None:
-            raise ValueError("HybridModel takes no adapters")
-        table = self.param(
-            "embedding", nn.initializers.normal(cfg.init_std),
-            (cfg.vocab_size, cfg.hidden_size), cfg.params_dtype)
-        slots_n = cache.num_slots
-        lengths = cache.lengths
-        if chunk is not None:
-            if len(chunk) != 2:
-                raise ValueError(
-                    "a recurrent state cannot defer a speculative row's "
-                    "commit: chunk=(slot_ids, positions) only")
-            if tokens.shape[0] != 1:
-                raise ValueError("a packed chunk is one stream (batch 1)")
-            ids = tokens[0]
-            geo = ssm.chunk_geometry(chunk[0], slots_n)
-            fresh, live = lengths == 0, None
-            touched = jnp.sum((geo["counts"] > 0).astype(jnp.int32))
-        else:
-            if tokens.shape[1] != 1:
-                raise ValueError(
-                    "HybridModel takes a packed chunk or one token per "
-                    "slot, not a whole-prompt window")
-            ids = tokens[:, 0]
-            geo, fresh = None, None
-            live = lengths < cache.capacity
-            touched = jnp.sum(live.astype(jnp.int32))
-        paged = dict(
-            page_table=cache.page_table, page_size=cache.page_size,
-            lengths=lengths)
-        h = (
-            cfg.embedding_multiplier * table[ids].astype(jnp.float32)
-        ).astype(cfg.dtype)
-        k, v = list(cache.k), list(cache.v)
-        ssm_s, conv_s = list(cache.ssm), list(cache.conv)
-        chosen = []
-        assignments = experts = load_max = jnp.int32(0)
-        ai = mi = 0
-        for i, kind in enumerate(cfg.layer_types):
-            if kind == "attention":
-                state = (k[ai], v[ai])
-            else:
-                state = (ssm_s[mi], conv_s[mi])
-            h, state, counts = HybridLayer(cfg, kind, name=f"layer_{i}")(
-                h, state, paged, chunk, geo, fresh, live)
-            if kind == "attention":
-                k[ai], v[ai] = state
-                ai += 1
-            else:
-                ssm_s[mi], conv_s[mi] = state
-                mi += 1
-            if cfg.log_routes:
-                chosen.append(counts["chosen"])
-            assignments = assignments + counts["assignments"]
-            experts = experts + counts["experts_touched"]
-            load_max = jnp.maximum(load_max, counts["load_max"])
-        h = RMSNorm(
-            cfg.hidden_size, cfg.rms_norm_eps, cfg.dtype, cfg.params_dtype,
-            name="final_norm")(h)
-        logits = jnp.dot(
-            h, table.astype(cfg.dtype).T, preferred_element_type=jnp.float32
-        ) / cfg.logits_scaling
-        old = cache.counters
-        counters = jnp.stack([
-            old[0] + assignments, old[1] + experts,
-            jnp.maximum(old[2], load_max), old[3] + touched,
-        ]).astype(jnp.int32)
-        cache = cache.replace(
-            k=tuple(k), v=tuple(v), ssm=tuple(ssm_s), conv=tuple(conv_s),
-            counters=counters)
-        if cfg.log_routes:
-            # every layer's mask of chosen experts, one row a position,
-            # in one paged write beside the K/V's
-            if chunk is not None:
-                at = (chunk[0], chunk[1])
-            else:
-                at = (jnp.arange(slots_n, dtype=jnp.int32), lengths)
-            masks = jnp.concatenate(chosen, axis=0).T  # (rows, layers * words)
-            lanes = cache.routes.shape[-1]
-            masks = jnp.pad(masks, ((0, 0), (0, lanes - masks.shape[1])))
-            cache = cache.replace(routes=paged_scatter(
-                cache.routes, cache.page_table, at[0], at[1],
-                masks[:, None, :]))
-        if chunk is not None:
-            return logits[None], cache
-        return logits[:, None, :], cache.replace(
-            lengths=jnp.minimum(lengths + 1, cache.capacity))
+    def layer(self, i):
+        return HybridLayer(
+            self.cfg, self.cfg.layer_types[i], name=f"layer_{i}")
+
+    def layer_states(self, cache):
+        kv = zip(cache.k, cache.v)
+        state = zip(cache.ssm, cache.conv)
+        return [
+            next(kv) if kind == "attention" else next(state)
+            for kind in self.cfg.layer_types]
+
+    def with_states(self, cache, states):
+        kinds = self.cfg.layer_types
+        kv = [s for s, kind in zip(states, kinds) if kind == "attention"]
+        state = [s for s, kind in zip(states, kinds) if kind != "attention"]
+        return cache.replace(
+            k=tuple(s[0] for s in kv), v=tuple(s[1] for s in kv),
+            ssm=tuple(s[0] for s in state), conv=tuple(s[1] for s in state))
+
+    def own_rows(self, rows, cache):
+        """A chunk's segments for the scans, and which of its slots are
+        FRESH; ``touched`` is how many slots' states the tick advances."""
+        if rows["chunk"] is None:
+            return dict(
+                rows, geo=None, fresh=None,
+                touched=jnp.sum(rows["live"].astype(jnp.int32)))
+        geo = ssm.chunk_geometry(rows["slots"], cache.num_slots)
+        return dict(
+            rows, geo=geo, fresh=cache.lengths == 0,
+            touched=jnp.sum((geo["counts"] > 0).astype(jnp.int32)))
+
+    def tick_counts(self, rows, cache):
+        return dict(state_slots_live=rows["touched"])
+
+    def embed(self, x):
+        return (
+            self.cfg.embedding_multiplier * x.astype(jnp.float32)
+        ).astype(self.cfg.dtype)
+
+    def project(self, h, head):
+        return super().project(h, head) / self.cfg.logits_scaling

@@ -31,7 +31,7 @@ from rocm_apex_tpu.transformer import parallel_state
 
 __all__ = [
     "SwitchMLP", "switch_route", "load_balancing_loss",
-    "HeldExperts", "route_top_k",
+    "HeldExperts", "route_top_k", "route_scores_bias",
 ]
 
 
@@ -170,12 +170,35 @@ def route_top_k(logits: jnp.ndarray, k: int):
     return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-class HeldExperts(nn.Module):
-    """Routed gated experts plus one shared expert, for a chip that
-    holds experts ``held = (lo, hi)`` of ``num_experts``.
+def route_scores_bias(logits: jnp.ndarray, bias: jnp.ndarray, k: int,
+                      scaling: float):
+    """Scores are the softmax over ALL of a row's float32 router logits;
+    the choice is the ``k`` largest of score + ``bias`` (a balancing
+    bias that steers the choice and is no part of the weight); the
+    weights are the chosen experts' own scores times ``scaling``, not
+    renormalised: (ids (T, k), weights (T, k))."""
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    return ids.astype(jnp.int32), scaling * jnp.take_along_axis(
+        scores, ids, axis=-1)
 
-    The router scores all ``num_experts`` and every token goes to its
-    ``top_k``, weighted by the softmax over those k logits. The part of
+
+class HeldExperts(nn.Module):
+    """Routed gated experts, for a chip that holds experts ``held =
+    (lo, hi)`` of ``num_experts``; optionally one shared expert
+    (``shared_width`` > 0) and ``zero_experts`` that compute nothing.
+
+    The router scores all ``num_experts + zero_experts`` outputs and
+    every token goes to its ``top_k``, by one of two rules
+    (``routing``): ``"top_k_softmax"``, the k largest logits weighted by
+    the softmax over those k (`route_top_k`); ``"scores_bias"``, the
+    softmax over ALL outputs as scores, the choice by score plus a
+    learned balancing bias (``router_bias``), the chosen scores times
+    ``scaling`` as weights (`route_scores_bias`). A ZERO expert (ids
+    ``num_experts`` and up) returns its token as it is, times its
+    weight: no weights, no row of the grouped product, applied by
+    whoever holds the token, so every chip of a deployment applies them
+    for its own tokens. The part of
     the result that the experts held here give is computed, and no
     assignment is dropped: each (token, expert) pair that fell on a held
     expert is one row of a group-by-group layout
@@ -207,6 +230,9 @@ class HeldExperts(nn.Module):
     params_dtype: Any = jnp.bfloat16
     init_std: float = 0.02
     log_chosen: bool = False
+    routing: str = "top_k_softmax"
+    zero_experts: int = 0
+    scaling: float = 1.0
 
     @nn.compact
     def __call__(self, u, live):
@@ -218,8 +244,9 @@ class HeldExperts(nn.Module):
         lo, hi = self.held
         g, f, k = hi - lo, self.expert_width, self.top_k
         init = nn.initializers.normal(self.init_std)
+        outputs = self.num_experts + self.zero_experts
         router = self.param(
-            "router", init, (h, self.num_experts), self.params_dtype)
+            "router", init, (h, outputs), self.params_dtype)
         w_in = self.param("w_in", init, (g, h, 2 * f), self.params_dtype)
         w_out = self.param("w_out", init, (g, f, h), self.params_dtype)
 
@@ -228,7 +255,16 @@ class HeldExperts(nn.Module):
                 u.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
-            ids, weights = route_top_k(logits, k)
+            if self.routing == "top_k_softmax":
+                ids, weights = route_top_k(logits, k)
+            elif self.routing == "scores_bias":
+                bias = self.param(
+                    "router_bias", nn.initializers.zeros, (outputs,),
+                    jnp.float32)
+                ids, weights = route_scores_bias(
+                    logits, bias, k, self.scaling)
+            else:
+                raise ValueError(f"unknown routing rule {self.routing!r}")
             flat = ids.reshape(t * k) - lo
             valid = (
                 (flat >= 0) & (flat < g) & jnp.repeat(live, k)
@@ -258,24 +294,34 @@ class HeldExperts(nn.Module):
             out = jnp.einsum(
                 "tk,tkh->th", gate, per.astype(jnp.float32))
         fs = self.shared_width
-        s_in = self.param("shared_in", init, (h, 2 * fs), self.params_dtype)
-        s_out = self.param("shared_out", init, (fs, h), self.params_dtype)
-        with jax.named_scope("moe_shared"):
-            ab = jnp.dot(u, s_in.astype(self.dtype))
-            act = (
-                jax.nn.silu(ab[:, :fs].astype(jnp.float32))
-                * ab[:, fs:].astype(jnp.float32)
-            ).astype(self.dtype)
-            out = out + jnp.dot(
-                act, s_out.astype(self.dtype),
-                preferred_element_type=jnp.float32)
+        if fs:
+            s_in = self.param(
+                "shared_in", init, (h, 2 * fs), self.params_dtype)
+            s_out = self.param("shared_out", init, (fs, h), self.params_dtype)
+            with jax.named_scope("moe_shared"):
+                ab = jnp.dot(u, s_in.astype(self.dtype))
+                act = (
+                    jax.nn.silu(ab[:, :fs].astype(jnp.float32))
+                    * ab[:, fs:].astype(jnp.float32)
+                ).astype(self.dtype)
+                out = out + jnp.dot(
+                    act, s_out.astype(self.dtype),
+                    preferred_element_type=jnp.float32)
         counts = dict(
             assignments=jnp.sum(sizes),
             experts_touched=jnp.sum((sizes > 0).astype(jnp.int32)),
             load_max=jnp.max(sizes),
+            zero_assignments=jnp.int32(0),
         )
+        if self.zero_experts:
+            with jax.named_scope("moe_zero"):
+                zero = (ids >= self.num_experts) & live[:, None]
+                out = out + jnp.sum(
+                    jnp.where(zero, weights, 0.0), axis=1, keepdims=True
+                ) * u.astype(jnp.float32)
+                counts["zero_assignments"] = jnp.sum(zero.astype(jnp.int32))
         if self.log_chosen:
-            words = -(-self.num_experts // 32)
+            words = -(-outputs // 32)
             bit = jnp.left_shift(jnp.uint32(1), (ids % 32).astype(jnp.uint32))
             counts["chosen"] = jnp.stack([
                 jnp.sum(jnp.where(ids // 32 == w, bit, jnp.uint32(0)), axis=1)

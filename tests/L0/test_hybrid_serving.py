@@ -17,16 +17,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _serving import VOCAB, prompts_of, recorded, run  # noqa: F401
 
 from benchmarks.families import granite_hybrid as fam
 from benchmarks.harness import rehearsal
 from rocm_apex_tpu.inference import InferenceEngine, SamplingParams
-from rocm_apex_tpu.inference import programs as programs_mod
 from rocm_apex_tpu.models.hybrid import HybridModel
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SEED = 5
-VOCAB = 257
 BUDGET = 16
 
 
@@ -58,33 +57,6 @@ def engine_of(config, params, impl="flash", slots=3, num_pages=48,
         num_pages=num_pages, **more)
 
 
-def run(eng, prompts, max_new):
-    for p in prompts:
-        eng.add_request(p, max_new)
-    out = {}
-    while eng.has_work():
-        for r in eng.step():
-            out[r.request_id] = r
-    return [out[i] for i in sorted(out)]
-
-
-def prompts_of(lengths, seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, VOCAB, size=n).tolist() for n in lengths]
-
-
-@pytest.fixture()
-def recorded(monkeypatch):
-    """Every logits array the engine's programs sample from."""
-    rows = []
-
-    def recording_sample(rng, logits, **kw):
-        jax.debug.callback(
-            lambda x: rows.extend(np.asarray(x, np.float32)), logits)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    monkeypatch.setattr(programs_mod, "sample", recording_sample)
-    return rows
 
 
 @pytest.mark.parametrize("impl", ["flash", "jnp"])
