@@ -16,13 +16,28 @@ first ``rank`` are also the value row.
 ``(num_pages, 1, page_size, width)``, the layout
 `ops/paging.py::paged_scatter` writes (one "head"; ``width`` is
 `latent_width`: the row, then zeros up to whole lane tiles, which the
-query meets with zeros of its own), and a grid step
-takes ALL query heads of one row against one page, so a page is fetched
-once for the 64 heads that read it: about 120 operations a byte read
-where the K/V kernel (`flash_attention_decode_paged`) does one. The
-caller's ``n`` rows each bring their own page list: the decode grid's
-rows are the engine's slots; a packed chunk's rows are its tokens, each
-with its slot's list and pre-chunk length (`models/latent.py`).
+query meets with zeros of its own). A page meets ALL query heads of a
+row at once, so it is fetched once for the 64 heads that read it: about
+120 operations a byte read where the K/V kernel
+(`flash_attention_decode_paged`) does one. The caller's ``n`` rows each
+bring their own page list: the decode grid's rows are the engine's
+slots; a packed chunk's rows are its tokens, each with its slot's list
+and pre-chunk length (`models/latent.py`).
+
+How the kernel walks: ONE grid step a query row, and inside it a loop
+over the ``ceil(length / page_size)`` pages the row has live, in order.
+The pool is not blocked by the grid; it stays in device memory and each
+live page is copied into one of three page buffers. A fetch cursor runs
+through the live pages of all rows, two pages ahead of the products, so
+two copies are always in flight while a third page is multiplied: a
+row's first pages were asked for by the live rows before it, no row but
+the call's first begins by waiting, and the copies follow one another
+at the memory's own rate (on a v5e 0.79 us for a page of 512 x 640
+bfloat16; 0.96 us a page all told, a row's step and its division
+included). A row with nothing to read (an idle slot, a chunk row with
+no cached prefix) costs its one step (0.45 us), copies nothing, and
+writes zeros and a log-sum-exp of -1e30. So a call costs its live pages
+plus a step a row, whatever ``n x pages_per_row`` is.
 
 Rotary positions (`rotary`): pairs interleaved, ``(x[2i], x[2i+1])``
 rotated by ``pos * theta ** (-2i / d)``, angles in float32.
@@ -65,29 +80,74 @@ def rotary(x, positions, theta):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _kernel(scale, rank, ps, tab_ref, len_ref, src_ref, q_ref, c_ref,
-            o_ref, lse_ref, m_scr, l_scr, acc_scr):
-    """Grid point (row, j): all heads of query row ``row`` against page
-    j of the row's list, by the online softmax of
+# pages of the pool the kernel holds at once: the one being multiplied
+# and two on their way (with one on its way the memory idles between
+# copies: 1.0 us a page on a v5e where two in flight give the copy's own
+# 0.79; a third gives no more)
+_BUFFERS = 3
+
+
+def _kernel(scale, rank, ps, num_pages, tab_ref, len_ref, live_ref, q_ref,
+            pool_ref, o_ref, lse_ref, buf, sem, cur, m_scr, l_scr, acc_scr):
+    """Grid point ``row``: all heads of that query row against the pages
+    its length covers, one after another, by the online softmax of
     `flash_attention.py::_decode_paged_kernel` (base 2; natural-log lse
-    at the boundary). A step past the row's live prefix, and every step
-    of a row with nothing to read, holds the block of the step before
-    it (`_page_map`), so nothing is fetched for it."""
-    del tab_ref, src_ref
+    at the boundary). The pool stays where it is. A fetch cursor
+    ``cur`` = (row, page of its list, pages asked for, pages awaited)
+    runs through the live pages of ALL rows, ``_BUFFERS - 1`` pages
+    ahead of the products: whoever multiplies a page first asks for the
+    next one the cursor points at, so a row's first pages are on their
+    way before its step begins and the copies follow one another
+    without a gap. A row with nothing to read copies nothing and loops
+    over nothing."""
     row = pl.program_id(0)
-    j = pl.program_id(1)
+    n = pl.num_programs(0)
     ln = len_ref[row]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    @pl.when(jax.lax.eq(row, 0))
+    def _reset():
+        cur[0] = live_ref[0]
+        cur[1] = 0
+        cur[2] = 0
+        cur[3] = 0
 
-    @pl.when(j * ps < ln)
-    def _body():
+    # scalar arithmetic in plain `lax` primitives: an operator on a
+    # traced scalar is a nested `pjit`, and the kernel is traced anew at
+    # every call site of a served program (two a layer and program)
+    def ask(_, carry):
+        r, j, asked = cur[0], cur[1], cur[2]
+
+        @pl.when(jax.lax.lt(r, n))
+        def _start():
+            slot = jax.lax.rem(asked, _BUFFERS)
+            pltpu.make_async_copy(
+                pool_ref.at[jax.lax.min(tab_ref[r, j], num_pages - 1), 0],
+                buf.at[slot], sem.at[slot]).start()
+            j1 = jax.lax.add(j, 1)
+            last = jax.lax.ge(jax.lax.mul(j1, ps), len_ref[r])
+            cur[0] = jax.lax.select(last, live_ref[jax.lax.add(r, 1)], r)
+            cur[1] = jax.lax.select(last, jnp.int32(0), j1)
+            cur[2] = jax.lax.add(asked, 1)
+
+        return carry
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _page(j, carry):
+        awaited = cur[3]
+        # as many asks as bring the cursor `_BUFFERS` pages past the
+        # last page awaited: all of them at the call's first page, one
+        # at every other
+        jax.lax.fori_loop(jax.lax.sub(cur[2], awaited), _BUFFERS, ask, 0)
+        slot = jax.lax.rem(awaited, _BUFFERS)
+        cur[3] = jax.lax.add(awaited, 1)
+        # the wait reads the semaphore and the size alone
+        pltpu.make_async_copy(
+            pool_ref.at[0, 0], buf.at[slot], sem.at[slot]).wait()
         q = q_ref[0]  # (heads, width)
-        c = c_ref[0, 0]  # (ps, width)
+        c = buf[slot]  # (ps, width)
         s = jax.lax.dot_general(
             q * jnp.asarray(scale * LOG2E, q.dtype), c,
             (((1,), (1,)), ((), ())),
@@ -106,17 +166,18 @@ def _kernel(scale, rank, ps, tab_ref, len_ref, src_ref, q_ref, c_ref,
         )
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        # every row writes its block, live or not: a row with nothing
-        # to read gives zeros at the -inf tier, which a log-sum-exp
-        # merge weighs to exactly zero
-        l = l_scr[:, :1]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(
-            l > 0.0, (m_scr[:, :1] + jnp.log2(safe_l)) * LN2, NEG_INF)
+    jax.lax.fori_loop(0, jax.lax.div(ln + (ps - 1), jnp.int32(ps)), _page, 0)
+
+    # every row writes its block, live or not: a row with nothing to
+    # read gives zeros at the -inf tier, which a log-sum-exp merge
+    # weighs to exactly zero
+    l = l_scr[:, :1]
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
+    lse_ref[0] = jnp.where(
+        l > 0.0, (m_scr[:, :1] + jnp.log2(safe_l)) * LN2, NEG_INF)
 
 
 def bounded_lengths(page_table, lengths, num_pages, ps):
@@ -134,7 +195,10 @@ def mla_decode_paged(q, pool, page_table, lengths, scale, rank):
     page list.
 
     ``q`` (n, heads, width): per head ``[q_n W_uk^T, q_r, 0...]``.
-    ``pool`` (num_pages, 1, page_size, width): rows ``[c', k_r, 0...]``.
+    ``pool`` (num_pages, 1, page_size, width): rows ``[c', k_r, 0...]``;
+    on a chip a page is copied in whole tiles, so ``width`` is whole
+    128-lane tiles (`latent_width`) and ``page_size`` whole sublane
+    tiles of the pool's dtype (16 rows of bfloat16).
     ``page_table`` (n, pages_per_row) int32, unmapped entries
     ``num_pages``; ``lengths`` (n,): row i attends positions ``[0,
     lengths[i])`` of its list, bounded by the pages the list maps.
@@ -148,55 +212,46 @@ def mla_decode_paged(q, pool, page_table, lengths, scale, rank):
         raise ValueError(
             f"latent pool {pool.shape} does not hold one row of {d} a "
             f"position")
-    pages_per_row = page_table.shape[1]
     table, lens = bounded_lengths(page_table, lengths, num_pages, ps)
-    # the row whose block a row's steps hold: itself when it has
-    # something to read, else the last live row before it, else the
-    # first live row after it (`flash_attention_decode_paged`)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(lens > 0, idx, -1))
-    after = jax.lax.cummin(jnp.where(lens > 0, idx, n - 1), reverse=True)
-    src = jnp.where(before >= 0, before, after)
+    # live[i]: the first row at or after i with something to read (n:
+    # none), which is where the fetch cursor goes from row i - 1
+    live = jax.lax.cummin(
+        jnp.where(
+            jnp.pad(lens, (0, 1)) > 0, jnp.arange(n + 1, dtype=jnp.int32), n),
+        reverse=True)
 
-    def _row_map(i, j, tab, lens, src):
+    def _row_map(i, tab, lens, live):
         return (i, 0, 0)
-
-    def _page_map(i, j, tab, lens, src):
-        held = src[i]
-        dead = lens[i] == 0
-        first = jnp.logical_and(dead, held >= i)
-        last_page = jax.lax.max(
-            jax.lax.div(lens[held] + (ps - 1), jnp.int32(ps)), 1) - 1
-        jeff = jax.lax.select(
-            first, jnp.int32(0),
-            jax.lax.select(dead, last_page, jax.lax.min(j, last_page)))
-        return (jax.lax.min(tab[held, jeff], num_pages - 1), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n, pages_per_row),
+        grid=(n,),
         in_specs=[
             pl.BlockSpec((1, heads, d), _row_map),
-            pl.BlockSpec((1, 1, ps, d), _page_map),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, heads, rank), _row_map),
             pl.BlockSpec((1, heads, 1), _row_map),
         ],
         scratch_shapes=[
+            pltpu.VMEM((_BUFFERS, ps, d), pool.dtype),
+            pltpu.SemaphoreType.DMA((_BUFFERS,)),
+            pltpu.SMEM((4,), jnp.int32),
             pltpu.VMEM((heads, 128), jnp.float32),
             pltpu.VMEM((heads, 128), jnp.float32),
             pltpu.VMEM((heads, rank), jnp.float32),
         ],
     )
     o, lse = pallas_call(
-        functools.partial(_kernel, float(scale), rank, ps),
+        functools.partial(_kernel, float(scale), rank, ps, num_pages),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((n, heads, rank), q.dtype),
             jax.ShapeDtypeStruct((n, heads, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=32 * 1024 * 1024),
-    )(table, lens, src, q, pool)
+    )(table, lens, live, q, pool)
     return o, lse[..., 0]

@@ -8,8 +8,11 @@ import pytest
 
 from benchmarks.families import longcat_flash as fam
 from rocm_apex_tpu.ops import mla
+from _helpers import assert_close
 
-N, HEADS, RANK, ROPE, PS, PAGES, PER_ROW = 6, 4, 32, 8, 8, 12, 3
+# chip-legal toy sizes: the kernel copies whole tiles of a page, so a
+# row is 128 lanes and a page 16 rows (a bfloat16 tile)
+N, HEADS, RANK, ROPE, PS, PAGES, PER_ROW = 6, 4, 96, 32, 16, 12, 3
 
 
 def reference(q, pool, page_table, lengths, scale, rank):
@@ -54,6 +57,66 @@ def test_the_kernel_matches_the_gathered_read():
     assert o.shape == (N, HEADS, RANK) and lse.shape == (N, HEADS)
     np.testing.assert_allclose(o, ro, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(lse, rlse, rtol=1e-5, atol=1e-5)
+
+
+FULL = PER_ROW * PS
+# lengths of eight rows, each with pages of its own
+RAGGED = {
+    "whole pages": [PS, 2 * PS, FULL, PS, 0, 2 * PS, PS, FULL],
+    "one position": [1] * 8,
+    "every page of the list": [FULL, FULL - 1, FULL, FULL - PS + 1] * 2,
+    "dead rows first": [0, 0, 0, 5, 17, 0, 9, FULL],
+    "dead rows in the middle": [11, 0, 0, 0, 0, 20, 0, 3],
+    "dead rows last": [7, FULL, 0, 13, 0, 0, 0, 0],
+    "one live row": [0, 0, 0, 0, 0, 2 * PS + 1, 0, 0],
+}
+# (o, lse rtol, lse atol), as the two tests above
+TOLERANCE = {jnp.float32: (1e-5, 1e-5, 1e-5), jnp.bfloat16: (3e-2, 2e-2, 5e-2)}
+
+
+def ragged(case):
+    """(page table, lengths) of one ragged case: pages of ``PS``
+    positions, ``PER_ROW`` to a list."""
+    own = np.random.default_rng(7).permutation(8 * PER_ROW) % PAGES
+    own = own.astype(np.int32).reshape(8, PER_ROW)
+    if case == "one row":
+        return own[:1], np.array([PS + 3], np.int32)
+    if case == "every row dead":
+        # the engine's dead rows: a length (the capacity) and no page
+        return np.full_like(own, PAGES), np.array([0, FULL] * 4, np.int32)
+    if case == "rows of one slot":
+        # a chunk's consecutive rows: one page list, each row its own
+        # pre-chunk length (and a second slot's rows after them)
+        return np.stack([own[0]] * 5 + [own[1]] * 3), np.array(
+            [FULL, 1, PS, PS + 1, 0, 2 * PS - 1, 2 * PS - 1, 4], np.int32)
+    lens = np.array(RAGGED[case], np.int32)
+    unmapped = np.arange(PER_ROW)[None, :] >= -(-lens // PS)[:, None]
+    return np.where(unmapped, PAGES, own).astype(np.int32), lens
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "case", ["one row", "every row dead", "rows of one slot", *RAGGED])
+def test_ragged_rows_match_the_gathered_read(case, dtype):
+    table, lengths = ragged(case)
+    n = table.shape[0]
+    rng = np.random.default_rng(8)
+    q = jnp.asarray(rng.normal(size=(n, HEADS, RANK + ROPE)), dtype)
+    pool = jnp.asarray(rng.normal(size=(PAGES, 1, PS, RANK + ROPE)), dtype)
+    o, lse = mla.mla_decode_paged(q, pool, table, lengths, 0.3, RANK)
+    ro, rlse = reference(q, pool, table, lengths, 0.3, RANK)
+    assert o.shape == (n, HEADS, RANK) and o.dtype == dtype
+    tol, lse_rtol, lse_atol = TOLERANCE[dtype]
+    assert_close(
+        o.astype(jnp.float32), ro, rtol=tol, atol=tol,
+        tpu_rtol=5e-2, tpu_atol=5e-2)
+    assert_close(
+        lse, rlse, rtol=lse_rtol, atol=lse_atol, tpu_rtol=5e-2, tpu_atol=5e-2)
+    _, read = mla.bounded_lengths(table, lengths, PAGES, PS)
+    dead = np.asarray(read) == 0
+    assert float(jnp.abs(o[dead]).max(initial=0.0)) == 0.0
+    assert float(lse[dead].max(initial=-1e30)) <= -1e29
 
 
 def test_a_row_that_maps_no_page_reads_nothing_whatever_its_length():
