@@ -239,6 +239,9 @@ class _Slot:
     # adapter-pool buffer slot this lease holds ONE admission ref on
     # (0 = base, no ref; -1 = already released — the teardown guard)
     adapter_slot: int = 0
+    # window group only: the first page index this slot still maps there
+    # (the pages before it lay behind its window and went back)
+    window_head: int = 0
 
     @property
     def prefilling(self) -> bool:
@@ -439,9 +442,12 @@ class InferenceEngine:
         # options are refused here and not at their first use. A LATENT
         # row lives in pages as K/V does, so a model that keeps one is
         # refused only what its own code does not do.
-        kinds = {layer["kind"] for layer in model.cache_spec()}
+        spec = model.cache_spec()
+        kinds = {layer["kind"] for layer in spec}
         self._stateful = "ssm" in kinds  # keeps per-slot state
         self._latent = "latent" in kinds
+        # frees the pages its window layers' rows have left
+        self._windowed = any(layer.get("window") for layer in spec)
         quantized = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
         refused = {}
         if self._stateful:
@@ -469,6 +475,25 @@ class InferenceEngine:
                 "adapter_pool (its projections take no adapters)":
                     adapter_pool is not None,
                 "kv_dtype=int8 (a latent pool has no int8 form)": quantized,
+            }
+        elif self._windowed:
+            keeps = (
+                "frees the pages that its window layers' rows have left "
+                "while a request runs")
+            refused = {
+                "paged=False (the contiguous cache keeps every row of a "
+                "slot)": not paged,
+                "prefix_sharing (a registered page may be freed behind "
+                "its borrower's window)": prefix_sharing,
+                "spec_k > 0 (the commit program writes the global "
+                "group's pools by layer; a window layer hands back no "
+                "deferred rows)": spec_k > 0,
+                "tensor_parallel_size > 1 (the window group's pools and "
+                "table have no sharded layout)": tp > 1,
+                "adapter_pool (its projections take no adapters)":
+                    adapter_pool is not None,
+                "kv_dtype=int8 (a windowed read has no int8 form)":
+                    quantized,
             }
         for what, asked in refused.items():
             if asked:
@@ -581,6 +606,8 @@ class InferenceEngine:
         self.paged = bool(paged)
         self.prefix_sharing = bool(prefix_sharing)
         self._allocator = None
+        self._window_allocator = None
+        self._window_pages_freed = 0  # in the tick under way
         self._store = None
         # preempted-request carryover: request_id -> (generated tokens,
         # first_token_at, chunk count) restored on re-admission
@@ -617,12 +644,23 @@ class InferenceEngine:
                     else cache_dtype or cfg.dtype
                 ),
                 quantized=quantized,
+                prefill_token_budget=self.prefill_token_budget,
             )
             if tp > 1:
                 self.cache = jax.device_put(
                     self.cache, self._cache_sharding()
                 )
             self._allocator = PageAllocator(self.cache.num_pages)
+            if self.cache.window:
+                # the window group: pools behind a table and an
+                # allocator of their own (`_free_window_pages`)
+                self._window_allocator = PageAllocator(
+                    self.cache.window_pages)
+                self._window_table = np.full(
+                    (num_slots, self.cache.pages_per_slot),
+                    self.cache.window_pages, np.int32,
+                )
+                self._window_table_dirty = False
             if prefix_sharing:
                 self._store = PrefixStore(page_size)
                 self._allocator.on_evict = self._store.unregister_page
@@ -1132,10 +1170,8 @@ class InferenceEngine:
 
         # page-occupancy counters (zeros when not paged, so one
         # MetricsLogger schema serves both engines)
-        pages_total = float(self.cache.num_pages) if self.paged else 0.0
-        pages_used = (
-            float(self._allocator.pages_used) if self.paged else 0.0
-        )
+        pages_total = float(self.pages_total)
+        pages_used = float(self.pages_used)
         shared_ratio = 0.0
         if self.paged:
             sentinel = self.cache.num_pages
@@ -1501,6 +1537,12 @@ class InferenceEngine:
         ) as tick:
             # read before the tick maps or frees a page
             pages_used = self.pages_used
+            window = {}
+            if self._window_allocator is not None:
+                window = dict(
+                    window_pages_used=self._window_allocator.pages_used,
+                    window_pages_total=self._window_allocator.num_pages)
+                self._window_pages_freed = 0
             out, leased = self._admit_phase()
             if self.chunked:
                 finished, counts = self._step_chunked()
@@ -1511,6 +1553,8 @@ class InferenceEngine:
             # (docs/observability.md says what reads each); the legacy
             # mode admits in `_step_whole` and counts its own
             counts.setdefault("admitted", leased)
+            if window:  # pages that went back behind windows in this tick
+                window["window_pages_freed"] = self._window_pages_freed
             tick.set_metadata(
                 **counts,
                 finished=len(out),
@@ -1518,7 +1562,8 @@ class InferenceEngine:
                 slots=self.num_slots,
                 budget=self.prefill_token_budget or 0,
                 pages_used=pages_used,
-                pages_total=self.cache.num_pages if self.paged else 0,
+                pages_total=self.pages_total,
+                **window,
             )
         return out
 
@@ -1658,6 +1703,9 @@ class InferenceEngine:
         if self.paged:
             sentinel = self.cache.num_pages
             mapped = int((self._table != sentinel).sum())
+            if self._window_allocator is not None:
+                mapped += int(
+                    (self._window_table != self.cache.window_pages).sum())
             if mapped:
                 dirty.append(f"{mapped} mapped page-table entries")
         if dirty:
@@ -1668,7 +1716,8 @@ class InferenceEngine:
         if self.paged:
             # the allocator's own invariants (free-list / refcounts /
             # parked set) must hold before we accept traffic again
-            self._allocator.assert_consistent()
+            for allocator in self._allocators():
+                allocator.assert_consistent()
         self._draining = False
         self._watchdog_fires = 0
         self._progress_mark = (
@@ -1775,6 +1824,13 @@ class InferenceEngine:
                 f"{type(self.model).__name__} keeps latent rows in pages: "
                 f"the shipped payload is the K and V pools' and does not "
                 f"carry them (evacuate and resume by tokens)"
+            )
+        if asked and self._windowed:
+            raise ValueError(
+                f"{type(self.model).__name__} frees the pages that its "
+                f"window layers' rows have left: the shipped payload is a "
+                f"slot's whole page list in the global group's pools and "
+                f"carries no window group (evacuate and resume by tokens)"
             )
 
     def evacuate_request(
@@ -1966,7 +2022,19 @@ class InferenceEngine:
     def pages_used(self) -> int:
         """Pages holding a live mapping (0 on the contiguous cache) —
         the memory-pressure term of least-loaded placement."""
-        return int(self._allocator.pages_used) if self.paged else 0
+        return sum(a.pages_used for a in self._allocators())
+
+    @property
+    def pages_total(self) -> int:
+        """Pages of every group (0 on the contiguous cache)."""
+        return sum(a.num_pages for a in self._allocators())
+
+    def _allocators(self) -> List[PageAllocator]:
+        """The page groups' allocators: the global group's, then the
+        window group's where the model declares one."""
+        return [
+            a for a in (self._allocator, self._window_allocator)
+            if a is not None]
 
     @property
     def progress_marker(self) -> Tuple[int, int, int]:
@@ -2041,6 +2109,10 @@ class InferenceEngine:
             self.cache = self.cache.replace(
                 page_table=self._replicated(jnp.asarray(self._table)))
             self._table_dirty = False
+        if self._window_allocator is not None and self._window_table_dirty:
+            self.cache = self.cache.replace(
+                window_table=jnp.asarray(self._window_table))
+            self._window_table_dirty = False
 
     def _export_slot_pages(self, st: _Slot, slot: int):
         """Snapshot the slot's mapped KV pages as a migration payload —
@@ -2188,6 +2260,16 @@ class InferenceEngine:
             # injected allocator failure: indistinguishable from a
             # genuinely exhausted pool — the caller backpressures
             return False
+        if self._window_allocator is not None and (
+            self._window_table[slot, idx] == self.cache.window_pages
+        ):
+            # the window group's page of the same positions; a global
+            # page mapped while this one is wanting stays the slot's
+            got = self._window_allocator.alloc(1)
+            if got is None:
+                return False
+            self._window_table[slot, idx] = got[0]
+            self._window_table_dirty = True
         sentinel = self.cache.num_pages
         page = int(self._table[slot, idx])
         track = f"req{st.req.request_id}"
@@ -2276,6 +2358,34 @@ class InferenceEngine:
             self._table[slot, idx] = sentinel
         self._table_dirty = True
         st.borrowed.clear()
+        if self._window_allocator is not None:
+            row = self._window_table[slot]
+            for page in row[row != self.cache.window_pages]:
+                self._window_allocator.decref(int(page))
+            row[:] = self.cache.window_pages
+            self._window_table_dirty = True
+
+    def _free_window_pages(self) -> None:
+        """After a tick commits: unmap and free every page of the window
+        group that lies wholly behind what a slot's NEXT row can attend.
+        That row sits at ``st.pos`` (the next chunk's first row while
+        the prompt is prefilled, else the next decode row) and attends
+        from ``st.pos + 1 - window``; every later row attends later
+        keys. The global group's pages stay until the slot ends."""
+        window, ps = self.cache.window, self.cache.page_size
+        sentinel = self.cache.window_pages
+        for slot, st in enumerate(self._slots):
+            if st is None:
+                continue
+            behind = (st.pos + 1 - window) // ps  # pages wholly behind
+            for idx in range(st.window_head, behind):
+                page = int(self._window_table[slot, idx])
+                if page != sentinel:
+                    self._window_allocator.decref(page)
+                    self._window_table[slot, idx] = sentinel
+                    self._window_table_dirty = True
+                    self._window_pages_freed += 1
+            st.window_head = max(st.window_head, behind)
 
     def _release_adapter(self, st: _Slot) -> None:
         """Drop an in-flight request's adapter residency ref, exactly
@@ -2301,13 +2411,17 @@ class InferenceEngine:
         mapped slot is drained and the pool is still empty (pages
         pinned elsewhere), the original deadlock diagnosis raises."""
         sentinel = self.cache.num_pages
-        while self._allocator.available < 1:
+        while any(a.available < 1 for a in self._allocators()):
             victim, vslot = None, -1
             for slot, st in enumerate(self._slots):
                 if st is None:
                     continue
                 if not any(
                     int(p) != sentinel for p in self._table[slot]
+                ) and not (
+                    self._window_allocator is not None
+                    and (self._window_table[slot]
+                         != self.cache.window_pages).any()
                 ):
                     continue
                 if victim is None or st.leased_at >= victim.leased_at:
@@ -2324,8 +2438,8 @@ class InferenceEngine:
                     "paged KV pool deadlock: every in-flight request "
                     "is stalled waiting for pages, no decode can run "
                     "to free any, and no slot holds reclaimable pages "
-                    f"(pages={self.cache.num_pages}, used="
-                    f"{self._allocator.pages_used}); size num_pages "
+                    f"(pages={self.pages_total}, used="
+                    f"{self.pages_used}); size num_pages "
                     "for the expected live tokens, or admit less "
                     "concurrency"
                 )
@@ -2889,28 +3003,51 @@ class InferenceEngine:
                     drafts_np, counts_np = self._drafter(hist, hist_len)
                     t_d1 = time.perf_counter()
 
+            # the budget goes to the prefilling slots in the order they
+            # were LEASED (the queue's order: within one tick's admissions
+            # the slot index), not by slot index: a long prompt in a high
+            # slot does not wait behind every newcomer in a lower one, and
+            # which slot a request happened to get decides nothing (by
+            # slot index a saturated run's tokens/s spread twice as
+            # widely over seeds: PERF.md, PR 35)
+            grants = {}
+            left = budget
+            for slot in sorted(
+                (i for i, s in enumerate(self._slots)
+                 if s is not None and s.prefilling),
+                key=lambda i: (self._slots[i].leased_at, i),
+            ):
+                if left <= 0:
+                    break
+                st = self._slots[slot]
+                n = min(left, len(st.prefix) - st.cursor)
+                if self.prefill_chunk is not None:
+                    n = min(n, self.prefill_chunk)
+                if self.paged:
+                    # pool backpressure: only tokens whose pages exist
+                    # (or could be allocated / CoW-forked) are
+                    # scheduled; a starved slot just waits for
+                    # evictions to free pages
+                    n = self._secure_prefill_pages(st, slot, n)
+                if n > 0:
+                    grants[slot] = n
+                    left -= n
+
             # slot order keeps the packed segment ids non-decreasing (the
             # varlen kernel's contract); a slot contributes either prefill
-            # rows or a speculative span, never both
+            # rows or a speculative span (from what the prompts left of
+            # the budget), never both
             for slot in range(S):
                 st = self._slots[slot]
                 if st is not None:
                     lengths_before[slot] = st.pos
                     lengths_after[slot] = st.pos
-                if st is None or used >= budget:
+                if st is None:
                     continue
                 if st.prefilling:
-                    n = min(budget - used, len(st.prefix) - st.cursor)
-                    if self.prefill_chunk is not None:
-                        n = min(n, self.prefill_chunk)
-                    if self.paged:
-                        # pool backpressure: only tokens whose pages exist
-                        # (or could be allocated / CoW-forked) are
-                        # scheduled; a starved slot just waits for
-                        # evictions to free pages
-                        n = self._secure_prefill_pages(st, slot, n)
-                        if n <= 0:
-                            continue
+                    n = grants.get(slot, 0)
+                    if n <= 0:
+                        continue
                     chunk_tokens[used:used + n] = st.prefix[
                         st.cursor:st.cursor + n
                     ]
@@ -2963,7 +3100,7 @@ class InferenceEngine:
                 if drafts_np is None or not st.generated:
                     continue
                 n = min(
-                    int(counts_np[slot]), self.spec_k, budget - used - 1,
+                    int(counts_np[slot]), self.spec_k, left - 1,
                     self.capacity - st.pos - 1,
                     st.req.max_new_tokens - len(st.generated) - 1,
                 )
@@ -2986,6 +3123,7 @@ class InferenceEngine:
                 spec_entries.append((slot, used, n, drafts, st.pos))
                 self._tokens_drafted += n
                 used += n + 1
+                left -= n + 1
 
             if poison_slot >= 0:
                 # poison the faulted slot's chunk rows too (a prompt
@@ -3127,7 +3265,7 @@ class InferenceEngine:
             }
             if layer_counts is not None:
                 counts.update(zip(
-                    self.cache.COUNTER_NAMES,
+                    self.cache.counter_names,
                     (int(c) for c in layer_counts),
                 ))
             # the device step committed: NOW the tick's full prompt pages
@@ -3285,6 +3423,8 @@ class InferenceEngine:
                         self.cache, chunk_kv,
                         jnp.asarray(commit_np), jnp.asarray(commit_pos_np),
                     )
+            if self._window_allocator is not None:
+                self._free_window_pages()
             self._close_tick()
         return finished, counts
 

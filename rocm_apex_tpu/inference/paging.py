@@ -44,7 +44,20 @@ from rocm_apex_tpu.ops.paging import (
     quantized_paged_scatter,
 )
 
-__all__ = ["PageAllocator", "PrefixStore", "PagedKVCache"]
+__all__ = [
+    "PageAllocator", "PrefixStore", "PagedKVCache", "window_pages_per_slot",
+]
+
+
+def window_pages_per_slot(window: int, page_size: int, budget: int,
+                          capacity: int) -> int:
+    """The most pages of a window group one slot holds: while a chunk of
+    ``budget`` rows is prefilled its first row still attends ``window -
+    1`` keys before it, so ``window - 1 + budget`` positions are live,
+    which straddle one page more than they fill; never more than the
+    context's own pages."""
+    live = window - 1 + budget
+    return min(-(-live // page_size) + 1, -(-capacity // page_size))
 
 
 class PageAllocator:
@@ -363,12 +376,25 @@ class PagedKVCache:
 
     ``counters`` (None unless a layer declares ``counters``): the
     tick's sums over layers and over the step program's applies, named
-    by ``COUNTER_NAMES``; `start_tick` zeroes them and the engine
+    by ``counter_names``; `start_tick` zeroes them and the engine
     fetches them with the tick's tokens. ``routes`` (None unless a
     layer declares ``route_words``, a model's debug option): one more
     pool, ``(num_pages, 1, page_size, lanes)`` uint32 behind the same
     page table, a position's row holding layer after layer the words
     of the mask of experts its router chose.
+
+    A K/V layer may declare a sliding ``window``: it attends the
+    ``window`` keys that end at a row's own and no further back. The
+    pools of such layers (``window_k``/``window_v``, in layer order)
+    form a group of their own: ``(window_pages, heads, page_size,
+    head_dim)`` behind ``window_table`` (the shape of ``page_table``;
+    unmapped entries hold ``window_pages``), with an allocator of its
+    own on the host. The engine unmaps and frees a page of this group
+    once it lies wholly behind every key the slot's next row can
+    attend, while the request runs, so a slot holds a window's worth of
+    them and not its whole context; ``k``/``v`` then hold the layers
+    that attend every earlier key (the GLOBAL group). ``window`` is the
+    group's length in keys, 0 where no layer declares one.
     """
 
     k: Tuple[jnp.ndarray, ...]
@@ -383,11 +409,24 @@ class PagedKVCache:
     routes: Optional[jnp.ndarray] = None
     counters: Optional[jnp.ndarray] = None
     latent: Tuple[jnp.ndarray, ...] = ()
+    window_k: Tuple[jnp.ndarray, ...] = ()
+    window_v: Tuple[jnp.ndarray, ...] = ()
+    window_table: Optional[jnp.ndarray] = None
+    window: int = struct.field(pytree_node=False, default=0)
 
     COUNTER_NAMES = (
         "moe_assignments", "moe_experts_touched", "moe_load_max",
         "state_slots_live", "moe_zero_assignments", "latent_rows_read",
     )
+    # a cache with a window group counts two more: the cached positions
+    # the decode grid attended over after the window's bound, and before
+    WINDOW_COUNTER_NAMES = ("kv_rows_read", "kv_rows_cached")
+
+    @property
+    def counter_names(self) -> Tuple[str, ...]:
+        """The names of ``counters``' entries, in order."""
+        return self.COUNTER_NAMES + (
+            self.WINDOW_COUNTER_NAMES if self.window else ())
 
     # ------------------------------------------------------------------
     # construction
@@ -499,6 +538,7 @@ class PagedKVCache:
         num_pages: Optional[int] = None,
         dtype: Any = jnp.bfloat16,
         quantized: bool = False,
+        prefill_token_budget: Optional[int] = None,
     ) -> "PagedKVCache":
         """The cache of a model whose layers declare what they keep, a
         list with one entry per thing kept (a layer with two attention
@@ -509,8 +549,18 @@ class PagedKVCache:
         state=, conv=, state_dtype=)`` for one that scans; any entry may
         add ``counters=True`` and ``route_words=<n>``. Something has to
         live in pages (``kv`` or ``latent``): the engine schedules by
-        them."""
+        them.
+
+        A ``kv`` entry may add ``window=<keys>``: those layers' pools
+        form the window group (one window length serves them all).
+        ``num_pages`` is then the global group's count or the pair
+        ``(global, window)``; a window group given no count gets its
+        worst case, `window_pages_per_slot` a slot (it never stalls)."""
         from rocm_apex_tpu.ops.mla import latent_width
+
+        window_pages = None
+        if isinstance(num_pages, (tuple, list)):
+            num_pages, window_pages = num_pages
 
         by_kind = {"kv": [], "latent": [], "ssm": []}
         for entry in spec:
@@ -519,12 +569,25 @@ class PagedKVCache:
                     f"a layer declares kind {entry['kind']!r}; the cache "
                     f"knows {sorted(by_kind)}")
             by_kind[entry["kind"]].append(entry)
-        kv_layers, ssm_layers = by_kind["kv"], by_kind["ssm"]
-        if not kv_layers and not by_kind["latent"]:
+        ssm_layers = by_kind["ssm"]
+        windowed = [s for s in by_kind["kv"] if s.get("window")]
+        kv_layers = [s for s in by_kind["kv"] if not s.get("window")]
+        windows = {int(s["window"]) for s in windowed}
+        if len(windows) > 1:
+            raise ValueError(
+                f"K/V layers declare windows {sorted(windows)}; one "
+                f"window group serves one length")
+        if windowed and quantized:
+            raise ValueError("a window group has no int8 form")
+        if window_pages is not None and not windowed:
+            raise ValueError(
+                "num_pages names a window group's pages and no layer "
+                "declares a window")
+        if not by_kind["kv"] and not by_kind["latent"]:
             raise ValueError(
                 "the engine schedules by pages: a model that keeps "
                 "neither K/V nor latent rows has none to schedule by")
-        shapes = {(s["heads"], s["head_dim"]) for s in kv_layers}
+        shapes = {(s["heads"], s["head_dim"]) for s in by_kind["kv"]}
         if len(shapes) > 1:
             raise ValueError(
                 f"K/V layers differ in (heads, head_dim): {sorted(shapes)}; "
@@ -541,6 +604,20 @@ class PagedKVCache:
             num_slots * cache.pages_per_slot if num_pages is None
             else num_pages)
         words = sum(s.get("route_words", 0) for s in spec)
+        if windowed:
+            window = windows.pop()
+            if window_pages is None:
+                window_pages = min(pages, num_slots * window_pages_per_slot(
+                    window, page_size, prefill_token_budget or capacity,
+                    capacity))
+            shape = (window_pages, heads, page_size, head_dim)
+            cache = cache.replace(
+                window_k=tuple(jnp.zeros(shape, dtype) for _ in windowed),
+                window_v=tuple(jnp.zeros(shape, dtype) for _ in windowed),
+                window_table=jnp.full(
+                    (num_slots, cache.pages_per_slot), window_pages,
+                    jnp.int32),
+                window=window)
         return cache.replace(
             latent=tuple(
                 jnp.zeros(
@@ -556,16 +633,16 @@ class PagedKVCache:
             routes=jnp.zeros(
                 (pages, 1, page_size, -(-words // 128) * 128),
                 jnp.uint32) if words else None,
-            counters=jnp.zeros((len(cls.COUNTER_NAMES),), jnp.int32)
+            counters=jnp.zeros((len(cache.counter_names),), jnp.int32)
             if any(s.get("counters") for s in spec) else None,
         )
 
     def count(self, **sums) -> "PagedKVCache":
-        """The tick's counters with ``sums`` (by their `COUNTER_NAMES`)
+        """The tick's counters with ``sums`` (by their `counter_names`)
         added in; ``moe_load_max`` is a running maximum."""
         new = list(self.counters)
         for name, value in sums.items():
-            i = self.COUNTER_NAMES.index(name)
+            i = self.counter_names.index(name)
             new[i] = (
                 jnp.maximum(new[i], value) if name == "moe_load_max"
                 else new[i] + value)
@@ -596,7 +673,16 @@ class PagedKVCache:
 
     @property
     def num_pages(self) -> int:
-        return (self.k or self.latent)[0].shape[0]
+        """Pages of the group behind ``page_table`` (a model whose K/V
+        layers all declare a window keeps that table for its scheduling
+        alone, over as many pages as the window group has)."""
+        pools = self.k or self.latent or self.window_k
+        return pools[0].shape[0]
+
+    @property
+    def window_pages(self) -> int:
+        """Pages of the window group (0 where there is none)."""
+        return self.window_k[0].shape[0] if self.window_k else 0
 
     @property
     def capacity(self) -> int:
@@ -616,8 +702,11 @@ class PagedKVCache:
         total = 0
         extra = tuple(
             a for a in (self.routes, self.counters) if a is not None)
+        if self.window_table is not None:
+            extra += (self.window_table,)
         for arrs in (self.k, self.v, self.k_scale or (), self.v_scale or (),
-                     self.latent, self.ssm, self.conv, extra):
+                     self.latent, self.ssm, self.conv, self.window_k,
+                     self.window_v, extra):
             for a in arrs:
                 total += a.size * a.dtype.itemsize
         total += self.page_table.size * self.page_table.dtype.itemsize

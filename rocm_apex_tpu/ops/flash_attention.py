@@ -121,7 +121,7 @@ def _keep_mask(seed_ref, rate, b, qi, ki, shape):
 
 def _masked_scores(
     causal, scale, sk_real, block_q, block_k,
-    q, k, bias_ref, len_ref, b, qi, ki, seg=None,
+    q, k, bias_ref, len_ref, b, qi, ki, seg=None, window=None,
 ):
     """The masked BASE-2 score block for grid point (b, qi, ki) —
     shared by ALL FOUR kernels (fwd, dkv, dq, dbias). Masking semantics
@@ -164,6 +164,11 @@ def _masked_scores(
             jnp.int32, (block_q, block_k), 0
         )
         s = jnp.where(row >= col, s, NEG_INF)
+        if window is not None:
+            # a sliding window over the stream: row i sees the `window`
+            # keys that end at its own (rows of one packed segment are
+            # contiguous, so stream distance is position distance)
+            s = jnp.where(row - col < window, s, NEG_INF)
     return s
 
 
@@ -1012,8 +1017,8 @@ def _paged_grid_row(b, nhb, row_blocks):
 
 
 def _decode_paged_kernel(
-    scale, hb, nhb, ps, num_pages, block_t, quantized, row_blocks,
-    tab_ref, len_ref, src_ref, q_ref, k_ref, v_ref, *rest,
+    scale, hb, nhb, ps, num_pages, block_t, quantized, row_blocks, bound,
+    tab_ref, len_ref, src_ref, *rest,
 ):
     """Online-softmax decode against a PAGED cache for grid point
     (b, j): b = (slot, head block, row block), slot-major, and j walks
@@ -1032,8 +1037,21 @@ def _decode_paged_kernel(
     ``quantized`` adds per-(page, head) fp32 dequantization: int8
     tiles are scaled into the score/value dots from SMEM-resident
     scale tables (``hb`` scalar reads a step). ``src_ref`` is only the
-    index maps' (`flash_attention_decode_paged`)."""
+    index maps' (`flash_attention_decode_paged`).
+
+    ``bound`` (None, ``"slot"`` or ``"rows"``) is a LOWER bound on the
+    positions read, a sliding window's: a fourth prefetched vector gives
+    each slot's first position, step j takes the page ``first // ps +
+    j`` (the pages before it are never fetched) and the first live page
+    is masked from the bound on; with ``"rows"`` each query row masks
+    from a bound of its own (one more block, ``(block_t, 1)``)."""
     del src_ref
+    first_ref = lo_ref = None
+    if bound is not None:
+        first_ref, rest = rest[0], rest[1:]
+    q_ref, k_ref, v_ref, *rest = rest
+    if bound == "rows":
+        lo_ref, rest = rest[0], rest[1:]
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -1044,6 +1062,14 @@ def _decode_paged_kernel(
     slot, hblk, _ = _paged_grid_row(b, nhb, row_blocks)
     head0 = hblk * hb
     ln = len_ref[slot]
+    # (without a bound the first position of step j's page stays the
+    # `j * ps` it was, written where it was: the older callers' programs
+    # are held to what they traced)
+    if bound is not None:
+        first = first_ref[slot]
+        row0 = jax.lax.mul(
+            jax.lax.add(jax.lax.div(first, jnp.int32(ps)), j),
+            jnp.int32(ps))
 
     @pl.when(j == 0)
     def _init():
@@ -1052,9 +1078,12 @@ def _decode_paged_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _body():
-        col = j * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (block_t, ps), 1
+        col = (j * ps if bound is None else row0) + (
+            jax.lax.broadcasted_iota(jnp.int32, (block_t, ps), 1)
         )
+        if bound is not None:
+            lo = first if bound == "slot" else lo_ref[...]
+            seen = jnp.logical_and(col < ln, col >= lo)
         if quantized:
             # j is inside the live prefix here, so this is the page the
             # index map fetched
@@ -1076,7 +1105,7 @@ def _decode_paged_kernel(
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32, precision=_PREC,
             )
-            s = jnp.where(col < ln, s, NEG_INF)
+            s = jnp.where(col < ln if bound is None else seen, s, NEG_INF)
             m_prev = m_scr[h, :, :1]
             m_new = jnp.maximum(
                 m_prev, jnp.max(s, axis=1, keepdims=True)
@@ -1101,7 +1130,7 @@ def _decode_paged_kernel(
 
     # pages wholly past the live prefix: no compute AND no fetch (the
     # index map held their DMA on an already-resident block)
-    pl.when(j * ps < ln)(_body)
+    pl.when((j * ps if bound is None else row0) < ln)(_body)
 
     @pl.when(j == nj - 1)
     def _finish():
@@ -1129,6 +1158,8 @@ def flash_attention_decode_paged(
     v_scale: Optional[jnp.ndarray] = None,
     return_lse: bool = False,
     _row_blocks: int = 1,
+    window: Optional[int] = None,
+    q_positions: Optional[jnp.ndarray] = None,
 ):
     """`flash_attention_decode` reading through a block table.
 
@@ -1179,6 +1210,20 @@ def flash_attention_decode_paged(
     only, like every decode read. ``return_lse`` as in
     `flash_attention_decode` (rows with an empty prefix carry
     -inf-tier lse so a log-sum-exp merge drops them).
+
+    ``window`` (a layer with a sliding window; None reads from position
+    0 and traces what it always did) bounds the read from below. Without
+    ``q_positions`` every row of slot s is the decode row at position
+    ``kv_lengths[s] - 1`` and attends ``[max(0, kv_lengths[s] - window),
+    kv_lengths[s])``: the ``window`` keys that end at its own. With
+    ``q_positions`` (t,) row r sits at that position (a packed chunk
+    scored against each slot's prefix: a slot's earliest row is at
+    ``kv_lengths[s]``) and attends ``[max(0, q_positions[r] + 1 -
+    window), kv_lengths[s])``. Either way the pages that lie wholly
+    before a slot's bound are neither fetched nor need to be mapped (the
+    cache frees them: their table entries hold the sentinel), the first
+    live page is masked from the bound on, and the grid's page axis is
+    as long as a window is and no longer.
     """
     bh, t, d0 = q.shape
     num_pages, nh, ps, dp = k_pool.shape
@@ -1202,7 +1247,10 @@ def flash_attention_decode_paged(
         out = flash_attention_decode_paged(
             q.reshape(bh // fold, fold * t, d0), k_pool, v_pool,
             page_table, kv_lengths, scale, k_scale, v_scale,
-            return_lse=True, _row_blocks=group // fold,
+            return_lse=True, _row_blocks=group // fold, window=window,
+            q_positions=(
+                None if q_positions is None
+                else jnp.tile(q_positions, fold)),
         )
         o, lse = out[0].reshape(bh, t, d0), out[1].reshape(bh, t)
         return (o, lse) if return_lse else o
@@ -1224,11 +1272,30 @@ def flash_attention_decode_paged(
     kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d - d0)))
     vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d - d0)))
     table = jnp.asarray(page_table, jnp.int32)
+    bound, walk, first = None, pages_per_slot, None
+    if window is not None:
+        if quantized:
+            raise ValueError("a windowed read has no int8 form")
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        bound = "slot" if q_positions is None else "rows"
+        # a window's keys lie in this many pages at most
+        walk = min(pages_per_slot, (window + ps - 2) // ps + 1)
+        first = jnp.maximum(
+            jnp.asarray(kv_lengths, jnp.int32)
+            + (0 if q_positions is None else 1) - window, 0)
     # the table bounds the read: no slot reads past its mapped pages, so
     # a slot that owns none has nothing to read whatever length it
     # carries (the engine's dead rows carry the capacity sentinel)
+    is_mapped = table < num_pages
+    if window is not None:
+        # the pages before the bound count as mapped: nothing reads them
+        is_mapped = jnp.logical_or(
+            is_mapped,
+            jnp.arange(pages_per_slot, dtype=jnp.int32)[None, :]
+            < (first // ps)[:, None])
     mapped = jnp.sum(
-        jnp.cumprod((table < num_pages).astype(jnp.int32), axis=1), axis=1
+        jnp.cumprod(is_mapped.astype(jnp.int32), axis=1), axis=1
     )
     lens = jnp.minimum(jnp.asarray(kv_lengths, jnp.int32), mapped * ps)
     # the slot whose block a slot's steps hold: itself when it has
@@ -1241,10 +1308,10 @@ def flash_attention_decode_paged(
     )
     src = jnp.where(before >= 0, before, after)
 
-    def _row_map(b, j, tab, lens, src):
+    def _row_map(b, j, *_):
         return (*_paged_grid_row(b, nhb, rb), 0, 0)
 
-    def _page_map(b, j, tab, lens, src):
+    def _page_map(b, j, tab, lens, src, *first):
         # a repeated block index is not refetched. Past a slot's live
         # prefix: its last live page. A slot with nothing to read: the
         # block of the step before its first (the LAST block of the
@@ -1254,17 +1321,21 @@ def flash_attention_decode_paged(
         slot, hblk, _ = _paged_grid_row(b, nhb, rb)
         held = src[slot]
         dead = lens[slot] == 0
-        first = jnp.logical_and(dead, held >= slot)
+        before = jnp.logical_and(dead, held >= slot)
         last_page = jax.lax.max(
             jax.lax.div(lens[held] + (ps - 1), jnp.int32(ps)), 1
         ) - 1
+        page0 = jnp.int32(0)
+        if first:  # a window: the walk starts at the bound's page
+            page0 = jax.lax.div(first[0][held], jnp.int32(ps))
+            j = jax.lax.add(page0, j)
         jeff = jax.lax.select(
-            first, jnp.int32(0),
+            before, page0,
             jax.lax.select(dead, last_page, jax.lax.min(j, last_page)),
         )
         if nhb > 1:
             hblk = jax.lax.select(
-                first, jnp.int32(0),
+                before, jnp.int32(0),
                 jax.lax.select(dead, jnp.int32(nhb - 1), hblk),
             )
         return (jax.lax.min(tab[held, jeff], num_pages - 1), hblk, 0, 0)
@@ -1275,6 +1346,16 @@ def flash_attention_decode_paged(
         pl.BlockSpec((1, hb, ps, d), _page_map),
     ]
     ins = [qp, kp, vp]
+    prefetch = [table, lens, src]
+    if window is not None:
+        prefetch.append(first)
+        if bound == "rows":
+            # each row's own bound; the rows that pad the block read all
+            lo = jnp.maximum(
+                jnp.asarray(q_positions, jnp.int32) + 1 - window, 0)
+            in_specs.append(
+                pl.BlockSpec((block_t, 1), lambda b, j, *_: (0, 0)))
+            ins.append(jnp.pad(lo, (0, block_t - t)).reshape(block_t, 1))
     if quantized:
         smem = pl.BlockSpec(memory_space=pltpu.SMEM)
         in_specs += [smem, smem]
@@ -1285,8 +1366,8 @@ def flash_attention_decode_paged(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # the page table stays FIRST and two-dimensional: the trace's
         # readers tell this kernel by it
-        num_scalar_prefetch=3,
-        grid=(num_slots * nhb * rb, pages_per_slot),
+        num_scalar_prefetch=len(prefetch),
+        grid=(num_slots * nhb * rb, walk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, hb, 1, block_t, d), _row_map),
@@ -1301,7 +1382,7 @@ def flash_attention_decode_paged(
     o, lse = pallas_call(
         functools.partial(
             _decode_paged_kernel, s, hb, nhb, ps, num_pages, block_t,
-            quantized, rb,
+            quantized, rb, bound,
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -1311,7 +1392,7 @@ def flash_attention_decode_paged(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=PAGED_VMEM_LIMIT
         ),
-    )(table, lens, src, *ins)
+    )(*prefetch, *ins)
     o = o.reshape(bh, block_t, d)[:, :t, :d0]
     if return_lse:
         return o, lse.reshape(bh, block_t)[:, :t]

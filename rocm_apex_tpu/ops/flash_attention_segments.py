@@ -68,7 +68,7 @@ def _overlap(causal, block_q, block_k, qi, ki,
 
 
 def _seg_fwd_kernel(
-    causal, scale, block_q, block_k,
+    causal, scale, block_q, block_k, window,
     q_ref, k_ref, v_ref, sq_ref, sk_ref,
     qmin_ref, qmax_ref, kmin_ref, kmax_ref,
     o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -91,6 +91,7 @@ def _seg_fwd_kernel(
         s = _masked_scores(
             causal, scale, k.shape[0] * pl.num_programs(2), block_q,
             block_k, q, k, None, None, b, qi, ki, seg=(sq_ref, sk_ref),
+            window=window,
         )
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -244,8 +245,10 @@ def _pad3(x, tp, d):
     return jnp.pad(x, ((0, 0), (0, tp - total), (0, d - d0)))
 
 
-def _seg_fwd(q, k, v, seg, causal, scale, block_q, block_k):
+def _seg_fwd(q, k, v, seg, causal, scale, block_q, block_k, window=None):
     h, total, d0 = q.shape
+    if window is not None and not causal:
+        raise ValueError("a window is a causal one")
     # grouped K/V heads (forward only): query head b reads K/V head
     # b // g; with g = 1 the index maps are what they were
     hk = k.shape[0]
@@ -261,7 +264,8 @@ def _seg_fwd(q, k, v, seg, causal, scale, block_q, block_k):
     qmin, qmax, kmin, kmax = ranges
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     o, lse = pallas_call(
-        functools.partial(_seg_fwd_kernel, causal, scale, block_q, block_k),
+        functools.partial(
+            _seg_fwd_kernel, causal, scale, block_q, block_k, window),
         grid=(h, tp // block_q, tp // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -394,6 +398,7 @@ def flash_attention_segments_with_lse(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK,
     block_k: int = DEFAULT_BLOCK,
+    window: Optional[int] = None,
 ):
     """Forward-only packed attention returning ``(o, lse)``.
 
@@ -403,11 +408,13 @@ def flash_attention_segments_with_lse(
     the per-slot cache-prefix piece
     (`flash_attention_decode(..., return_lse=True)`) by log-sum-exp
     weights. No vjp: inference never differentiates this variant.
+    ``window`` (causal only): a token also attends no further back than
+    the ``window`` tokens that end at its own.
     """
     return _seg_fwd(
         q, k, v, segment_ids, causal,
         scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]),
-        block_q, block_k,
+        block_q, block_k, window,
     )
 
 
@@ -423,6 +430,8 @@ def flash_attention_chunk_paged(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
+    window: Optional[int] = None,
+    positions: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Chunked-prefill attention against a PAGED cache prefix.
 
@@ -444,7 +453,15 @@ def flash_attention_chunk_paged(
     pre-chunk materialized length). Returns fp32
     (budget, heads, head_dim) — token-major, output-projection-ready.
     Forward only (serving never differentiates).
+
+    ``window`` with ``positions`` (budget,), each token's position in
+    its sequence: a layer with a sliding window. A token attends the
+    ``window`` keys that end at its own, in the chunk and in its slot's
+    prefix alike; the prefix pages wholly before a slot's first row's
+    bound are not fetched and need not be mapped.
     """
+    if (window is None) != (positions is None):
+        raise ValueError("pass window and positions together or neither")
     from rocm_apex_tpu.ops.flash_attention import (
         flash_attention_decode_paged,
     )
@@ -453,7 +470,8 @@ def flash_attention_chunk_paged(
     num_slots = page_table.shape[0]
     s = scale if scale is not None else 1.0 / np.sqrt(d0)
     o_a, lse_a = flash_attention_segments_with_lse(
-        q, k_chunk, v_chunk, segment_ids, causal=True, scale=s
+        q, k_chunk, v_chunk, segment_ids, causal=True, scale=s,
+        window=window,
     )
     # every slot scores the WHOLE chunk against its prefix (chunk-width
     # cache read, not per-token width); each token keeps its own slot's
@@ -464,6 +482,7 @@ def flash_attention_chunk_paged(
     o_b, lse_b = flash_attention_decode_paged(
         qB, k_pool, v_pool, page_table, kv_lengths, s,
         k_scale=k_scale, v_scale=v_scale, return_lse=True,
+        window=window, q_positions=positions,
     )
     o_b = o_b.reshape(num_slots, nh, budget, d0)
     lse_b = lse_b.reshape(num_slots, nh, budget)

@@ -65,15 +65,25 @@ def latent_width(rank: int, rope: int) -> int:
     return -(-(rank + rope) // 128) * 128
 
 
-def rotary(x, positions, theta):
-    """``x`` (T, ..., d) rotated at ``positions`` (T,): interleaved
-    pairs, base ``theta``; float32 in and out of the rotation, the
-    result in ``x``'s dtype."""
+def rotary(x, positions, theta, pairing="interleaved"):
+    """``x`` (T, ..., d) rotated at ``positions`` (T,), base ``theta``:
+    pair i turns by ``position * theta ** (-2i / d)``. ``pairing`` says
+    which two values pair i is: ``"interleaved"`` (x[2i], x[2i + 1]) or
+    ``"halves"`` (x[i], x[i + d/2]). Float32 in and out of the rotation,
+    the result in ``x``'s dtype."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
     shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    if pairing == "halves":
+        xf = x.astype(jnp.float32)
+        a, b = xf[..., :d // 2], xf[..., d // 2:]
+        out = jnp.concatenate(
+            [a * cos - b * sin, a * sin + b * cos], axis=-1)
+        return out.astype(x.dtype)
+    if pairing != "interleaved":
+        raise ValueError(f"unknown rotary pairing {pairing!r}")
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)

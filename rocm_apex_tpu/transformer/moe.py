@@ -218,6 +218,12 @@ class HeldExperts(nn.Module):
     received any, the most one received, and with ``log_chosen`` (a
     debugging option) the mask of chosen experts per token
     (``ceil(num_experts / 32)`` words of 32 bits).
+
+    ``gate`` names the experts' gating function: ``"silu"`` or
+    ``"relu"`` (ReGLU: ``relu(u W_gate) * (u W_up)``). ``router_input``
+    (T, hidden), where given, is what the ROUTER scores in ``u``'s place
+    (a model whose router reads the layer's input from before its
+    attention); the experts read ``u`` either way.
     """
 
     hidden_size: int
@@ -233,9 +239,10 @@ class HeldExperts(nn.Module):
     routing: str = "top_k_softmax"
     zero_experts: int = 0
     scaling: float = 1.0
+    gate: str = "silu"
 
     @nn.compact
-    def __call__(self, u, live):
+    def __call__(self, u, live, router_input=None):
         from rocm_apex_tpu.ops.grouped_matmul import (
             group_layout, grouped_matmul, row_tile,
         )
@@ -245,6 +252,10 @@ class HeldExperts(nn.Module):
         g, f, k = hi - lo, self.expert_width, self.top_k
         init = nn.initializers.normal(self.init_std)
         outputs = self.num_experts + self.zero_experts
+        if self.gate not in ("silu", "relu"):
+            raise ValueError(f"unknown expert gate {self.gate!r}")
+        gate_fn = jax.nn.silu if self.gate == "silu" else jax.nn.relu
+        scored = u if router_input is None else router_input
         router = self.param(
             "router", init, (h, outputs), self.params_dtype)
         w_in = self.param("w_in", init, (g, h, 2 * f), self.params_dtype)
@@ -252,7 +263,7 @@ class HeldExperts(nn.Module):
 
         with jax.named_scope("moe_router"):
             logits = jnp.dot(
-                u.astype(jnp.float32), router.astype(jnp.float32),
+                scored.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
             if self.routing == "top_k_softmax":
@@ -281,12 +292,17 @@ class HeldExperts(nn.Module):
             ab = grouped_matmul(
                 xs, w_in, tile_group, num_live, block_m=block_m)
             act = (
-                jax.nn.silu(ab[:, :f].astype(jnp.float32))
+                gate_fn(ab[:, :f].astype(jnp.float32))
                 * ab[:, f:].astype(jnp.float32)
             ).astype(self.dtype)
+            # the widest block of at most 1024 columns that divides the
+            # hidden size (2560 takes 640; 4096 and 6144 take 1024)
+            block_n = max(
+                (b for b in range(128, 1025, 128) if h % b == 0),
+                default=1024)
             ys = grouped_matmul(
                 act, w_out, tile_group, num_live, block_m=block_m,
-                block_n=1024)
+                block_n=block_n)
             per = jnp.take(
                 ys, dest, axis=0, mode="fill", fill_value=0
             ).reshape(t, k, h)
@@ -301,7 +317,7 @@ class HeldExperts(nn.Module):
             with jax.named_scope("moe_shared"):
                 ab = jnp.dot(u, s_in.astype(self.dtype))
                 act = (
-                    jax.nn.silu(ab[:, :fs].astype(jnp.float32))
+                    gate_fn(ab[:, :fs].astype(jnp.float32))
                     * ab[:, fs:].astype(jnp.float32)
                 ).astype(self.dtype)
                 out = out + jnp.dot(

@@ -512,6 +512,36 @@ class TestChunkedPrefill:
         )
         assert [r.tokens for r in base] == [r.tokens for r in capped]
 
+    def test_budget_goes_to_the_oldest_lease_not_the_lowest_slot(self):
+        """Two prompts that together exceed the budget: the one leased
+        FIRST is fed first, whichever slot it sits in. A long prompt in
+        slot 1 used to wait behind every newcomer in slot 0; by lease
+        it keeps the budget until it is done, and the tokens are the
+        same either way."""
+        cfg = fp32_cfg()
+        model, params = make_model(cfg)
+        eng = greedy_engine(model, params)
+        eng.add_request([1, 2, 3], max_new_tokens=2)  # slot 0, ends fast
+        eng.add_request(list(range(5, 19)), max_new_tokens=3)  # slot 1
+        eng.step()  # [1,2,3] whole + 1 row of the long prompt; 2 tokens
+        assert eng._slots[0] is None and eng._slots[1].cursor == 1
+        eng.add_request(list(range(20, 30)), max_new_tokens=3)
+        eng.step()  # the newcomer takes slot 0 and waits: 4 rows to slot 1
+        assert eng._slots[1].cursor == 5 and eng._slots[0].cursor == 0
+        eng.step(), eng.step()
+        assert eng._slots[1].cursor == 13 and eng._slots[0].cursor == 0
+        eng.step()  # the old prompt's last row, then the newcomer's first 3
+        assert not eng._slots[1].prefilling and eng._slots[0].cursor == 3
+        done = {}
+        while eng.has_work():
+            for r in eng.step():
+                done[tuple(r.prompt)] = r.tokens
+        alone = greedy_engine(model, params).generate(
+            [list(range(5, 19)), list(range(20, 30))], max_new_tokens=3
+        )
+        for r in alone:
+            assert done[tuple(r.prompt)] == r.tokens
+
     def test_decode_liveness_while_long_prefill_streams(self):
         """Head-of-line blocking is gone: while an 16-token prompt
         streams through the 4-token budget (4 ticks), the already-
