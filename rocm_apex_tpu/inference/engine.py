@@ -283,7 +283,7 @@ class InferenceEngine:
     profiler annotation (`monitor.trace.phase`) tiled by its phases
     ``engine.admit`` → ``engine.pack`` → ``engine.table_push`` →
     ``engine.dispatch`` → ``engine.fetch`` → ``engine.commit``, the
-    tick's counts (program, decodes, chunk
+    tick's counts (program, the model's applies in it, decodes, chunk
     tokens, slots, pages, queue depth, admitted, finished) riding as
     the tick's metadata; `add_request` is ``apex/engine.enqueue``. A
     `jax.profiler` capture holds them on the device planes' clock;
@@ -2931,6 +2931,7 @@ class InferenceEngine:
         with self.tracer.phase("engine.pack", track="engine"):
             budget = self.prefill_token_budget
             S = self.num_slots
+            one_pass = self.programs.one_pass
             chunk_tokens = np.zeros((budget,), np.int32)
             # slot id == num_slots marks padding: the scatter drops it and
             # the segment mask keeps pads talking only to each other
@@ -3077,8 +3078,14 @@ class InferenceEngine:
                         # decodes on a later tick). A RESUMED (preempted)
                         # request completing its recomputed prefix emits
                         # nothing here — its tokens already exist; it
-                        # rejoins the decode grid below this same tick.
-                        fed = st.cursor < self.capacity
+                        # rejoins the decode grid below this same tick
+                        # (the next, under the one-pass body).
+                        # The ONE-PASS body (`StepPrograms.one_pass`)
+                        # feeds nothing: every completion emits its first
+                        # token here and decodes from the next tick, so
+                        # no page is reserved for a fused write and no
+                        # stall is counted for one.
+                        fed = not one_pass and st.cursor < self.capacity
                         if fed and self.paged:
                             fed = self._ensure_writable(
                                 st, slot, st.cursor // self.cache.page_size
@@ -3143,6 +3150,12 @@ class InferenceEngine:
             )
             for slot, _, _, _, _ in spec_entries:
                 active[slot] = False
+            if one_pass:
+                # no slot has rows in both parts of the one apply: a
+                # preempted request whose recomputed prefix ends in this
+                # chunk rejoins the grid in the next tick
+                for slot, _, _ in packed:
+                    active[slot] = False
             self._guard_capacity(active)
             if self.paged:
                 for slot, st in enumerate(self._slots):
@@ -3162,9 +3175,11 @@ class InferenceEngine:
                 np.int32,
             )
 
+            # the two-apply body reads it as "feed this row's token to
+            # the grid"; the one-pass body as "the head's row of the slot"
             completion_idx = np.full((S,), -1, np.int32)
             for slot, idx, fed in completions:
-                completion_idx[slot] = idx if fed else -1
+                completion_idx[slot] = idx if fed or one_pass else -1
             if dec_adp is not None:
                 # only rows the fused decode actually emits carry their
                 # adapter slot; dead rows stay 0 so a pure-base tick's
@@ -3256,6 +3271,10 @@ class InferenceEngine:
             # token of a prompt completed in this one), leased slots
             counts = {
                 "program": program,
+                # applies of the model in the tick's program
+                "model_passes": (
+                    0 if program == "none"
+                    else 1 if program == "decode" or one_pass else 2),
                 "chunk_tokens": used,
                 "prefill_tokens": prefill_used,
                 "decodes": int(active.sum()) + sum(
@@ -3276,6 +3295,8 @@ class InferenceEngine:
             now2 = time.perf_counter()
             for slot, idx, fed in completions:
                 st = self._slots[slot]
+                if one_pass:
+                    idx = slot  # the chunk's fetched values are per slot
                 if chunk_bad is not None and chunk_bad[idx]:
                     # fault isolation: only THIS slot quarantines; every
                     # other slot's tokens came out of the same fetch,
@@ -3295,9 +3316,10 @@ class InferenceEngine:
                     finished.append(self._evict(slot, st, done))
                     continue
                 if not fed:
-                    # no fused decode ran for this slot (at-capacity edge
-                    # already evicted above, or a paged page stall): the
-                    # second token arrives on a later tick
+                    # no fused decode ran for this slot (the one-pass
+                    # body, the at-capacity edge already evicted above,
+                    # or a paged page stall): the second token arrives
+                    # on a later tick
                     continue
                 if dec_bad is not None and dec_bad[slot]:
                     finished.append(self._quarantine(
@@ -3509,6 +3531,7 @@ class InferenceEngine:
         with self.tracer.phase("engine.commit", track="engine"):
             counts = {
                 "program": "whole",
+                "model_passes": len(pending) + int(toks is not None),
                 "chunk_tokens": 0,
                 "prefill_tokens": prefilled,
                 "decodes": int(active.sum()),

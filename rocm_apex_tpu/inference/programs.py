@@ -2,7 +2,14 @@
 
 An engine tick runs ONE of two programs: the fused **mixed** step (a
 packed prefill chunk plus the whole decode grid) or the **decode**
-step alone. `StepPrograms` holds the single definition of each, built
+step alone. The mixed step has two bodies, and the MODEL's class says
+which (`StepPrograms.one_pass`): a model that declares
+``mixed_in_one_pass`` (`models/hybrid.py::ServedDecoder`) is applied
+ONCE to the chunk's rows and the grid's together, and a prompt the chunk
+completes emits its first token in this tick and decodes from the next;
+every other model (`GPTModel`) is applied to the chunk and then to the
+grid, which takes the completed prompts' first tokens in the same tick.
+`StepPrograms` holds the single definition of each, built
 from what the traced graphs bake in — the model, the `SamplingParams`,
 the cache's type and geometry, the tp mesh, the donation flag — and a
 feature set the ENGINE derives from its configuration, never a user's
@@ -82,6 +89,10 @@ class StepPrograms:
         cfg = model.cfg
         spec = self.spec = spec_k > 0
         lora = self.lora = adapter_buffers is not None
+        #: the mixed step applies the model once to all of a tick's rows
+        #: (the model's class declares it; `_mixed_one_pass` says what
+        #: the engine then does differently)
+        self.one_pass = bool(getattr(model, "mixed_in_one_pass", False))
         paged = isinstance(cache, PagedKVCache)
         donate_buffers = bool(donate_buffers)
         # What `compatible_with` compares, by the name it reports:
@@ -332,16 +343,74 @@ class StepPrograms:
                 + (adapters,) * lora + tuple(chunk_kv)
             )
 
-        def _positional(step, names):
+        def _mixed_one_pass(
+            params, cache, chunk_tokens, chunk_slots, chunk_pos,
+            lengths_before, lengths_after, completion_idx,
+            dec_tokens, dec_active, chunk_poison, dec_poison, key,
+            **features,
+        ):
+            """`_mixed`'s operands and outputs, ONE apply of the model
+            over the chunk's rows and the grid's together. The grid
+            takes no token from the chunk, so a prompt the chunk
+            completes emits its first token here and decodes from the
+            next tick, and no slot has rows in both parts. The head and
+            the ONE sampler call see ``2 x slots`` rows: per slot the
+            chunk row ``completion_idx`` names (some row where it names
+            none: the host reads a slot's first token only where it
+            packed a completion) and the grid's. The chunk's two fetched
+            values are therefore per SLOT (first token, its nonfinite
+            flag), and ``chunk_poison`` is read at the rows gathered.
+            A feature's operands are refused: one apply verifies no
+            drafts and rides no adapter ids."""
+            if features:
+                raise ValueError(
+                    f"{type(model).__name__}'s mixed tick is one apply "
+                    f"of the model: it takes no {sorted(features)}")
+            traces["mixed"] += 1
+            key, rng = jax.random.split(key)
+            slots, budget = dec_tokens.shape[0], chunk_tokens.shape[0]
+            logits, cache = model.apply(
+                params, chunk_tokens[None, :],
+                cache=_start_tick(cache).replace(lengths=lengths_before),
+                chunk=(chunk_slots, chunk_pos),
+                grid=(
+                    dec_tokens,
+                    # dead rows write at the capacity sentinel, as in
+                    # `_decode_body`
+                    jnp.where(dec_active, lengths_after, dev_capacity),
+                    completion_idx),
+            )
+            # the chunk commits (cursors advance by what was packed) and
+            # the grid's live rows by their one token
+            cache = cache.replace(lengths=jnp.where(
+                dec_active, jnp.minimum(lengths_after + 1, dev_capacity),
+                lengths_after))
+            has_comp = completion_idx >= 0
+            poison = jnp.concatenate([
+                chunk_poison[jnp.clip(completion_idx, 0, budget - 1)],
+                dec_poison])
+            last = logits + poison[:, None]
+            bad = jnp.any(~jnp.isfinite(last), axis=-1)
+            tok = _sample(rng, last)
+            return (
+                jnp.where(has_comp, tok[:slots], 0),
+                jnp.where(dec_active, tok[slots:], 0),
+                bad[:slots], bad[slots:], key, cache,
+            )
+
+        def _positional(name, step, names):
             def program(*operands):
                 return step(**dict(zip(names, operands, strict=True)))
-            # the compiled program keeps the step's name (`jit__mixed`,
-            # `jit__decode`): trace readers group executions by it
-            program.__name__ = program.__qualname__ = step.__name__
+            # the compiled program is named after the step, whichever
+            # body it has (`jit__mixed`, `jit__decode`): trace readers
+            # group executions by it
+            program.__name__ = program.__qualname__ = name
             return program
 
-        _decode = _positional(_decode, self.decode_operands)
-        _mixed = _positional(_mixed, self.mixed_operands)
+        _decode = _positional("_decode", _decode, self.decode_operands)
+        _mixed = _positional(
+            "_mixed", _mixed_one_pass if self.one_pass else _mixed,
+            self.mixed_operands)
 
         n_layers = len(cache.k)
 

@@ -12,7 +12,8 @@ logits.
 The model serves through `InferenceEngine` under the contract
 `GPTModel` has: ``apply(params, tokens, cache=, chunk=)`` returns
 ``(logits, cache)``; a ``(1, budget)`` packed chunk with
-``chunk=(slot_ids, positions)`` or a ``(slots, 1)`` decode grid. What it
+``chunk=(slot_ids, positions)``, a ``(slots, 1)`` decode grid, or both
+at once (``grid=``: `ServedDecoder`). What it
 keeps per request it declares (`cache_spec`), and the engine builds the
 cache from that (`inference/paging.py` `PagedKVCache.from_spec`): paged
 K/V for the attention layers only, a fixed-size recurrent state and convolution tail
@@ -127,16 +128,29 @@ def _param(mod, name, shape):
         mod.cfg.params_dtype)
 
 
+def part_rows(x, part):
+    """The rows of ``x`` (one a row of the tick) that are ``part``'s:
+    all of them where the tick has one part."""
+    lo, hi = part["span"]
+    return x if (lo, hi) == (0, x.shape[0]) else x[lo:hi]
+
+
+def join_rows(xs):
+    """The parts' rows, in the tick's order."""
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
+
+
 class MambaMixer(nn.Module):
     """``u`` (T, hidden) -> (T, hidden), with the slot states of
     ``state`` = (ssm (slots, n, heads * p), conv (slots, d_conv - 1,
-    conv dim)) read and advanced: by the packed chunk (``chunk_geo`` and
-    ``fresh``) or by the decode grid (``live``)."""
+    conv dim)) read and advanced part by part of ``rows``: by a packed
+    chunk (its ``geo`` and ``fresh``) or by a decode grid (``live``).
+    The two projections run once over all rows."""
 
     cfg: HybridConfig
 
     @nn.compact
-    def __call__(self, u, state, chunk_geo=None, fresh=None, live=None):
+    def __call__(self, u, state, rows):
         cfg = self.cfg
         heads, p, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
         di, cd, kw = cfg.mamba_d_inner, cfg.mamba_conv_dim, cfg.mamba_d_conv
@@ -152,27 +166,32 @@ class MambaMixer(nn.Module):
 
         zxd = jnp.dot(u, in_proj.astype(cfg.dtype))
         z, xbc, dt = zxd[:, :di], zxd[:, di:di + cd], zxd[:, di + cd:]
-        with jax.named_scope("ssm_conv"):
-            if chunk_geo is not None:
-                xbc, conv_tail = ssm.conv_chunk(
-                    xbc, conv_w, conv_b, conv_tail, fresh, chunk_geo)
-            else:
-                xbc, conv_tail = ssm.conv_decode(
-                    xbc, conv_w, conv_b, conv_tail, live)
-            xbc = jax.nn.silu(xbc).astype(cfg.dtype)
-        x = xbc[:, :di].reshape(-1, heads, p)
-        b, c = xbc[:, di:di + n], xbc[:, di + n:]
         dt = jax.nn.softplus(
             dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
         a = -jnp.exp(a_log.astype(jnp.float32))
-        with jax.named_scope("ssm_scan"):
-            if chunk_geo is not None:
-                y, ssm_state = ssm.ssd_chunk(
-                    x, dt, a, b, c, d, ssm_state, fresh, chunk_geo)
-            else:
-                y, ssm_state = ssm.ssd_decode(
-                    x, dt, a, b, c, d, ssm_state, live)
-        y = y.reshape(-1, di) * jax.nn.silu(z.astype(jnp.float32))
+        ys = []
+        for part in rows["parts"]:
+            geo, fresh, live = part["geo"], part["fresh"], part["live"]
+            xbc_p, dt_p = part_rows(xbc, part), part_rows(dt, part)
+            with jax.named_scope("ssm_conv"):
+                if geo is not None:
+                    xbc_p, conv_tail = ssm.conv_chunk(
+                        xbc_p, conv_w, conv_b, conv_tail, fresh, geo)
+                else:
+                    xbc_p, conv_tail = ssm.conv_decode(
+                        xbc_p, conv_w, conv_b, conv_tail, live)
+                xbc_p = jax.nn.silu(xbc_p).astype(cfg.dtype)
+            x = xbc_p[:, :di].reshape(-1, heads, p)
+            b, c = xbc_p[:, di:di + n], xbc_p[:, di + n:]
+            with jax.named_scope("ssm_scan"):
+                if geo is not None:
+                    y, ssm_state = ssm.ssd_chunk(
+                        x, dt_p, a, b, c, d, ssm_state, fresh, geo)
+                else:
+                    y, ssm_state = ssm.ssd_decode(
+                        x, dt_p, a, b, c, d, ssm_state, live)
+            ys.append(y.reshape(-1, di))
+        y = join_rows(ys) * jax.nn.silu(z.astype(jnp.float32))
         y = rms_norm(y, norm_w, cfg.rms_norm_eps).astype(cfg.dtype)
         return jnp.dot(y, out_proj.astype(cfg.dtype)), (ssm_state, conv_tail)
 
@@ -180,13 +199,16 @@ class MambaMixer(nn.Module):
 class GroupedAttention(nn.Module):
     """Causal attention with fewer K/V heads than query heads over the
     paged pool, no positional encoding. ``kv`` = (k pool, v pool) of
-    this layer; ``paged`` carries the table, the page size and the
-    slots' lengths."""
+    this layer. The two projections run once over all rows; each part of
+    ``rows`` then writes its rows' K/V and reads the pool by its own
+    kernel, as an apply of that part alone does: a packed chunk under
+    the lengths its slots had before it, a decode grid under its
+    cursors."""
 
     cfg: HybridConfig
 
     @nn.compact
-    def __call__(self, u, kv, paged, chunk=None):
+    def __call__(self, u, kv, rows):
         cfg = self.cfg
         nq, nkv, hd = (
             cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim)
@@ -198,77 +220,108 @@ class GroupedAttention(nn.Module):
         k = qkv[:, nq * hd:(nq + nkv) * hd].reshape(t, nkv, hd)
         v = qkv[:, (nq + nkv) * hd:].reshape(t, nkv, hd)
         k_buf, v_buf = kv
-        table, lengths = paged["page_table"], paged["lengths"]
-        slots_n = table.shape[0]
-        capacity = table.shape[1] * paged["page_size"]
         scale = cfg.attention_multiplier
         group = nq // nkv
-        if chunk is not None:
-            w_slots, w_pos = chunk
-        else:
-            w_slots, w_pos = jnp.arange(t, dtype=jnp.int32), lengths
-        k_buf = paged_scatter(k_buf, table, w_slots, w_pos, k)
-        v_buf = paged_scatter(v_buf, table, w_slots, w_pos, v)
-        if cfg.attention_impl == "jnp":
-            # the plain read: each row attends its slot's gathered rows
-            # [0, position + 1), which the scatter above has completed
-            kc = paged_view(k_buf, table).astype(jnp.float32)
-            vc = paged_view(v_buf, table).astype(jnp.float32)
-            row_slot = jnp.clip(w_slots, 0, slots_n - 1)
-            kr = jnp.repeat(kc[row_slot], group, axis=2)  # (t, cap, nq, hd)
-            vr = jnp.repeat(vc[row_slot], group, axis=2)
-            scores = jnp.einsum(
-                "tnd,tcnd->tnc", q.astype(jnp.float32), kr) * scale
-            bound = jnp.minimum(w_pos + 1, capacity)[:, None, None]
-            col = jnp.arange(capacity)[None, None, :]
-            scores = jnp.where(col < bound, scores, -1e30)
-            ctx = jnp.einsum(
-                "tnc,tcnd->tnd", jax.nn.softmax(scores, axis=-1), vr)
-        elif chunk is not None:
-            from rocm_apex_tpu.ops.flash_attention_segments import (
-                flash_attention_chunk_paged,
-            )
+        ctx = []
+        for part in rows["parts"]:
+            q_p, k_p, v_p = (part_rows(x, part) for x in (q, k, v))
+            chunk, paged = part["chunk"], part["paged"]
+            table, lengths = paged["page_table"], paged["lengths"]
+            slots_n, tp = table.shape[0], q_p.shape[0]
+            capacity = table.shape[1] * paged["page_size"]
+            if chunk is not None:
+                w_slots, w_pos = chunk
+            else:
+                w_slots, w_pos = jnp.arange(tp, dtype=jnp.int32), lengths
+            k_buf = paged_scatter(k_buf, table, w_slots, w_pos, k_p)
+            v_buf = paged_scatter(v_buf, table, w_slots, w_pos, v_p)
+            if cfg.attention_impl == "jnp":
+                # the plain read: each row attends its slot's gathered
+                # rows [0, position + 1), which the scatter above has
+                # completed
+                kc = paged_view(k_buf, table).astype(jnp.float32)
+                vc = paged_view(v_buf, table).astype(jnp.float32)
+                row_slot = jnp.clip(w_slots, 0, slots_n - 1)
+                kr = jnp.repeat(kc[row_slot], group, axis=2)  # (t, cap, nq, hd)
+                vr = jnp.repeat(vc[row_slot], group, axis=2)
+                scores = jnp.einsum(
+                    "tnd,tcnd->tnc", q_p.astype(jnp.float32), kr) * scale
+                bound = jnp.minimum(w_pos + 1, capacity)[:, None, None]
+                col = jnp.arange(capacity)[None, None, :]
+                scores = jnp.where(col < bound, scores, -1e30)
+                out = jnp.einsum(
+                    "tnc,tcnd->tnd", jax.nn.softmax(scores, axis=-1), vr)
+            elif chunk is not None:
+                from rocm_apex_tpu.ops.flash_attention_segments import (
+                    flash_attention_chunk_paged,
+                )
 
-            ctx = flash_attention_chunk_paged(
-                q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                v.transpose(1, 0, 2), chunk[0], k_buf, v_buf, table,
-                lengths, scale,
-            )
-        else:
-            from rocm_apex_tpu.ops.flash_attention import (
-                flash_attention_decode_paged,
-            )
+                out = flash_attention_chunk_paged(
+                    q_p.transpose(1, 0, 2), k_p.transpose(1, 0, 2),
+                    v_p.transpose(1, 0, 2), chunk[0], k_buf, v_buf, table,
+                    lengths, scale,
+                )
+            else:
+                from rocm_apex_tpu.ops.flash_attention import (
+                    flash_attention_decode_paged,
+                )
 
-            ctx = flash_attention_decode_paged(
-                q.reshape(t * nq, 1, hd), k_buf, v_buf, table,
-                jnp.minimum(lengths + 1, capacity), scale,
-            )
-        ctx = ctx.astype(cfg.dtype).reshape(t, nq * hd)
-        return jnp.dot(ctx, o_w.astype(cfg.dtype)), (k_buf, v_buf)
+                out = flash_attention_decode_paged(
+                    q_p.reshape(tp * nq, 1, hd), k_buf, v_buf, table,
+                    jnp.minimum(lengths + 1, capacity), scale,
+                )
+            ctx.append(out.astype(cfg.dtype).reshape(tp, nq * hd))
+        return jnp.dot(join_rows(ctx), o_w.astype(cfg.dtype)), (k_buf, v_buf)
 
 
 class ServedDecoder(nn.Module):
     """The frame a served model of declared layers runs in: it checks
-    what the engine hands over (a ``(1, budget)`` packed chunk with
-    ``chunk=(slot_ids, positions)`` or a ``(slots, 1)`` decode grid),
-    embeds, runs the layers over what each keeps in the cache, norms,
-    projects onto the vocabulary, adds up the tick's counters, writes
-    the routing log and advances the decode grid's lengths. A model
-    declares its layers (`layer`, `layer_states`, `with_states`) and,
-    where it has them, rows of its own (`own_rows`), counters of its own
-    (`tick_counts`) and multipliers (`embed`, `project`).
+    what the engine hands over, embeds, runs the layers over what each
+    keeps in the cache, norms, projects onto the vocabulary, adds up the
+    tick's counters, writes the routing log and advances the decode
+    grid's lengths. A model declares its layers (`layer`,
+    `layer_states`, `with_states`) and, where it has them, rows of its
+    own (`own_rows`), counters of its own (`tick_counts`) and
+    multipliers (`embed`, `project`).
+
+    A tick's rows come in one of three forms:
+
+    * a ``(1, budget)`` packed chunk with ``chunk=(slot_ids,
+      positions)``; ``cache.lengths`` are the slots' lengths before it;
+    * a ``(slots, 1)`` decode grid; ``cache.lengths`` are the cursors,
+      the capacity sentinel on DEAD rows;
+    * BOTH AT ONCE (`mixed_in_one_pass`): the chunk as above and
+      ``grid=(tokens, cursors, emit)``, each ``(slots,)``: the grid's
+      tokens, its cursors (the sentinel on dead rows) and per slot the
+      chunk row whose logits are wanted (the last row of a prompt the
+      chunk completes; -1: none). The rows are the chunk's ``budget``
+      followed by the grid's ``slots``; a slot has rows in one of the
+      two, never in both. Every product with weights runs once over all
+      of them; the head runs over ``2 x slots`` rows only, per slot the
+      chunk row ``emit`` names (any row where it names none), then the
+      grid's. Returns those logits ``(2 x slots, vocab)`` and the cache
+      with the lengths it came with.
 
     A layer is ``(h, state, rows) -> (h, state, counts)``. ``rows``
-    says where the tick's rows live: ``paged`` (page table, page size,
-    lengths), ``chunk`` (as given, None in the decode grid), each row's
-    ``slots`` and ``positions``, and ``live`` (the decode grid's rows
-    that are not DEAD; None in a chunk). ``counts`` are `HeldExperts`'.
+    holds what is one value a row over ALL the tick's rows (each row's
+    ``slots`` and ``positions``, and ``live``: the rows that are tokens,
+    neither a chunk's padding nor a grid's dead rows) and ``parts``:
+    per form in the tick what a mixer's core needs to read the cache its
+    own way. A part says where its rows are (``span``) and carries
+    ``paged`` (page table, page size, its lengths), ``chunk`` (as given;
+    None for a grid), its own ``slots``, ``positions`` and ``live``, and
+    what the model adds (`own_rows`). ``counts`` are `HeldExperts`'.
     """
 
     cfg: Any
     untied_head = False  # True: an ``lm_head`` of its own
     # why a chunk's third element (rows whose commit waits) is refused
     no_deferred_commit = "this model's layers defer no row's commit"
+    # A mixed tick is ONE apply (``grid=`` beside ``chunk=``), and the
+    # engine's step programs are built for that: every weight is read
+    # once a tick, and a prompt the chunk completes emits its first
+    # token in this tick and decodes from the next.
+    mixed_in_one_pass = True
 
     # -- what a model declares ------------------------------------------
 
@@ -282,10 +335,12 @@ class ServedDecoder(nn.Module):
     def with_states(self, cache, states):
         raise NotImplementedError
 
-    def own_rows(self, rows, cache):
-        return rows
+    def own_rows(self, part, cache):
+        """``part`` with what the model's layers need of it beside;
+        ``cache.lengths`` are the part's."""
+        return part
 
-    def tick_counts(self, rows, cache):
+    def tick_counts(self, part, cache):
         return {}
 
     def embed(self, x):
@@ -297,7 +352,8 @@ class ServedDecoder(nn.Module):
     # -- the frame ------------------------------------------------------
 
     @nn.compact
-    def __call__(self, tokens, cache=None, chunk=None, adapters=None):
+    def __call__(self, tokens, cache=None, chunk=None, adapters=None,
+                 grid=None):
         cfg, who = self.cfg, type(self).__name__
         if cache is None:
             raise ValueError(
@@ -305,10 +361,22 @@ class ServedDecoder(nn.Module):
                 f"grid); it has no cache-less forward")
         if adapters is not None:
             raise ValueError(f"{who} takes no adapters")
+        if grid is not None and chunk is None:
+            raise ValueError("grid= rides beside a chunk; alone it is tokens")
         table = self.param(
             "embedding", nn.initializers.normal(cfg.init_std),
             (cfg.vocab_size, cfg.hidden_size), cfg.params_dtype)
-        lengths = cache.lengths
+        ids, parts = [], []
+
+        def add_part(part_ids, lengths, **part):
+            lo = sum(i.shape[0] for i in ids)
+            ids.append(part_ids)
+            parts.append(self.own_rows(dict(
+                part, span=(lo, lo + part_ids.shape[0]), paged=dict(
+                    page_table=cache.page_table, page_size=cache.page_size,
+                    lengths=lengths),
+            ), cache.replace(lengths=lengths)))
+
         if chunk is not None:
             if len(chunk) != 2:
                 raise ValueError(
@@ -316,25 +384,26 @@ class ServedDecoder(nn.Module):
                     f"positions) only")
             if tokens.shape[0] != 1:
                 raise ValueError("a packed chunk is one stream (batch 1)")
-            ids = tokens[0]
-            slots, positions = chunk
-            live = None
+            add_part(
+                tokens[0], cache.lengths, chunk=chunk, slots=chunk[0],
+                positions=chunk[1], live=None)
         else:
             if tokens.shape[1] != 1:
                 raise ValueError(
                     f"{who} takes a packed chunk or one token per slot, "
                     f"not a whole-prompt window")
-            ids = tokens[:, 0]
-            slots = jnp.arange(cache.num_slots, dtype=jnp.int32)
-            positions = lengths
-            live = lengths < cache.capacity
-        rows = self.own_rows(dict(
-            paged=dict(
-                page_table=cache.page_table, page_size=cache.page_size,
-                lengths=lengths),
-            chunk=chunk, slots=slots, positions=positions, live=live,
-        ), cache)
-        h = self.embed(table[ids])
+            grid = tokens[:, 0], cache.lengths, None
+        emit = None  # set: both forms at once
+        if grid is not None:
+            grid_ids, cursors, emit = grid
+            add_part(
+                grid_ids, cursors, chunk=None,
+                slots=jnp.arange(cache.num_slots, dtype=jnp.int32),
+                positions=cursors, live=cursors < cache.capacity)
+        rows = dict(parts=parts, **{
+            name: join_rows([part[name] for part in parts])
+            for name in ("slots", "positions", "live")})
+        h = self.embed(table[join_rows(ids)])
         states = self.layer_states(cache)
         chosen = []
         sums = dict(
@@ -349,6 +418,12 @@ class ServedDecoder(nn.Module):
             sums["moe_zero_assignments"] += counts["zero_assignments"]
             sums["moe_load_max"] = jnp.maximum(
                 sums["moe_load_max"], counts["load_max"])
+        if emit is not None:
+            # the head's rows: per slot the chunk row it names, then
+            # the grid's
+            budget = ids[0].shape[0]
+            h = jnp.concatenate(
+                [h[jnp.clip(emit, 0, budget - 1)], h[budget:]], axis=0)
         h = RMSNorm(
             cfg.hidden_size, cfg.rms_norm_eps, cfg.dtype, cfg.params_dtype,
             name="final_norm")(h)
@@ -360,8 +435,11 @@ class ServedDecoder(nn.Module):
         else:
             head = table.astype(cfg.dtype).T
         logits = self.project(h, head)
-        cache = self.with_states(cache, states).count(
-            **sums, **self.tick_counts(rows, cache))
+        for part in parts:
+            own = cache.replace(lengths=part["paged"]["lengths"])
+            for name, value in self.tick_counts(part, own).items():
+                sums[name] = sums[name] + value if name in sums else value
+        cache = self.with_states(cache, states).count(**sums)
         if cfg.log_routes:
             # every layer's mask of chosen experts, one row a position,
             # in one paged write beside the layers' own
@@ -369,12 +447,14 @@ class ServedDecoder(nn.Module):
             lanes = cache.routes.shape[-1]
             masks = jnp.pad(masks, ((0, 0), (0, lanes - masks.shape[1])))
             cache = cache.replace(routes=paged_scatter(
-                cache.routes, cache.page_table, slots, positions,
-                masks[:, None, :]))
+                cache.routes, cache.page_table, rows["slots"],
+                rows["positions"], masks[:, None, :]))
+        if emit is not None:
+            return logits, cache
         if chunk is not None:
             return logits[None], cache
         return logits[:, None, :], cache.replace(
-            lengths=jnp.minimum(lengths + 1, cache.capacity))
+            lengths=jnp.minimum(cache.lengths + 1, cache.capacity))
 
 
 class HybridLayer(nn.Module):
@@ -387,24 +467,21 @@ class HybridLayer(nn.Module):
         norm = dict(
             size=cfg.hidden_size, eps=cfg.rms_norm_eps, dtype=cfg.dtype,
             params_dtype=cfg.params_dtype)
-        geo = rows["geo"]
         u = RMSNorm(**norm, name="norm1")(h)
         if self.kind == "mamba":
-            y, state = MambaMixer(cfg, name="mamba")(
-                u, state, geo, rows["fresh"], rows["live"])
+            y, state = MambaMixer(cfg, name="mamba")(u, state, rows)
         else:
             y, state = GroupedAttention(cfg, name="self_attention")(
-                u, state, rows["paged"], rows["chunk"])
+                u, state, rows)
         h = h + (cfg.residual_multiplier * y).astype(cfg.dtype)
         u = RMSNorm(**norm, name="norm2")(h)
-        tokens = geo["valid"] if geo is not None else rows["live"]
         y, counts = HeldExperts(
             hidden_size=cfg.hidden_size, num_experts=cfg.num_experts,
             held=cfg.experts_held, top_k=cfg.num_experts_per_tok,
             expert_width=cfg.expert_width, shared_width=cfg.shared_width,
             dtype=cfg.dtype, params_dtype=cfg.params_dtype,
             init_std=cfg.init_std, log_chosen=cfg.log_routes, name="moe",
-        )(u, tokens)
+        )(u, rows["live"])
         h = h + (cfg.residual_multiplier * y).astype(cfg.dtype)
         return h, state, counts
 
@@ -456,20 +533,21 @@ class HybridModel(ServedDecoder):
             k=tuple(s[0] for s in kv), v=tuple(s[1] for s in kv),
             ssm=tuple(s[0] for s in state), conv=tuple(s[1] for s in state))
 
-    def own_rows(self, rows, cache):
-        """A chunk's segments for the scans, and which of its slots are
-        FRESH; ``touched`` is how many slots' states the tick advances."""
-        if rows["chunk"] is None:
+    def own_rows(self, part, cache):
+        """A chunk's segments for the scans, which of its rows are
+        tokens and which of its slots are FRESH; ``touched`` is how many
+        slots' states the part advances."""
+        if part["chunk"] is None:
             return dict(
-                rows, geo=None, fresh=None,
-                touched=jnp.sum(rows["live"].astype(jnp.int32)))
-        geo = ssm.chunk_geometry(rows["slots"], cache.num_slots)
+                part, geo=None, fresh=None,
+                touched=jnp.sum(part["live"].astype(jnp.int32)))
+        geo = ssm.chunk_geometry(part["slots"], cache.num_slots)
         return dict(
-            rows, geo=geo, fresh=cache.lengths == 0,
+            part, geo=geo, live=geo["valid"], fresh=cache.lengths == 0,
             touched=jnp.sum((geo["counts"] > 0).astype(jnp.int32)))
 
-    def tick_counts(self, rows, cache):
-        return dict(state_slots_live=rows["touched"])
+    def tick_counts(self, part, cache):
+        return dict(state_slots_live=part["touched"])
 
     def embed(self, x):
         return (

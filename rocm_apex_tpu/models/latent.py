@@ -38,7 +38,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from rocm_apex_tpu.models.hybrid import RMSNorm, ServedDecoder, rms_norm
+from rocm_apex_tpu.models.hybrid import (
+    RMSNorm, ServedDecoder, join_rows, part_rows, rms_norm,
+)
 from rocm_apex_tpu.ops.mla import (
     bounded_lengths, latent_width, mla_decode_paged, rotary,
 )
@@ -102,16 +104,18 @@ def _param(mod, name, shape):
 
 
 class LatentAttention(nn.Module):
-    """``u`` (T, hidden) at ``positions`` (T,) -> (T, hidden), with the
-    block's latent ``pool`` written at ``(w_slots, positions)`` and read
-    through ``paged``: by the packed chunk (``chunk`` = its slot ids) or
-    by the decode grid."""
+    """``u`` (T, hidden) -> (T, hidden), with the block's latent
+    ``pool`` written at every row's ``(slots, positions)`` and read part
+    by part of ``rows``: by a packed chunk (``segments`` = its slot ids)
+    or by a decode grid. The projections, the write and the absorbed
+    query run once over all rows."""
 
     cfg: LatentConfig
 
     @nn.compact
-    def __call__(self, u, pool, paged, positions, w_slots, chunk=None):
+    def __call__(self, u, pool, rows):
         cfg = self.cfg
+        positions, w_slots = rows["positions"], rows["slots"]
         nh, dn, dr, dv = (
             cfg.num_attention_heads, cfg.qk_nope_head_dim,
             cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -126,7 +130,8 @@ class LatentAttention(nn.Module):
         t = u.shape[0]
         dt = cfg.dtype
         s_q, s_kv = math.sqrt(h / rq), math.sqrt(h / rkv)
-        table, lengths = paged["page_table"], paged["lengths"]
+        table = rows["parts"][0]["paged"]["page_table"]
+        capacity = table.shape[1] * rows["parts"][0]["paged"]["page_size"]
 
         with jax.named_scope("mla_proj"):
             cq = s_q * rms_norm(
@@ -152,37 +157,43 @@ class LatentAttention(nn.Module):
                 [q_lat, q_r, jnp.zeros((t, nh, pad), dt)], axis=-1)
         scale = 1.0 / math.sqrt(dn + dr)
 
-        def latent_read(scope, rows_table, rows_lengths):
+        def latent_read(scope, q, rows_table, rows_lengths):
             # the kernel's instructions in a trace are named after the
             # innermost scope (a `cond` branch would rename them)
             with jax.named_scope(scope):
                 o, lse = mla_decode_paged(
-                    q_abs, pool, rows_table, rows_lengths, scale, rkv)
+                    q, pool, rows_table, rows_lengths, scale, rkv)
             # W_uv takes the weighted latent to each head's values
             return jnp.einsum(
                 "thr,rhv->thv", o, kv_up[..., dn:].astype(dt),
                 preferred_element_type=jnp.float32), lse
 
-        if chunk is None:
-            capacity = table.shape[1] * paged["page_size"]
-            ctx, _ = latent_read(
-                "mla_decode", table, jnp.minimum(lengths + 1, capacity))
-        else:
+        def read(part):
+            lengths, chunk = part["paged"]["lengths"], part["segments"]
+            q_abs_p = part_rows(q_abs, part)
+            if chunk is None:
+                return latent_read(
+                    "mla_decode", q_abs_p, table,
+                    jnp.minimum(lengths + 1, capacity))[0]
             from rocm_apex_tpu.ops.flash_attention_segments import (
                 flash_attention_segments_with_lse,
             )
 
             slots_n = table.shape[0]
+            c_p, k_r_p = part_rows(c, part), part_rows(k_r, part)
+            q_p = jnp.concatenate(
+                [part_rows(q_n, part), part_rows(q_r, part)], axis=-1)
+            tp = c_p.shape[0]
             with jax.named_scope("mla_chunk"):
                 # (A) the chunk's own rows, keys and values expanded
-                kv = jnp.einsum("tr,rhe->the", c, kv_up.astype(dt))
+                kv = jnp.einsum("tr,rhe->the", c_p, kv_up.astype(dt))
                 k = jnp.concatenate([
                     kv[..., :dn],
-                    jnp.broadcast_to(k_r[:, None, :], (t, nh, dr)),
+                    jnp.broadcast_to(k_r_p[:, None, :], (tp, nh, dr)),
                 ], axis=-1)
                 v = jnp.pad(kv[..., dn:], ((0, 0), (0, 0), (0, dn + dr - dv)))
                 o_a, lse_a = flash_attention_segments_with_lse(
-                    jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2),
+                    q_p.transpose(1, 0, 2),
                     k.transpose(1, 0, 2), v.transpose(1, 0, 2), chunk,
                     causal=True, scale=scale)
                 o_a = o_a.transpose(1, 0, 2)[..., :dv].astype(jnp.float32)
@@ -197,16 +208,19 @@ class LatentAttention(nn.Module):
                 o_b, lse_b = jax.lax.cond(
                     jnp.any(prefix > 0),
                     lambda: latent_read(
-                        "mla_chunk_prefix", table[row_slot], prefix),
+                        "mla_chunk_prefix", q_abs_p, table[row_slot],
+                        prefix),
                     lambda: (
-                        jnp.zeros((t, nh, dv), jnp.float32),
-                        jnp.full((t, nh), -1e30, jnp.float32)),
+                        jnp.zeros((tp, nh, dv), jnp.float32),
+                        jnp.full((tp, nh), -1e30, jnp.float32)),
                 )
                 m = jnp.maximum(lse_a, lse_b)
                 w_a, w_b = jnp.exp(lse_a - m), jnp.exp(lse_b - m)
-                ctx = (
+                return (
                     w_a[..., None] * o_a + w_b[..., None] * o_b
                 ) / (w_a + w_b)[..., None]
+
+        ctx = join_rows([read(part) for part in rows["parts"]])
         ctx = ctx.astype(dt).reshape(t, nh * dv)
         return jnp.dot(ctx, o_proj.astype(dt)), pool
 
@@ -245,9 +259,7 @@ class ShortcutLayer(nn.Module):
         moe = counts = None
         for j in range(BLOCKS):
             y, pools[j] = LatentAttention(cfg, name=f"attn_{j}")(
-                RMSNorm(**norm, name=f"norm_a{j}")(h), pools[j],
-                rows["paged"], rows["positions"], rows["slots"],
-                rows["segments"])
+                RMSNorm(**norm, name=f"norm_a{j}")(h), pools[j], rows)
             h = h + y
             u = RMSNorm(**norm, name=f"norm_m{j}")(h)
             if j == 0:
@@ -300,17 +312,17 @@ class LatentModel(ServedDecoder):
     def with_states(self, cache, states):
         return cache.replace(latent=tuple(p for pair in states for p in pair))
 
-    def own_rows(self, rows, cache):
+    def own_rows(self, part, cache):
         """A chunk's rows are live where they name a slot, and its
         segments are its slot ids."""
-        if rows["chunk"] is None:
-            return dict(rows, segments=None)
+        if part["chunk"] is None:
+            return dict(part, segments=None)
         return dict(
-            rows, segments=rows["slots"],
-            live=rows["slots"] < cache.num_slots)
+            part, segments=part["slots"],
+            live=part["slots"] < cache.num_slots)
 
-    def tick_counts(self, rows, cache):
-        if rows["chunk"] is not None:
+    def tick_counts(self, part, cache):
+        if part["chunk"] is not None:
             return {}
         # cached positions the decode grid attended over, all blocks
         _, read = bounded_lengths(

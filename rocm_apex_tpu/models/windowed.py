@@ -33,7 +33,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from rocm_apex_tpu.models.hybrid import RMSNorm, ServedDecoder, _param
+from rocm_apex_tpu.models.hybrid import (
+    RMSNorm, ServedDecoder, _param, join_rows, part_rows,
+)
 from rocm_apex_tpu.ops.mla import rotary
 from rocm_apex_tpu.ops.paging import paged_scatter
 from rocm_apex_tpu.transformer.moe import HeldExperts
@@ -99,7 +101,9 @@ class WindowedAttention(nn.Module):
     layer's paged pools ``kv``. ``kind`` ``"window"``: rotary positions
     and a window of ``cfg.sliding_window`` keys, the pools behind the
     window group's table; ``"global"``: neither. ``rows`` as
-    `ServedDecoder` hands them, with this model's own (`own_rows`)."""
+    `ServedDecoder` hands them, with this model's own (`own_rows`): the
+    projections, the rotation and the write of every row's K/V run once
+    over all rows, and each part reads the pools by its own kernel."""
 
     cfg: WindowedConfig
     kind: str
@@ -114,8 +118,10 @@ class WindowedAttention(nn.Module):
         t = u.shape[0]
         windowed = self.kind == "window"
         window = cfg.sliding_window if windowed else None
-        paged, chunk = rows["paged"], rows["chunk"]
-        table = rows["window_table"] if windowed else paged["page_table"]
+        first = rows["parts"][0]
+        table = (
+            first["window_table"] if windowed
+            else first["paged"]["page_table"])
         w_slots, w_pos = rows["slots"], rows["positions"]
         scale = hd ** -0.5
         with jax.named_scope("attn_proj"):
@@ -130,45 +136,51 @@ class WindowedAttention(nn.Module):
         k_buf, v_buf = kv
         k_buf = paged_scatter(k_buf, table, w_slots, w_pos, k)
         v_buf = paged_scatter(v_buf, table, w_slots, w_pos, v)
-        name = f"attn_{self.kind}_{'decode' if chunk is None else 'chunk'}"
-        if chunk is not None:
+
+        def read_chunk(part):
             from rocm_apex_tpu.ops.flash_attention_segments import (
                 flash_attention_chunk_paged,
             )
 
+            q_p, k_p, v_p = (
+                part_rows(x, part).transpose(1, 0, 2) for x in (q, k, v))
+
             def read(segments, table, kv_lengths):
                 # the scope opens inside a `cond` branch, which would
                 # otherwise name the kernels after the branch
-                with jax.named_scope(name):
+                with jax.named_scope(f"attn_{self.kind}_chunk"):
                     return flash_attention_chunk_paged(
-                        q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                        v.transpose(1, 0, 2), segments, k_buf, v_buf, table,
+                        q_p, k_p, v_p, segments, k_buf, v_buf, table,
                         kv_lengths, scale, window=window,
-                        positions=w_pos if windowed else None,
+                        positions=part["positions"] if windowed else None,
                     )
 
-            few = rows["few"]
+            few = part["few"]
             if few is None:
-                ctx = read(w_slots, table, rows["kv_lengths"])
-            else:
-                ctx = jax.lax.cond(
-                    few["fits"],
-                    lambda: read(
-                        few["segments"], table[few["slots"]],
-                        rows["kv_lengths"][few["slots"]]),
-                    lambda: read(w_slots, table, rows["kv_lengths"]))
-        else:
+                return read(part["slots"], table, part["kv_lengths"])
+            return jax.lax.cond(
+                few["fits"],
+                lambda: read(
+                    few["segments"], table[few["slots"]],
+                    part["kv_lengths"][few["slots"]]),
+                lambda: read(part["slots"], table, part["kv_lengths"]))
+
+        def read_grid(part):
             from rocm_apex_tpu.ops.flash_attention import (
                 flash_attention_decode_paged,
             )
 
-            with jax.named_scope(name):
-                ctx = flash_attention_decode_paged(
-                    q.reshape(t * nq, 1, hd), k_buf, v_buf, table,
-                    rows["kv_lengths"], scale, window=window,
+            with jax.named_scope(f"attn_{self.kind}_decode"):
+                return flash_attention_decode_paged(
+                    part_rows(q, part).reshape(-1, 1, hd), k_buf, v_buf,
+                    table, part["kv_lengths"], scale, window=window,
                 )
+
         with jax.named_scope("attn_proj"):
-            ctx = ctx.astype(cfg.dtype).reshape(t, nq * hd)
+            ctx = join_rows([
+                (read_grid if part["chunk"] is None else read_chunk)(part)
+                .astype(cfg.dtype).reshape(-1, nq * hd)
+                for part in rows["parts"]])
             return jnp.dot(ctx, o_w.astype(cfg.dtype)), (k_buf, v_buf)
 
 
@@ -241,7 +253,7 @@ class WindowedModel(ServedDecoder):
             window_k=tuple(s[0] for s in behind),
             window_v=tuple(s[1] for s in behind))
 
-    def own_rows(self, rows, cache):
+    def own_rows(self, part, cache):
         """The window group's table, the rows that are tokens, and the
         keys each slot's rows read from the cache: in the decode grid a
         live row reads its own too (the scatter wrote it), a dead one
@@ -249,14 +261,14 @@ class WindowedModel(ServedDecoder):
         kernel scores the whole chunk against every slot that does),
         and ``few`` names the slots that have rows in it, where they are
         at most `CHUNK_SLOTS` of more."""
-        lengths = rows["paged"]["lengths"]
-        rows = dict(rows, window_table=cache.window_table)
-        if rows["chunk"] is None:
-            return dict(rows, few=None, kv_lengths=jnp.where(
-                rows["live"], jnp.minimum(lengths + 1, cache.capacity), 0))
+        lengths = part["paged"]["lengths"]
+        part = dict(part, window_table=cache.window_table)
+        if part["chunk"] is None:
+            return dict(part, few=None, kv_lengths=jnp.where(
+                part["live"], jnp.minimum(lengths + 1, cache.capacity), 0))
         slots_n = cache.num_slots
-        live = rows["slots"] < slots_n
-        in_chunk = jnp.zeros((slots_n,), bool).at[rows["slots"]].set(
+        live = part["slots"] < slots_n
+        in_chunk = jnp.zeros((slots_n,), bool).at[part["slots"]].set(
             True, mode="drop")
         few = None
         if slots_n > CHUNK_SLOTS:
@@ -269,22 +281,22 @@ class WindowedModel(ServedDecoder):
                 fits=rank[-1] < CHUNK_SLOTS,
                 slots=jnp.argsort(~in_chunk, stable=True)[:CHUNK_SLOTS],
                 segments=jnp.where(
-                    live, new_id[jnp.clip(rows["slots"], 0, slots_n - 1)],
+                    live, new_id[jnp.clip(part["slots"], 0, slots_n - 1)],
                     CHUNK_SLOTS).astype(jnp.int32))
         return dict(
-            rows, live=live, few=few,
+            part, live=live, few=few,
             kv_lengths=jnp.where(in_chunk, lengths, 0))
 
-    def tick_counts(self, rows, cache):
+    def tick_counts(self, part, cache):
         """Cached positions the decode grid attended over, summed over
         live slots and layers: after the window's bound, and before."""
-        if rows["chunk"] is not None:
+        if part["chunk"] is not None:
             return {}
         kinds = self.cfg.layer_types
         behind = sum(kind == "window" for kind in kinds)
-        cached = jnp.sum(rows["kv_lengths"])
+        cached = jnp.sum(part["kv_lengths"])
         seen = jnp.sum(
-            jnp.minimum(rows["kv_lengths"], self.cfg.sliding_window))
+            jnp.minimum(part["kv_lengths"], self.cfg.sliding_window))
         return dict(
             kv_rows_read=(len(kinds) - behind) * cached + behind * seen,
             kv_rows_cached=len(kinds) * cached)

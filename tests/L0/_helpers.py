@@ -14,32 +14,6 @@ PAGE = 8 if ON_CHIP else 4
 PAGE_I8 = 32 if ON_CHIP else 4
 
 
-def hybrid_toy():
-    """(model, params) of the hybrid state-space / routed-expert toy:
-    the benchmark's granite configuration shrunk, fp32, its multipliers
-    set so that a wrong state shows in the logits. Its paged cache
-    keeps tick counters."""
-    import json
-    import pathlib
-
-    import jax.numpy as jnp
-
-    from benchmarks.families import granite_hybrid as fam
-    from benchmarks.harness import rehearsal
-    from rocm_apex_tpu.models.hybrid import HybridModel
-
-    root = pathlib.Path(__file__).resolve().parents[2]
-    raw = json.loads(
-        (root / "benchmarks/configs/granite-4.0-h-small.json").read_text())
-    config = dict(
-        rehearsal.shrink(raw), embedding_multiplier=1.0,
-        residual_multiplier=1.5, logits_scaling=1.0)
-    model = HybridModel(fam.model_config(
-        config, params_dtype=jnp.float32, dtype=jnp.float32,
-        attention_impl="flash", log_routes=False))
-    return model, fam.make_params(config, 5, jnp.float32)
-
-
 def jit_shmap(*args, **kwargs):
     """jit-wrapped shard_map: eager shard_map dispatches per-op on the
     CPU mesh and runs Pallas kernels in slow python-interpret mode —

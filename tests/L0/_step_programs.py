@@ -1,6 +1,6 @@
-"""The serving engines of the benchmark's older cells at toy size, and a
-count of what their two step programs trace: every equation of the
-jaxpr, those of nested jaxprs (a `pjit`, a loop's body, a kernel's)
+"""The serving engines of the benchmark's cells at toy size, and a count
+of what their two step programs trace: every equation of the jaxpr,
+those of nested jaxprs (a `pjit`, a loop's body, a kernel's)
 included."""
 
 import jax
@@ -11,7 +11,7 @@ from benchmarks.harness import rehearsal
 from benchmarks.harness.manifest import Manifest
 
 CELLS = ("gpt1p3b-serve-chat", "granite4hs-serve-chat",
-         "longcat-serve-agent-sat")
+         "longcat-serve-agent-sat", "smallthinker-serve-longmix-sat")
 
 
 def toy_engine(cell_name, seed=7):
@@ -32,19 +32,20 @@ def _nested(value):
             yield from _nested(v)
 
 
-def count_equations(jaxpr):
-    n = 0
+def every_equation(jaxpr):
     for eqn in jaxpr.eqns:
-        n += 1
+        yield eqn
         for value in eqn.params.values():
             for inner in _nested(value):
-                n += count_equations(inner)
-    return n
+                yield from every_equation(inner)
 
 
-def step_program_counts(engine):
-    """(equations of `_mixed`, equations of `_decode`) as the engine
-    would trace them for a tick."""
+def count_equations(jaxpr):
+    return sum(1 for _ in every_equation(jaxpr))
+
+
+def step_program_jaxprs(engine):
+    """(`_mixed`, `_decode`) as the engine would trace them for a tick."""
     programs = engine.programs
     slots, budget = engine.num_slots, engine.prefill_token_budget
     by_name = dict(
@@ -66,6 +67,10 @@ def step_program_counts(engine):
     out = []
     for fn, names in ((programs.mixed_fn, programs.mixed_operands),
                       (programs.decode_fn, programs.decode_operands)):
-        jaxpr = jax.make_jaxpr(fn)(*(by_name[n] for n in names))
-        out.append(count_equations(jaxpr.jaxpr))
+        out.append(jax.make_jaxpr(fn)(*(by_name[n] for n in names)).jaxpr)
     return tuple(out)
+
+
+def step_program_counts(engine):
+    """(equations of `_mixed`, equations of `_decode`)."""
+    return tuple(count_equations(j) for j in step_program_jaxprs(engine))

@@ -19,7 +19,8 @@ import statistics
 import jax
 import jax.numpy as jnp
 import pytest
-from _helpers import PAGE, hybrid_toy
+from _helpers import PAGE
+from _serving import served_toy
 
 from rocm_apex_tpu import profiler
 from rocm_apex_tpu.inference import InferenceEngine, SamplingParams
@@ -198,6 +199,11 @@ class TestTickSpans:
         for t in recording["ticks"]:
             c = t["counts"]
             assert (c["program"] == "mixed") == (c["chunk_tokens"] > 0)
+            # the GPT engine's mixed tick applies the model to the chunk
+            # and then to the grid (a served model of declared layers
+            # reads 1 there: test_step_programs.py)
+            assert not eng.programs.one_pass
+            assert c["model_passes"] == (2 if c["program"] == "mixed" else 1)
 
     def test_enqueue_and_admit_carry_the_request_ids(self, recording):
         enq = [s for s in recording["spans"] if s["name"] == "engine.enqueue"]
@@ -220,7 +226,7 @@ class TestTickSpans:
 
 def hand_off_engine(case, tracer):
     if case == "hybrid-counters":
-        model, params = hybrid_toy()
+        model, params, _ = served_toy("hybrid")
         return InferenceEngine(
             model, params, num_slots=3, capacity=64, paged=True,
             page_size=PAGE, prefill_token_budget=16, tracer=tracer,
@@ -347,6 +353,10 @@ class TestWholePromptMode:
             sum(c["decodes"] for c in ticks) + len(PROMPTS)
             == cfg_eng.stats()["generated_tokens"])
         assert all(c["pages_total"] == 0 == c["chunk_tokens"] for c in ticks)
+        # a whole-prompt prefill an admission, and the grid
+        assert all(
+            c["model_passes"] == c["admitted"] + (c["decodes"] > 0)
+            for c in ticks)
         phases = {
             e["name"] for e in tracer.events()
             if e["ph"] == "X" and e["name"].startswith("engine.")}

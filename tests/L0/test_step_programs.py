@@ -8,7 +8,8 @@ tokens bit for bit; every set traces each program once; and
 `compatible_with` refuses each mismatch an engine's ``step_source=``
 must refuse. The GPT cases reuse test_inference.py's shape tuple
 (slots=2, capacity=24, budget=4, the fp32 model), the hybrid case
-test_hybrid_serving.py's toy.
+test_hybrid_serving.py's toy, whose mixed tick is ONE apply of the
+model: the second half of this file holds that body to the two applies.
 """
 
 import re
@@ -18,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _helpers import PAGE, hybrid_toy
+from _helpers import PAGE
+from _serving import SERVED, prompts_of, served_engine, served_toy
 from rocm_apex_tpu.inference import (
     AdapterPool,
     KVCache,
@@ -59,7 +61,7 @@ def gpt():
 
 @pytest.fixture(scope="module")
 def hybrid():
-    model, params = hybrid_toy()
+    model, params, _ = served_toy("hybrid")
     return dict(model=model, params=params, slots=3, capacity=64, budget=16)
 
 
@@ -99,9 +101,10 @@ def build(world, paged=False, spec_k=0, lora=False, hybrid=False, **over):
 
 
 def one_tick_then_a_decode(world, programs, cache, adapters):
-    """A mixed tick (slot 0's whole prompt, completing and fed into the
-    decode grid; the rest of the budget from slot 1's prompt) and a
-    decode tick after it, each run twice. Returns the sampled tokens."""
+    """A mixed tick (slot 0's whole prompt, completing and, under the
+    body of two applies, fed into the decode grid; the rest of the
+    budget from slot 1's prompt) and a decode tick after it, each run
+    twice. Returns the sampled tokens."""
     B, S = world["budget"], world["slots"]
     rest = B - PROMPT
     if getattr(cache, "counters", None) is not None:
@@ -215,3 +218,196 @@ def test_every_feature_set_runs_the_one_body(case, request):
         with pytest.raises(ValueError, match=re.escape(name)):
             programs.compatible_with(
                 build(world, **{**features, **change})[0])
+
+
+# ---------------------------------------------------------------------------
+# the mixed tick of ONE apply (a model that declares `mixed_in_one_pass`)
+# ---------------------------------------------------------------------------
+
+KEPT = ("k", "v", "latent", "ssm", "conv", "window_k", "window_v", "lengths")
+
+
+def served_programs(kind, two_applies, slots=3, budget=16):
+    """(programs, a cache whose slots own their worst-case pages in
+    order, params) of a served toy under either mixed body."""
+    model, params, geometry = served_toy(kind, two_applies)
+    cache = PagedKVCache.from_spec(
+        model.cache_spec(), slots, geometry["capacity"],
+        page_size=geometry["page_size"], dtype=jnp.float32,
+        prefill_token_budget=budget)
+    table = jnp.arange(
+        slots * cache.pages_per_slot, dtype=jnp.int32,
+    ).reshape(slots, cache.pages_per_slot)
+    cache = cache.replace(page_table=table)
+    if cache.window:
+        pool = cache.window_k[0].shape
+        full = (slots * cache.pages_per_slot,) + pool[1:]
+        cache = cache.replace(
+            window_table=table, window_k=tuple(
+                jnp.zeros(full, jnp.float32) for _ in cache.window_k),
+            window_v=tuple(
+                jnp.zeros(full, jnp.float32) for _ in cache.window_v))
+    programs = StepPrograms(
+        model, GREEDY, cache, budget=budget, donate_buffers=False)
+    assert programs.one_pass is (not two_applies)
+    return programs, cache, params
+
+
+def mixed_tick(programs, params, cache, segments, before, decoding,
+               dec_tokens, completes):
+    """One mixed tick: ``segments`` = [(slot, tokens, first position)]
+    packed in slot order; ``decoding`` the grid's live slots;
+    ``completes`` = {slot: chunk row of its prompt's last token}, which
+    the one-pass body takes as the head's rows and the two-apply body
+    does NOT feed into its grid (so its grid is the decode apply after
+    the chunk apply, over other slots)."""
+    S, B = before.shape[0], programs._built_for["prefill_token_budget"]
+    tokens = np.zeros((B,), np.int32)
+    slots = np.full((B,), S, np.int32)
+    pos = np.zeros((B,), np.int32)
+    after = before.copy()
+    used = 0
+    for slot, toks, first in segments:
+        n = len(toks)
+        tokens[used:used + n] = toks
+        slots[used:used + n] = slot
+        pos[used:used + n] = np.arange(first, first + n)
+        after[slot] = first + n
+        used += n
+    done = np.full((S,), -1, np.int32)
+    if programs.one_pass:
+        for slot, row in completes.items():
+            done[slot] = row
+    active = np.zeros((S,), bool)
+    active[list(decoding)] = True
+    operands = dict(
+        params=params, cache=cache, chunk_tokens=tokens, chunk_slots=slots,
+        chunk_pos=pos, lengths_before=before, lengths_after=after,
+        completion_idx=done, dec_tokens=dec_tokens, dec_active=active,
+        chunk_poison=np.zeros((B,), np.float32),
+        dec_poison=np.zeros((S,), np.float32), key=jax.random.PRNGKey(0))
+    chunk_tok, dec_tok, chunk_bad, dec_bad, _, cache = programs.mixed(
+        *(operands[n] for n in programs.mixed_operands))
+    assert not np.asarray(chunk_bad).any() and not np.asarray(dec_bad).any()
+    chunk_tok = np.asarray(chunk_tok)
+    first = {
+        slot: int(chunk_tok[slot if programs.one_pass else row])
+        for slot, row in completes.items()}
+    return first, np.asarray(dec_tok), cache, after
+
+
+@pytest.mark.parametrize("kind", sorted(SERVED))
+def test_one_apply_leaves_the_cache_the_two_applies_leave(kind):
+    """Two mixed ticks at toy size in float32 under either body. The
+    second holds every case a layer's core meets: a slot that goes on
+    from a cached prefix and completes (past the window, where the model
+    has one), a FRESH slot, and a decode row whose token is the first
+    token the tick before emitted. What the cache keeps (K/V, latent
+    rows, recurrent state and convolution tail, window pages, lengths)
+    and every token agree."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 250, size=n).tolist() for n in (5, 24, 9)]
+    out = {}
+    for two_applies in (False, True):
+        programs, cache, params = served_programs(kind, two_applies)
+        before = np.zeros((3,), np.int32)
+        first1, _, cache, before = mixed_tick(
+            programs, params, cache,
+            [(0, prompts[0], 0), (1, prompts[1][:11], 0)], before,
+            decoding=[], dec_tokens=np.zeros((3,), np.int32),
+            completes={0: 4})
+        dec = np.zeros((3,), np.int32)
+        dec[0] = first1[0]
+        first2, dec_tok, cache, after = mixed_tick(
+            programs, params, cache,
+            [(1, prompts[1][11:], 11), (2, prompts[2][:3], 0)], before,
+            decoding=[0], dec_tokens=dec, completes={1: 12})
+        assert programs.traces["mixed"] == 1
+        want = after.copy()
+        want[0] += 1  # the grid's live row advanced by its one token
+        np.testing.assert_array_equal(np.asarray(cache.lengths), want)
+        out[two_applies] = dict(
+            first=(first1, first2), dec=int(dec_tok[0]), kept={
+                name: getattr(cache, name) for name in KEPT})
+    one, two = out[False], out[True]
+    assert one["first"] == two["first"] and one["dec"] == two["dec"]
+    compared = 0
+    for name in KEPT:
+        a, b = (jax.tree_util.tree_leaves(o["kept"][name]) for o in (one, two))
+        assert len(a) == len(b)
+        for x, y in zip(a, b):
+            np.testing.assert_allclose(
+                np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-6,
+                err_msg=name)
+            compared += int(np.any(np.asarray(x) != 0))
+    assert compared > 2  # pools that hold something, and the lengths
+
+
+@pytest.mark.parametrize("kind", sorted(SERVED))
+def test_a_first_token_leaves_in_tick_t_and_the_second_in_t_plus_1(kind):
+    """The engine over the one-pass body: a prompt that completes in
+    tick T has ONE token after it and two after T+1, where the body of
+    two applies (the parent's) gives it two in T; each request's tokens
+    are the parent's; a mixed tick counts one apply of the model; and
+    decode rows + first tokens = generated tokens."""
+    from rocm_apex_tpu.monitor.trace import Tracer
+
+    prompts = prompts_of([5, 37, 21, 9], seed=2)
+    served = {}
+    for two_applies in (False, True):
+        tracer = Tracer()
+        eng = served_engine(kind, two_applies, tracer=tracer)
+        assert eng.programs.one_pass is (not two_applies)
+        for p in prompts:
+            eng.add_request(p, 7)
+        out, seen = {}, {}
+        while eng.has_work():
+            for r in eng.step():
+                out[r.request_id] = r
+            for st in eng._slots:
+                if st is not None and st.generated:
+                    seen.setdefault(st.req.request_id, []).append(
+                        len(st.generated))
+        ticks = [
+            e["args"] for e in tracer.events()
+            if e.get("name") == "engine.tick"]
+        served[two_applies] = dict(
+            tokens=[out[i].tokens for i in sorted(out)], seen=seen,
+            ticks=ticks, stats=eng.stats())
+    one, two = served[False], served[True]
+    assert one["tokens"] == two["tokens"]
+    assert all(len(t) == 7 for t in one["tokens"])
+    # tokens a request holds after each tick since its first
+    assert all(s[:3] == [1, 2, 3] for s in one["seen"].values())
+    assert all(s[:2] == [2, 3] for s in two["seen"].values())
+    for body, passes in ((one, 1), (two, 2)):
+        mixed = [t for t in body["ticks"] if t["program"] == "mixed"]
+        decode = [t for t in body["ticks"] if t["program"] == "decode"]
+        assert mixed and decode
+        assert {t["model_passes"] for t in mixed} == {passes}
+        assert {t["model_passes"] for t in decode} == {1}
+        assert (
+            sum(t["decodes"] for t in body["ticks"]) + len(prompts)
+            == body["stats"]["generated_tokens"] == 7 * len(prompts))
+        assert body["stats"]["page_stalls"] == 0
+    # a slot is held one tick longer a request, no more
+    assert 0 < len(one["ticks"]) - len(two["ticks"]) <= len(prompts)
+
+
+def test_the_one_pass_body_refuses_a_features_operands():
+    model, params, geometry = served_toy("hybrid", False)
+    world = dict(
+        model=model, params=params, slots=3, capacity=64, budget=16)
+    programs, cache, _ = build(world, paged=True, spec_k=2)
+    operands = dict(
+        params=params, cache=cache, key=jax.random.PRNGKey(0),
+        **{n: np.zeros((16,), np.int32) for n in (
+            "chunk_tokens", "chunk_slots", "chunk_pos", "commit_slots")},
+        **{n: np.zeros((3,), np.int32) for n in (
+            "lengths_before", "lengths_after", "completion_idx",
+            "dec_tokens")},
+        dec_active=np.zeros((3,), bool),
+        chunk_poison=np.zeros((16,), np.float32),
+        dec_poison=np.zeros((3,), np.float32))
+    with pytest.raises(ValueError, match="one apply.*commit_slots"):
+        programs.mixed(*(operands[n] for n in programs.mixed_operands))

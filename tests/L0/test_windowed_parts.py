@@ -2,14 +2,19 @@
 plain `jax.numpy`: `HeldExperts` with a second router input and a ReLU
 gate, rotary positions with pairs by halves; and the proof that the
 older served models' step programs trace what they traced before those
-parts, the window group and the kernels' lower bound existed."""
+parts, the window group and the kernels' lower bound existed; since
+the mixed tick of one apply, that the GPT engine's programs and every
+model's `_decode` still do, and that a served model's `_mixed` traces
+fewer equations than its two applies did and no logits a chunk row."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _helpers import assert_close
-from _step_programs import CELLS, step_program_counts, toy_engine
+from _step_programs import (
+    CELLS, count_equations, every_equation, step_program_jaxprs, toy_engine,
+)
 
 from benchmarks.families import smallthinker as fam
 from rocm_apex_tpu.ops.mla import rotary
@@ -124,23 +129,50 @@ def test_a_global_layer_has_no_positions_and_a_window_layer_has():
         rtol=1e-5, atol=1e-6)
 
 
-# Equations of (`_mixed`, `_decode`) of the older cells' engines at
-# their rehearsal sizes, read once from the tree BEFORE this model
-# (commit 9f95df4, PR 31) with `_step_programs.step_program_counts`: a
+# Equations of (`_mixed`, `_decode`) of the cells' engines at their
+# rehearsal sizes, read with `_step_programs.step_program_counts`. The
+# GPT pair and every `_decode` count are the PARENT's (GPT, granite and
+# longcat from the tree before the window/global model, commit 9f95df4,
+# PR 31; smallthinker's `_decode` from PR 35's, commit 3afee19): a
 # window-less call of either paged kernel, `HeldExperts` with its
-# default arguments and a cache without a window group trace what they
-# traced then.
+# default arguments, a cache without a window group and the frame's
+# decode-grid form trace what they traced then. A served model's
+# `_mixed` is ONE apply since PR 36 and is held UNDER what its two
+# applies traced at the parent (4,815, 5,316 and 12,772).
 PARENT_COUNTS = {
     "gpt1p3b-serve-chat": (2511, 1083),
-    "granite4hs-serve-chat": (4815, 1987),
-    "longcat-serve-agent-sat": (5316, 2319),
+    "granite4hs-serve-chat": (3531, 1987),
+    "longcat-serve-agent-sat": (3619, 2319),
+    "smallthinker-serve-longmix-sat": (8313, 5668),
+}
+TWO_APPLIES = {
+    "granite4hs-serve-chat": 4815,
+    "longcat-serve-agent-sat": 5316,
+    "smallthinker-serve-longmix-sat": 12772,
 }
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_the_older_step_programs_trace_as_at_the_parent(cell):
+def test_the_step_programs_trace_as_pinned(cell):
     engine = toy_engine(cell)
-    assert step_program_counts(engine) == PARENT_COUNTS[cell]
+    mixed, decode = step_program_jaxprs(engine)
+    counts = count_equations(mixed), count_equations(decode)
+    assert counts == PARENT_COUNTS[cell]
     cache = engine.cache
-    assert cache.window == 0 and cache.window_table is None
-    assert not cache.window_k and not cache.window_v
+    if cell != "smallthinker-serve-longmix-sat":
+        assert cache.window == 0 and cache.window_table is None
+        assert not cache.window_k and not cache.window_v
+    assert engine.programs.one_pass is (cell in TWO_APPLIES)
+    if not engine.programs.one_pass:
+        return
+    assert counts[0] < TWO_APPLIES[cell]
+    # no value of the one apply has a row of logits a CHUNK row: the
+    # head runs over the rows that emit a token
+    budget, vocab = engine.prefill_token_budget, engine.model.cfg.vocab_size
+    assert budget > 2 * engine.num_slots
+    shapes = {
+        tuple(v.aval.shape) for eqn in every_equation(mixed)
+        for v in eqn.outvars if hasattr(v.aval, "shape")}
+    assert (2 * engine.num_slots, vocab) in shapes
+    assert not [s for s in shapes if len(s) >= 2 and s[-1] == vocab
+                and budget in s[:-1]]
