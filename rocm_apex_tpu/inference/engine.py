@@ -65,6 +65,8 @@ from rocm_apex_tpu.inference.paging import (
 from rocm_apex_tpu.inference.programs import FETCHED, StepPrograms
 from rocm_apex_tpu.monitor.trace import (
     NULL_TRACER,
+    gc_pauses,
+    install_gc_hook,
     mint_trace_id,
     phase,
 )
@@ -78,6 +80,17 @@ __all__ = [
     "FINISH_REASONS",
     "shard_tp1_params",
 ]
+
+#: the phases of the tick's own clock (`InferenceEngine.step`), in the
+#: order a tick meets them; ``rest`` is what lies outside the others
+TICK_PHASES = (
+    "admit", "pack", "table_push", "dispatch", "fetch", "commit", "rest",
+)
+# where the tick's account counts a tick and its wall time, by `program`
+_ACCOUNT_KEYS = {
+    "mixed": ("cum_ticks_mixed", "cum_ms_mixed"),
+    "decode": ("cum_ticks_decode", "cum_ms_decode"),
+}
 
 
 def shard_tp1_params(model, params_tp1, mesh, sample_tokens=None):
@@ -775,6 +788,9 @@ class InferenceEngine:
             "serve_slots_active", "Slots holding a live request."
         )
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # the collector's pauses as ``host.gc`` spans and totals: once a
+        # process, whatever the tracer (the tick's account reads them)
+        install_gc_hook()
         # ---- runtime retrace sentinel + sensor plane (ISSUE 19) ------
         # retrace_policy="count"|"raise" arms a RetraceSentinel at the
         # next reset_stats() (the bench contract's warmed-up-now
@@ -818,6 +834,9 @@ class InferenceEngine:
         self.flight_recorder = flight_recorder
         self._draining = False
         self._tick = 0  # step() count — the fault plans' tick domain
+        # the tick's own clock: (phase, when it ended) of the tick
+        # under way, appended where each phase ends
+        self._laps: List[Tuple[str, float]] = []
         # queue_full results awaiting delivery through the next step()
         self._shed_results: List[GenerationResult] = []
         # stall watchdog anchors: last wall time token progress was
@@ -1145,7 +1164,17 @@ class InferenceEngine:
         emitted), ``acceptance_rate`` (their ratio), ``rollbacks``
         (spans with at least one rejected draft). Every drafted token
         is one or the other: ``drafted - accepted`` is exactly the
-        rolled-back row count."""
+        rolled-back row count.
+
+        The tick's own account since `reset_stats` (they also ride on
+        every ``engine.tick`` span: docs/observability.md):
+        ``cum_ticks_mixed``/``cum_ticks_decode`` and
+        ``cum_ms_mixed``/``cum_ms_decode`` (ticks and their wall time
+        by the program they ran), ``cum_gap_ms`` (the serving loop's
+        time between two ticks while the engine had work),
+        ``cum_gc_n``/``cum_gc_ms``/``gc_max_ms`` (the collector's
+        pauses), ``slow_ms``/``slow_tick`` (the slowest tick, wall plus
+        gap; `slowest_tick` names its program and phases)."""
         prefill_ticks = (
             self._mixed_steps if self.chunked else self._admitted
         )
@@ -1271,7 +1300,30 @@ class InferenceEngine:
             ),
             "ttft_ms_p50": _pct_ms(self._ttfts, self._h_ttft, 50),
             "ttft_ms_p95": _pct_ms(self._ttfts, self._h_ttft, 95),
+            **self.account_totals(),
+            "slow_ms": self._account["slow_ms"],
+            "slow_tick": float(self._account["slow_tick"]),
         }
+
+    def account_totals(self) -> Dict[str, float]:
+        """The tick account's sums since `reset_stats`, under the names
+        they ride on ``engine.tick`` (`_tick_account`). A dozen numbers
+        the tick keeps anyway: safe to read from another thread at any
+        rate (`start_exporter` shows them on ``/varz``), where `stats()`
+        walks every request and every mapped page."""
+        return {
+            k: v for k, v in self._account.items()
+            if not k.startswith("slow_")}
+
+    def slowest_tick(self) -> Dict[str, Any]:
+        """The slowest tick since `reset_stats`, wall plus the gap
+        before it: ``slow_ms``, ``slow_tick`` (its `tick_count`),
+        ``slow_program`` and ``slow_phases`` (that tick's gap and phase
+        durations, names and microseconds joined with spaces: put a
+        stall down to admit, pack, table_push, dispatch, fetch, commit,
+        the rest of the tick or the loop around it)."""
+        return {
+            k: v for k, v in self._account.items() if k.startswith("slow_")}
 
     def _zero_counters(self) -> None:
         """The monotonic counters and wall-time sums `stats()` reports,
@@ -1297,6 +1349,24 @@ class InferenceEngine:
         # adapters
         self._adapter_stalls = 0
         self._tier_preemptions = self._tier_sheds = 0
+        # the tick's own account (`_tick_account`), under the names it
+        # rides on ``engine.tick``: ticks and their wall time by
+        # `program`, the serving loop's time between two ticks, the
+        # collector's pauses, and the slowest tick (wall + gap) with its
+        # phases. ONE dict, updated in place and handed to the span as
+        # it stands, so a tick builds nothing to report it.
+        self._account: Dict[str, Any] = {
+            "cum_ticks_mixed": 0, "cum_ticks_decode": 0,
+            "cum_ms_mixed": 0.0, "cum_ms_decode": 0.0,
+            "cum_gap_ms": 0.0,
+            "cum_gc_ms": 0.0, "cum_gc_n": 0, "gc_max_ms": 0.0,
+            "slow_ms": 0.0, "slow_tick": -1, "slow_program": "none",
+            "slow_phases": "",
+        }
+        # the collector's totals at the last tick's reading
+        self._gc_seen = gc_pauses()[:2]
+        # when the last `step()` returned, where it left work behind
+        self._returned_at: Optional[float] = None
 
     def reset_stats(self) -> None:
         """Zero the telemetry counters and per-request distributions.
@@ -1532,8 +1602,12 @@ class InferenceEngine:
         next) — including any shed (``queue_full``) and expired
         (``deadline``) requests, so every submitted request yields
         exactly one result."""
+        entered = time.perf_counter()
+        gap = 0.0 if self._returned_at is None else entered - self._returned_at
+        self._laps = []
+        number = self._tick
         with self.tracer.phase(
-            "engine.tick", track="engine", tick=self._tick
+            "engine.tick", track="engine", tick=number
         ) as tick:
             # read before the tick maps or frees a page
             pages_used = self.pages_used
@@ -1555,17 +1629,85 @@ class InferenceEngine:
             counts.setdefault("admitted", leased)
             if window:  # pages that went back behind windows in this tick
                 window["window_pages_freed"] = self._window_pages_freed
-            tick.set_metadata(
-                **counts,
-                finished=len(out),
-                queue_depth=len(self._queue),
-                slots=self.num_slots,
-                budget=self.prefill_token_budget or 0,
-                pages_used=pages_used,
-                pages_total=self.pages_total,
-                **window,
-            )
+            gc_n, gc_us = self._tick_account(
+                number, counts.get("program", "none"), entered, gap)
+            # handed over only while somebody keeps them (a profiler
+            # capture is live, or the tracer's ring records the span):
+            # thirty keywords cost a tick more than the account itself
+            if tick.is_enabled():
+                tick.set_metadata(
+                    **counts,
+                    finished=len(out),
+                    queue_depth=len(self._queue),
+                    slots=self.num_slots,
+                    budget=self.prefill_token_budget or 0,
+                    pages_used=pages_used,
+                    pages_total=self.pages_total,
+                    **window,
+                    # the tick's account (docs/observability.md)
+                    gap_us=int(1e6 * gap),
+                    gc_us=gc_us,
+                    gc_n=gc_n,
+                    cum_prefill_tokens=self._prompt_tokens,
+                    cum_generated=self._generated_tokens,
+                    **self._account,
+                )
+        # the loop's gap is counted from here, where the loop has a
+        # reason to come straight back
+        self._returned_at = time.perf_counter() if self.has_work() else None
         return out
+
+    def _tick_account(
+        self, number: int, program: str, entered: float, gap: float,
+    ) -> Tuple[int, int]:
+        """Close the tick's books in `_account`, which rides on
+        ``engine.tick`` as it stands beside the tick's counts: the sums
+        since `reset_stats` by the tick's ``program`` (`account_totals`)
+        and the slowest tick so far (`slowest_tick`). ``gap`` is from
+        the previous `step()`'s return to this one's entry, 0 where
+        that one left no work behind. Returns this tick's ``gc_n`` and
+        ``gc_us``: the collections since the previous tick's reading.
+        The last tick a capture holds so carries the whole run's totals
+        to a reader that has nothing but the capture.
+
+        The tick's own clock is read where each phase ends
+        (``self._laps``): what has passed since the last lap (or the
+        tick's entry) is that phase's, so the laps tile the tick. They
+        are summed by name only for a tick that becomes the slowest."""
+        now = time.perf_counter()
+        wall = now - entered
+        account = self._account
+        count, seconds, longest = gc_pauses()
+        gc_n, gc_us = count - self._gc_seen[0], 0
+        if gc_n:
+            gc_s = seconds - self._gc_seen[1]
+            self._gc_seen = count, seconds
+            gc_us = int(1e6 * gc_s)
+            account["cum_gc_n"] += gc_n
+            account["cum_gc_ms"] += 1e3 * gc_s
+            # one collection's pause; where several fell between two
+            # ticks, their sum, capped by the process's longest
+            account["gc_max_ms"] = max(
+                account["gc_max_ms"], 1e3 * min(gc_s, longest))
+        if program in _ACCOUNT_KEYS:
+            ticks, ms = _ACCOUNT_KEYS[program]
+            account[ticks] += 1
+            account[ms] += 1e3 * wall
+        if gap:
+            account["cum_gap_ms"] += 1e3 * gap
+        if 1e3 * (wall + gap) > account["slow_ms"]:
+            phases, at = dict.fromkeys(TICK_PHASES, 0.0), entered
+            for name, t in (*self._laps, ("rest", now)):
+                phases[name] += t - at
+                at = t
+            account.update(
+                slow_ms=1e3 * (wall + gap),
+                slow_tick=number,
+                slow_program=program,
+                slow_phases=f"gap {int(1e6 * gap)} " + " ".join(
+                    f"{name} {int(1e6 * s)}" for name, s in phases.items()),
+            )
+        return gc_n, gc_us
 
     def _admit_phase(self) -> Tuple[List[GenerationResult], int]:
         """The tick's ``engine.admit`` phase: the watchdog, the shed
@@ -1586,6 +1728,7 @@ class InferenceEngine:
                 admit.set_metadata(
                     request_ids=" ".join(str(i) for i in leased)
                 )
+        self._laps.append(("admit", time.perf_counter()))
         return out, len(leased)
 
     def _close_tick(self) -> None:
@@ -2652,7 +2795,9 @@ class InferenceEngine:
         """``engine.fetch``: ONE batched `device_get` (= the device
         sync) — never a per-request scalar pull."""
         with self.tracer.phase("engine.fetch", track="engine"):
-            return jax.device_get(values)
+            values = jax.device_get(values)
+        self._laps.append(("fetch", time.perf_counter()))
+        return values
 
     def _run_program(self, name: str, operands, fetch: bool = True):
         """The one way a tick reaches the device: run step program
@@ -2710,6 +2855,7 @@ class InferenceEngine:
                         raise FaultInjected(
                             f"injected host_fetch fault (tick {self._tick})"
                         )
+                self._laps.append(("dispatch", time.perf_counter()))
                 values = out[:n], out[n + 1].counters if self.paged else None
                 if fetch:
                     values = self._fetch(values)
@@ -3202,9 +3348,11 @@ class InferenceEngine:
                 # prompt + generated tokens are recomputed through the
                 # ordinary chunked prefill.
                 self._preempt_for_pages()
+        self._laps.append(("pack", time.perf_counter()))
         if self.paged:
             with self.tracer.phase("engine.table_push", track="engine"):
                 self._push_table()
+            self._laps.append(("table_push", time.perf_counter()))
 
         chunk_out = dec_out = chunk_bad = dec_bad = None
         chunk_kv = layer_counts = None
@@ -3448,6 +3596,7 @@ class InferenceEngine:
             if self._window_allocator is not None:
                 self._free_window_pages()
             self._close_tick()
+        self._laps.append(("commit", time.perf_counter()))
         return finished, counts
 
     def _step_whole(
@@ -3556,6 +3705,7 @@ class InferenceEngine:
                 if done is not None:
                     finished.append(self._evict(slot, state, done))
             self._close_tick()
+        self._laps.append(("commit", time.perf_counter()))
         return finished, counts
 
     def _finish_reason(self, state: _Slot) -> Optional[str]:

@@ -274,7 +274,9 @@ def start_exporter(
     registry=None, *, port: int = 0, engine=None, router=None, **kw
 ) -> TelemetryServer:
     """One-call convenience: start a `TelemetryServer`, wiring
-    `engine_health` automatically when an engine is passed, or the
+    `engine_health` and the tick's account (the engine's
+    `account_totals()` and `slowest_tick()`: a dozen numbers, read
+    without walking anything) on `/varz` when an engine is passed, or the
     whole fleet surface when a `ReplicaRouter` is passed — merged
     per-scrape registry (``router.merged_registry`` as the zero-arg
     provider), `fleet_health` on `/healthz` (503 only with no healthy
@@ -289,8 +291,13 @@ def start_exporter(
             registry = router.merged_registry
         kw.setdefault("health_fn", fleet_health(router))
         kw.setdefault("varz_fn", router.varz)
-    elif engine is not None and "health_fn" not in kw:
-        kw["health_fn"] = engine_health(engine)
+    elif engine is not None:
+        kw.setdefault("health_fn", engine_health(engine))
+        # the tick's account and the slowest tick with its phases
+        # (docs/observability.md); not `stats()`, whose percentiles and
+        # page walk would hold the stepping thread up at every scrape
+        kw.setdefault("varz_fn", lambda: {
+            "engine": {**engine.account_totals(), **engine.slowest_tick()}})
     for owner in (router, engine):
         if owner is None:
             continue

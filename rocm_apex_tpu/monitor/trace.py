@@ -39,6 +39,15 @@ preallocated no-op context manager — call sites pay an attribute check
 programs and host↔device fetch pattern are untouched (pinned by
 tests/L0/test_trace.py).
 
+**The collector as a span (ISSUE 37).** `install_gc_hook()` puts one
+callback on ``gc.callbacks``: every collection of Python's cyclic
+collector is a ``phase("host.gc", generation=)`` from its start to
+its stop, and `gc_pauses()` returns the process's totals
+(collections, seconds, the longest). The serving engine asks for the
+hook and reads the totals once a tick, so a pause shows on the tick it
+delayed (``gc_n``/``gc_us``) and, in a capture, as a host event where
+it stopped the thread.
+
 **Fleet-causal tracing (ISSUE 19).** A fleet shatters one request's
 timeline across tracers: the router records `dispatch`, replica A the
 prefill, replica B (after a failover or a page-shipping handoff) the
@@ -72,6 +81,7 @@ events are process-global, so one armed sentinel guards the whole
 fleet.
 """
 
+import gc
 import itertools
 import json
 import os
@@ -79,13 +89,15 @@ import threading
 import time
 import weakref
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
 __all__ = [
     "PROGRAM_PREFIX",
     "phase",
+    "install_gc_hook",
+    "gc_pauses",
     "Tracer",
     "NULL_TRACER",
     "mint_trace_id",
@@ -97,6 +109,10 @@ __all__ = [
     "COMPILE_EVENT_PHASES",
 ]
 
+
+# guards the once-a-process installs: the collector's hook and the
+# retrace sentinel's listeners
+_INSTALL_LOCK = threading.Lock()
 
 #: every span the program itself opens in a profiler capture starts
 #: with this (the benchmark's own spans start with ``bench/``)
@@ -120,6 +136,54 @@ def phase(name: str, *, step_num: Optional[int] = None, **counts):
             PROGRAM_PREFIX + name, step_num=step_num, **counts
         )
     return jax.profiler.TraceAnnotation(PROGRAM_PREFIX + name, **counts)
+
+
+# ---------------------------------------------------------------------
+# the collector as a span (ISSUE 37)
+# ---------------------------------------------------------------------
+
+# Process-wide, like the collector: [collections, seconds in them, the
+# longest one's seconds] since the hook went in. One collection runs at
+# a time and its two callbacks run on the thread that set it off, so
+# the open span needs no lock.
+_GC_TOTALS = [0, 0.0, 0.0]
+_GC_OPEN: List[Any] = []  # [the open ``host.gc`` annotation, its start]
+
+
+def _on_gc(when: str, info: Dict[str, int]) -> None:
+    if when == "start":
+        span = phase("host.gc", generation=info["generation"])
+        span.__enter__()
+        _GC_OPEN[:] = span, time.perf_counter()
+    elif _GC_OPEN:  # a stop whose start came before the hook: skipped
+        span, t0 = _GC_OPEN
+        seconds = time.perf_counter() - t0
+        del _GC_OPEN[:]
+        span.__exit__(None, None, None)
+        _GC_TOTALS[0] += 1
+        _GC_TOTALS[1] += seconds
+        if seconds > _GC_TOTALS[2]:
+            _GC_TOTALS[2] = seconds
+
+
+def install_gc_hook() -> None:
+    """Make every collection of Python's cyclic collector a
+    `phase("host.gc", generation=)` from its start to its stop, and
+    keep the process's totals for `gc_pauses`. Idempotent;
+    like `_install_listeners` below it goes in once a process and
+    stays (`InferenceEngine` asks for it and has no ``close()``). In a
+    capture a pause is a host event on the capture's clock, on the
+    thread it stopped; out of one it costs a collection two clock
+    reads and an annotation that formats nothing."""
+    with _INSTALL_LOCK:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def gc_pauses() -> Tuple[int, float, float]:
+    """(collections, seconds spent in them, the longest one's seconds)
+    in this process since `install_gc_hook`."""
+    return tuple(_GC_TOTALS)
 
 
 class _NullSpan:
@@ -157,6 +221,13 @@ class _Span:
             self._ann.__enter__()
         self._t0 = self._tracer.clock()
         return self
+
+    def is_enabled(self):
+        """Whether anything keeps this span's counts: always, because
+        the tracer's ring records it (the annotation's own method: a
+        bare `phase` says whether a profiler capture is live, so a
+        caller asks before it builds counts nobody would read)."""
+        return True
 
     def set_metadata(self, **counts):
         """Counts known only once the span is open (the annotation's
@@ -563,7 +634,6 @@ class RetraceError(RuntimeError):
 # short-lived sentinels (tests, benches) vanish with their owners.
 _SENTINELS: "weakref.WeakSet" = weakref.WeakSet()
 _LISTENERS_INSTALLED = False
-_INSTALL_LOCK = threading.Lock()
 
 
 def _dispatch_compile_event(event: str, **kwargs) -> None:
