@@ -9,8 +9,10 @@ independently A/B-able rungs:
 1. **Block tables** — all slots draw fixed-size pages from ONE shared
    pool; a ``(num_slots, pages_per_slot)`` int32 table maps each
    slot's logical positions onto pool pages. Memory in use scales
-   with LIVE tokens; the decode read is bounded by pages actually
-   mapped (`flash_attention_decode_paged`).
+   with LIVE tokens; the decode read walks the pages a slot has live
+   and no other (`flash_attention_decode_paged`: one grid step a slot
+   and head block, the slot's own pages copied from the pool two
+   ahead).
 2. **int8 per-page quantization** — pools store int8 with one fp32
    scale per (page, head) (EQuARX's per-chunk-scale design, arXiv
    2506.17615, applied to cache bytes): cache HBM and decode DMA

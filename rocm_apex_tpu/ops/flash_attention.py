@@ -956,14 +956,28 @@ def flash_attention_decode(
 # ---------------------------------------------------------------------------
 
 
-# What one grid step of the paged decode kernel may hold in VMEM: the K
-# and V tiles of its head block (double-buffered by the pipeline), the
-# query/output/lse blocks and the accumulators of those heads, and their
-# float32 scores (counted for every head, though the heads run in
-# turn). The head block is the largest divisor of the pool's
-# heads that stays inside it (`_paged_head_block`); the call's own
-# scoped-VMEM limit leaves Mosaic its relayout room above it (a v5e
-# core has 128 MiB).
+# pages of each pool the paged decode kernel holds at once: the one being
+# multiplied and two on their way (`ops/mla.py` timed the forms on a
+# v5e: with one on its way the memory idles between copies, a third in
+# flight gives no more)
+PAGED_BUFFERS = 3
+
+# a head block of at most this many heads is lowered with its heads
+# side by side. A larger block loops one head at a time: in groups of
+# four GPT's sixteen would run 19% faster (216 us a call against 266 on
+# a v5e), but a loop over unrolled groups costs 0.2 s of lowering at
+# each of that cell's 48 decode call sites, 15% of its set-up (PERF.md,
+# PR 39)
+PAGED_HEADS_UNROLLED = 4
+
+# What one grid step of the paged decode kernel may hold in VMEM: the
+# `PAGED_BUFFERS` page buffers of K and of V for its head block, the
+# query/output/lse blocks (double-buffered by the pipeline) and the
+# accumulators of those heads, and their float32 scores (counted for
+# every head, though they run a few at a time). The head block is the
+# largest divisor of the pool's heads that stays inside it
+# (`_paged_head_block`); the call's own scoped-VMEM limit leaves Mosaic
+# its relayout room above it (a v5e core has 128 MiB).
 PAGED_VMEM_BUDGET = 16 << 20
 PAGED_VMEM_LIMIT = 32 << 20
 
@@ -971,7 +985,7 @@ PAGED_VMEM_LIMIT = 32 << 20
 def _paged_block_bytes(hb, ps, d, block_t, kv_itemsize, q_itemsize,
                        quantized):
     """VMEM bytes of one grid step that takes ``hb`` heads."""
-    kv = 2 * 2 * hb * ps * d * kv_itemsize  # K and V, double-buffered
+    kv = 2 * PAGED_BUFFERS * hb * ps * d * kv_itemsize  # K and V buffers
     if quantized:
         kv += 2 * hb * ps * d * q_itemsize  # their dequantized tiles
     per_row = (
@@ -999,10 +1013,10 @@ def _paged_head_block(nh, ps, d, block_t, kv_itemsize, q_itemsize,
 
 
 def _paged_grid_row(b, nhb, row_blocks):
-    """Grid row b of the paged kernel as (slot, head block, row block):
+    """Grid step b of the paged kernel as (slot, head block, row block):
     slot-major, row blocks innermost (grouped heads: they share a K/V
-    head block, which then stays put across them). b is never negative,
-    so truncating division, and none where a factor is 1."""
+    head block). b is never negative, so truncating division, and none
+    where a factor is 1."""
     r = 0
     if row_blocks > 1:
         b, r = (
@@ -1018,34 +1032,38 @@ def _paged_grid_row(b, nhb, row_blocks):
 
 def _decode_paged_kernel(
     scale, hb, nhb, ps, num_pages, block_t, quantized, row_blocks, bound,
-    tab_ref, len_ref, src_ref, *rest,
+    tab_ref, len_ref, live_ref, *rest,
 ):
-    """Online-softmax decode against a PAGED cache for grid point
-    (b, j): b = (slot, head block, row block), slot-major, and j walks
-    the slot's page list. One step takes ``hb`` heads of ONE page: the
-    K and V tiles are the `(hb, page_size, head_dim)` slab of the pool
-    as it is stored, fetched by the scalar-prefetch index maps through
-    the page table, so the kernel sees exactly the pages the slot owns.
-    What the contiguous `_decode_kernel` still DMAs (its skip is
-    compute-only) never leaves HBM here: a step past the slot's live
-    prefix, and every step of a slot with nothing to read, holds the
-    block index of the step before it, and Pallas elides the DMA of a
-    repeated block index. Each head runs the accumulation of
+    """Online-softmax decode against a PAGED cache for grid step b =
+    (slot, head block, row block), slot-major: ``hb`` heads of that
+    slot's query rows against the pages the slot has live, one after
+    another. The pools stay where they are; a page's `(hb, page_size,
+    head_dim)` K slab and V slab, as the pool stores them, are copied
+    into one of `PAGED_BUFFERS` buffers each. A fetch cursor ``cur`` =
+    (grid step, page of its slot's list, pages asked for, pages awaited)
+    runs through the live pages of ALL steps, ``PAGED_BUFFERS - 1``
+    pages ahead of the products (`ops/mla.py::_kernel` is the pattern):
+    whoever multiplies a page first asks for the next one the cursor
+    points at, so a step's first pages are on their way before it begins
+    and the copies follow one another without a gap. A step with nothing
+    to read (a slot that maps no page, a length of 0) copies nothing and
+    loops over nothing. Each head runs the accumulation of
     `_decode_kernel` (base-2 online softmax, natural-log lse at the
-    boundary) over its own rows of the head-major scratch.
+    boundary) over its own rows of the head-major scratch; the heads of
+    a block of at most `PAGED_HEADS_UNROLLED` are lowered side by side.
 
     ``quantized`` adds per-(page, head) fp32 dequantization: int8
     tiles are scaled into the score/value dots from SMEM-resident
-    scale tables (``hb`` scalar reads a step). ``src_ref`` is only the
-    index maps' (`flash_attention_decode_paged`).
+    scale tables (``hb`` scalar reads a page). ``live_ref[b]`` is the
+    first grid step at or after b with something to read (the grid's
+    length where none has), which is where the cursor goes from b - 1.
 
     ``bound`` (None, ``"slot"`` or ``"rows"``) is a LOWER bound on the
     positions read, a sliding window's: a fourth prefetched vector gives
-    each slot's first position, step j takes the page ``first // ps +
-    j`` (the pages before it are never fetched) and the first live page
+    each slot's first position, the loop starts at the page ``first //
+    ps`` (the pages before it are never fetched) and the first live page
     is masked from the bound on; with ``"rows"`` each query row masks
     from a bound of its own (one more block, ``(block_t, 1)``)."""
-    del src_ref
     first_ref = lo_ref = None
     if bound is not None:
         first_ref, rest = rest[0], rest[1:]
@@ -1055,50 +1073,99 @@ def _decode_paged_kernel(
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
+    o_ref, lse_ref, k_buf, v_buf, sem, cur, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+    n = pl.num_programs(0)
     slot, hblk, _ = _paged_grid_row(b, nhb, row_blocks)
-    head0 = hblk * hb
     ln = len_ref[slot]
-    # (without a bound the first position of step j's page stays the
-    # `j * ps` it was, written where it was: the older callers' programs
-    # are held to what they traced)
     if bound is not None:
         first = first_ref[slot]
-        row0 = jax.lax.mul(
-            jax.lax.add(jax.lax.div(first, jnp.int32(ps)), j),
-            jnp.int32(ps))
+    if quantized:
+        head0 = jax.lax.mul(hblk, hb)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    # scalar arithmetic in plain `lax` primitives: an operator on a
+    # traced scalar is a nested `pjit`, and the kernel is traced anew at
+    # every call site of a served program (72 in the GPT cell's two)
+    def page0(r):
+        """The first page grid step r reads (r may be the grid's end)."""
+        if bound is None:
+            return jnp.int32(0)
+        s = _paged_grid_row(
+            jax.lax.min(r, jax.lax.sub(n, 1)), nhb, row_blocks)[0]
+        return jax.lax.div(first_ref[s], jnp.int32(ps))
 
-    def _body():
-        col = (j * ps if bound is None else row0) + (
+    def slabs(page, head):
+        """The K and V slab of ``page`` for the ``hb`` heads from
+        ``head`` on."""
+        if nhb == 1:
+            return k_ref.at[page], v_ref.at[page]
+        heads = pl.ds(head, hb)
+        return k_ref.at[page, heads], v_ref.at[page, heads]
+
+    @pl.when(jax.lax.eq(b, 0))
+    def _reset():
+        cur[0] = live_ref[0]
+        cur[1] = page0(live_ref[0])
+        cur[2] = 0
+        cur[3] = 0
+
+    def ask(_, carry):
+        r, j, asked = cur[0], cur[1], cur[2]
+
+        @pl.when(jax.lax.lt(r, n))
+        def _start():
+            at = jax.lax.rem(asked, PAGED_BUFFERS)
+            s, hk, _ = _paged_grid_row(r, nhb, row_blocks)
+            k_src, v_src = slabs(
+                jax.lax.min(tab_ref[s, j], num_pages - 1),
+                jax.lax.mul(hk, hb) if nhb > 1 else 0)
+            pltpu.make_async_copy(k_src, k_buf.at[at], sem.at[0, at]).start()
+            pltpu.make_async_copy(v_src, v_buf.at[at], sem.at[1, at]).start()
+            j1 = jax.lax.add(j, 1)
+            last = jax.lax.ge(jax.lax.mul(j1, ps), len_ref[s])
+            nxt = live_ref[jax.lax.add(r, 1)]
+            cur[0] = jax.lax.select(last, nxt, r)
+            cur[1] = jax.lax.select(last, page0(nxt), j1)
+            cur[2] = jax.lax.add(asked, 1)
+
+        return carry
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _page(j, carry):
+        awaited = cur[3]
+        # as many asks as bring the cursor `PAGED_BUFFERS` pages past
+        # the last page awaited: all of them at the call's first page,
+        # one at every other
+        jax.lax.fori_loop(
+            jax.lax.sub(cur[2], awaited), PAGED_BUFFERS, ask, 0)
+        at = jax.lax.rem(awaited, PAGED_BUFFERS)
+        cur[3] = jax.lax.add(awaited, 1)
+        # the waits read the semaphore and the size alone
+        k_any, v_any = slabs(0, 0)
+        pltpu.make_async_copy(k_any, k_buf.at[at], sem.at[0, at]).wait()
+        pltpu.make_async_copy(v_any, v_buf.at[at], sem.at[1, at]).wait()
+        col = jax.lax.mul(j, ps) + (
             jax.lax.broadcasted_iota(jnp.int32, (block_t, ps), 1)
         )
         if bound is not None:
             lo = first if bound == "slot" else lo_ref[...]
             seen = jnp.logical_and(col < ln, col >= lo)
         if quantized:
-            # j is inside the live prefix here, so this is the page the
-            # index map fetched
-            page = jnp.minimum(tab_ref[slot, j], num_pages - 1)
+            page = jax.lax.min(tab_ref[slot, j], num_pages - 1)
 
         def _head(h, carry):
             q = q_ref[0, h, 0]  # (block_t, d)
-            k = k_ref[0, h]  # (ps, d)
-            v = v_ref[0, h]
+            k = k_buf[at, h]  # (ps, d)
+            v = v_buf[at, h]
             if quantized:
                 k = (
-                    k.astype(jnp.float32) * ks_ref[page, head0 + h]
+                    k.astype(jnp.float32) * ks_ref[page, jax.lax.add(head0, h)]
                 ).astype(q.dtype)
                 v = (
-                    v.astype(jnp.float32) * vs_ref[page, head0 + h]
+                    v.astype(jnp.float32) * vs_ref[page, jax.lax.add(head0, h)]
                 ).astype(q.dtype)
             s = jax.lax.dot_general(
                 (q * jnp.asarray(scale * LOG2E, q.dtype)), k,
@@ -1125,26 +1192,32 @@ def _decode_paged_kernel(
 
         # one traced body for all heads of the block: a Python loop
         # traces and lowers it hb times at every call site (16 heads x
-        # 72 sites: 115 s of the serving cell's set-up, PERF.md PR 27)
-        jax.lax.fori_loop(0, hb, _head, 0)
+        # 72 sites: 115 s of the serving cell's set-up, PERF.md PR 27).
+        # A block of few heads is unrolled at LOWERING: side by side,
+        # one head's products run beside the next head's softmax and a
+        # page's arithmetic hides under its copy, which one head after
+        # another does not (PERF.md PR 39)
+        jax.lax.fori_loop(
+            0, hb, _head, 0, unroll=hb <= PAGED_HEADS_UNROLLED)
+        return carry
 
-    # pages wholly past the live prefix: no compute AND no fetch (the
-    # index map held their DMA on an already-resident block)
-    pl.when((j * ps if bound is None else row0) < ln)(_body)
+    # the slot's own live pages, in order: none where it has nothing to
+    # read (the bounds are traced, so an empty range loops over nothing)
+    jax.lax.fori_loop(
+        page0(b),
+        jax.lax.div(jax.lax.add(ln, ps - 1), jnp.int32(ps)), _page, 0)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        # every step writes its own output block, live or not: a dead
-        # slot's rows are zeros at the -inf tier, which the chunk
-        # read's log-sum-exp merge weighs to exactly zero
-        l = l_scr[:, :, :1]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, :, 0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, :, 0] = jnp.where(
-            l > 0.0,
-            (m_scr[:, :, :1] + jnp.log2(safe_l)) * LN2,
-            NEG_INF,
-        )
+    # every step writes its own output block, live or not: a dead
+    # slot's rows are zeros at the -inf tier, which the chunk read's
+    # log-sum-exp merge weighs to exactly zero
+    l = l_scr[:, :, :1]
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o_ref[0, :, 0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
+    lse_ref[0, :, 0] = jnp.where(
+        l > 0.0,
+        (m_scr[:, :, :1] + jnp.log2(safe_l)) * LN2,
+        NEG_INF,
+    )
 
 
 def flash_attention_decode_paged(
@@ -1176,24 +1249,27 @@ def flash_attention_decode_paged(
     it carries (the engine's dead rows carry the capacity sentinel),
     and emits zeros at the -inf tier.
 
-    The grid walks (slot · head block, page). A grid step takes a
-    BLOCK OF HEADS of one page: all heads of a page are contiguous in
-    the pool, so the K and V tiles are one `(hb, page_size, head_dim)`
-    slab each, fetched via a scalar-prefetch index map that resolves
-    the table on the fly. ``hb`` follows the shapes of the call (the
-    largest divisor of the pool's heads whose blocks fit
-    ``PAGED_VMEM_BUDGET``: all 16 heads of a 512-row bf16 page at the
-    decode step, 8 under a 256-row chunk), so the grid is
-    ``num_slots · heads / hb · pages_per_slot`` steps. HBM reads are
-    bounded by pages actually live — the paged answer to the
-    contiguous kernel's fixed-capacity tail DMA: a step past a slot's
-    live prefix holds the slot's last live page, and a slot with
-    NOTHING to read (length 0) holds whatever block the step before it
-    held (the last block of the nearest live slot before it; the
-    first block of the first live slot when none is), so no tile of a
-    page nobody owns is fetched. Every step still costs its fixed
-    overhead and the slot's small query/output blocks: the grid does
-    not shrink with the load.
+    ONE grid step a (slot, head block): the step loops over the pages
+    the slot has live, in order, and takes a BLOCK OF HEADS of each. All
+    heads of a page are contiguous in the pool, so the K and V tiles
+    are one `(hb, page_size, head_dim)` slab each. The pools are not
+    blocked by the grid; they stay in device memory, and a fetch cursor
+    copies each live slab into one of `PAGED_BUFFERS` buffers, running
+    two pages ahead of the products through the live pages of ALL
+    steps: a slot's first pages were asked for by the live slots before
+    it, and only the call's first page is waited for from its start
+    (`ops/mla.py::mla_decode_paged` is the pattern, timed on a v5e in
+    PERF.md, PR 31 and PR 39). ``hb`` follows the shapes of the call
+    (the largest divisor of the pool's heads whose blocks and page
+    buffers fit ``PAGED_VMEM_BUDGET``: all 16 heads of a 512-row bf16
+    page at the decode step, 4 under a 256-row chunk), so the grid is
+    ``num_slots · heads / hb`` steps whatever ``pages_per_slot`` is. HBM
+    reads are the pages actually live, the paged answer to the
+    contiguous kernel's fixed-capacity tail DMA: no page past a slot's
+    live prefix is asked for, and a slot with NOTHING to read (length
+    0, or no mapped page) copies nothing, loops over nothing and costs
+    its one step and its small query/output blocks. A live page is read
+    whole, however few of its rows are live.
 
     GROUPED K/V heads: ``q`` may hold ``g`` query heads per pool head,
     (num_slots·heads·g, t, head_dim) with query head ``n·g + i``
@@ -1201,7 +1277,7 @@ def flash_attention_decode_paged(
     query rows, so they are folded into the kernel's row axis (a free
     reshape: as many as keep a row block at or under
     ``GROUP_FOLD_ROWS``) and a K/V page is fetched once for all of
-    them; what does not fold walks the grid as further row blocks of
+    them; what does not fold takes further grid steps, row blocks of
     the same head block. With g = 1 nothing changes.
 
     ``k_scale``/``v_scale`` ((num_pages, heads) fp32) switch the pools
@@ -1222,8 +1298,8 @@ def flash_attention_decode_paged(
     window), kv_lengths[s])``. Either way the pages that lie wholly
     before a slot's bound are neither fetched nor need to be mapped (the
     cache frees them: their table entries hold the sentinel), the first
-    live page is masked from the bound on, and the grid's page axis is
-    as long as a window is and no longer.
+    live page is masked from the bound on, and a slot's loop starts at
+    its bound's page.
     """
     bh, t, d0 = q.shape
     num_pages, nh, ps, dp = k_pool.shape
@@ -1272,15 +1348,13 @@ def flash_attention_decode_paged(
     kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d - d0)))
     vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d - d0)))
     table = jnp.asarray(page_table, jnp.int32)
-    bound, walk, first = None, pages_per_slot, None
+    bound = first = None
     if window is not None:
         if quantized:
             raise ValueError("a windowed read has no int8 form")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
         bound = "slot" if q_positions is None else "rows"
-        # a window's keys lie in this many pages at most
-        walk = min(pages_per_slot, (window + ps - 2) // ps + 1)
         first = jnp.maximum(
             jnp.asarray(kv_lengths, jnp.int32)
             + (0 if q_positions is None else 1) - window, 0)
@@ -1298,55 +1372,27 @@ def flash_attention_decode_paged(
         jnp.cumprod(is_mapped.astype(jnp.int32), axis=1), axis=1
     )
     lens = jnp.minimum(jnp.asarray(kv_lengths, jnp.int32), mapped * ps)
-    # the slot whose block a slot's steps hold: itself when it has
-    # something to read, else the last live slot before it, else the
-    # first live slot after it (the last slot when nothing is live)
-    idx = jnp.arange(num_slots, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(lens > 0, idx, -1))
-    after = jax.lax.cummin(
-        jnp.where(lens > 0, idx, num_slots - 1), reverse=True
-    )
-    src = jnp.where(before >= 0, before, after)
+    # live[b]: the first grid step at or after b with something to read
+    # (the grid's length: none), which is where the fetch cursor goes
+    # from step b - 1; a slot's steps (its head and row blocks) read
+    # alike
+    steps = num_slots * nhb * rb
+    reads = lens > (0 if window is None else first // ps * ps)
+    if nhb * rb > 1:
+        reads = jnp.repeat(reads, nhb * rb)
+    live = jax.lax.cummin(
+        jnp.where(
+            jnp.pad(reads, (0, 1)),
+            jnp.arange(steps + 1, dtype=jnp.int32), steps),
+        reverse=True)
 
-    def _row_map(b, j, *_):
+    def _row_map(b, *_):
         return (*_paged_grid_row(b, nhb, rb), 0, 0)
 
-    def _page_map(b, j, tab, lens, src, *first):
-        # a repeated block index is not refetched. Past a slot's live
-        # prefix: its last live page. A slot with nothing to read: the
-        # block of the step before its first (the LAST block of the
-        # live slot before it), else the block of the step after its
-        # last (the FIRST block of the live slot after it). Plain lax
-        # primitives: an index map is lowered at every call site.
-        slot, hblk, _ = _paged_grid_row(b, nhb, rb)
-        held = src[slot]
-        dead = lens[slot] == 0
-        before = jnp.logical_and(dead, held >= slot)
-        last_page = jax.lax.max(
-            jax.lax.div(lens[held] + (ps - 1), jnp.int32(ps)), 1
-        ) - 1
-        page0 = jnp.int32(0)
-        if first:  # a window: the walk starts at the bound's page
-            page0 = jax.lax.div(first[0][held], jnp.int32(ps))
-            j = jax.lax.add(page0, j)
-        jeff = jax.lax.select(
-            before, page0,
-            jax.lax.select(dead, last_page, jax.lax.min(j, last_page)),
-        )
-        if nhb > 1:
-            hblk = jax.lax.select(
-                before, jnp.int32(0),
-                jax.lax.select(dead, jnp.int32(nhb - 1), hblk),
-            )
-        return (jax.lax.min(tab[held, jeff], num_pages - 1), hblk, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, hb, 1, block_t, d), _row_map),
-        pl.BlockSpec((1, hb, ps, d), _page_map),
-        pl.BlockSpec((1, hb, ps, d), _page_map),
-    ]
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, hb, 1, block_t, d), _row_map), pool, pool]
     ins = [qp, kp, vp]
-    prefetch = [table, lens, src]
+    prefetch = [table, lens, live]
     if window is not None:
         prefetch.append(first)
         if bound == "rows":
@@ -1354,7 +1400,7 @@ def flash_attention_decode_paged(
             lo = jnp.maximum(
                 jnp.asarray(q_positions, jnp.int32) + 1 - window, 0)
             in_specs.append(
-                pl.BlockSpec((block_t, 1), lambda b, j, *_: (0, 0)))
+                pl.BlockSpec((block_t, 1), lambda b, *_: (0, 0)))
             ins.append(jnp.pad(lo, (0, block_t - t)).reshape(block_t, 1))
     if quantized:
         smem = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -1367,13 +1413,17 @@ def flash_attention_decode_paged(
         # the page table stays FIRST and two-dimensional: the trace's
         # readers tell this kernel by it
         num_scalar_prefetch=len(prefetch),
-        grid=(num_slots * nhb * rb, walk),
+        grid=(steps,),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, hb, 1, block_t, d), _row_map),
             pl.BlockSpec((1, hb, 1, block_t, 1), _row_map),
         ],
         scratch_shapes=[
+            pltpu.VMEM((PAGED_BUFFERS, hb, ps, d), kp.dtype),
+            pltpu.VMEM((PAGED_BUFFERS, hb, ps, d), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, PAGED_BUFFERS)),
+            pltpu.SMEM((4,), jnp.int32),
             pltpu.VMEM((hb, block_t, 128), jnp.float32),
             pltpu.VMEM((hb, block_t, 128), jnp.float32),
             pltpu.VMEM((hb, block_t, d), jnp.float32),
@@ -1389,8 +1439,10 @@ def flash_attention_decode_paged(
             jax.ShapeDtypeStruct(qp.shape, q.dtype),
             jax.ShapeDtypeStruct(qp.shape[:-1] + (1,), jnp.float32),
         ],
+        # the cursor's state goes from one step to the next
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=PAGED_VMEM_LIMIT
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PAGED_VMEM_LIMIT,
         ),
     )(*prefetch, *ins)
     o = o.reshape(bh, block_t, d)[:, :t, :d0]

@@ -19,8 +19,9 @@ transforms every consumer shares:
   the table back into the contiguous ``(num_slots, capacity, …)``
   layout (+ dequantization). The jnp attention fallback reads this
   view, which makes paged-vs-contiguous parity BIT-exact there; the
-  flash path instead gathers page tiles in-kernel
-  (`flash_attention_decode_paged`) and never materializes it.
+  flash path instead copies each slot's live pages in-kernel
+  (`flash_attention_decode_paged`: a grid step a slot and head block,
+  a loop over the pages that slot has live) and never materializes it.
 * ``paged_fork`` — the copy-on-write primitive: duplicate one page's
   rows (pool + scales) so a prefix-sharing slot can diverge without
   touching its sharers' bytes.
@@ -36,9 +37,10 @@ of a page is always consistent with the page's current scale.
 Pool layout is ``(num_pages, heads, page_size, head_dim)`` — heads
 AHEAD of the page rows (the ISSUE sketch writes (num_pages, page_size,
 heads, head_dim)) so a single (page, head) tile is the pool's LAST TWO
-dims: the Pallas paged-decode kernel fetches ``(1, 1, page_size,
-head_dim)`` blocks, which Mosaic tiles natively, instead of a
-sublane-degenerate ``(1, page_size, 1, head_dim)`` slice.
+dims: the Pallas paged-decode kernel copies a page's ``(head block,
+page_size, head_dim)`` slab as one contiguous piece, whole tiles that
+Mosaic lays out natively, instead of a sublane-degenerate ``(1,
+page_size, 1, head_dim)`` slice.
 
 A WRITE IS A TILE GROUP, NOT A ROW. In that layout one token's write
 is one row of each head's ``(page_size, head_dim)`` tile, and on a TPU a

@@ -5,8 +5,8 @@ many follows from the shapes of the call through `PAGED_VMEM_BUDGET`
 (no argument chooses it), so the cases here force 1, 2 and all heads
 by setting that budget to what such a block needs. One page table
 serves every case: slots with nothing to read first, between live
-ones and last (their K/V index maps hold the block of a neighbour, so
-a wrong hold would show as a live slot reading another slot's page),
+ones and last (the fetch cursor skips them, so a wrong hand-over
+would show as a live slot reading another slot's page),
 a slot of one row, one exactly at a page boundary, one at capacity,
 and unmapped sentinel entries everywhere past a slot's pages; dead
 slots carry the capacity sentinel as their length, as the engine's
@@ -115,18 +115,25 @@ def test_head_blocks_match_the_plain_read(
 def test_head_block_follows_the_shapes():
     """The served geometries (pages of 512 x 128, a v5e's budget): every
     head of a bf16 page at the decode step, fewer under a chunk's rows,
-    never more than the pool holds (tp > 1: its local heads)."""
+    never more than the pool holds (tp > 1: its local heads). The
+    reckoning holds `PAGED_BUFFERS` (three) page buffers of K and of V
+    where the fixed grid's pipeline held two: 16 heads under a 256-row
+    chunk take 4 a step where they took 8; the other shapes stay."""
     bf16, decode, chunk = 2, fa.DECODE_BLOCK_T, 256
     assert fa._paged_head_block(16, 512, 128, decode, bf16, bf16, False) == 16
     assert fa._paged_head_block(8, 512, 128, decode, bf16, bf16, False) == 8
-    assert fa._paged_head_block(16, 512, 128, chunk, bf16, bf16, False) == 8
+    assert fa._paged_head_block(16, 512, 128, chunk, bf16, bf16, False) == 4
     assert fa._paged_head_block(8, 512, 128, 512, bf16, bf16, False) == 4
     assert fa._paged_head_block(16, 512, 128, decode, 1, bf16, True) == 16
     # a divisor of the heads, and one head whatever the budget
     assert fa._paged_head_block(12, 512, 128, chunk, bf16, bf16, False) == 6
     assert fa._paged_head_block(7, 2048, 256, 512, 4, 4, False) == 1
-    for hb in (16, 8):
+    for hb in (16, 4):
         rows = decode if hb == 16 else chunk
         assert fa._paged_block_bytes(
             hb, 512, 128, rows, bf16, bf16, False) <= fa.PAGED_VMEM_BUDGET
+    # three buffers of K and of V: 12 MiB of the decode step's block
+    assert fa.PAGED_BUFFERS == 3
+    assert fa._paged_block_bytes(
+        16, 512, 128, decode, bf16, bf16, False) >= 2 * 3 * (2 << 20)
     assert fa.PAGED_VMEM_BUDGET < fa.PAGED_VMEM_LIMIT

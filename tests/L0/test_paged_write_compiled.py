@@ -3,7 +3,7 @@ whole-pool copy: a paged K/V write reaches the pool in the layout the
 pool is stored in (`ops/paging.py::paged_scatter`). And their paged
 attention reads take a block of heads a grid step
 (`ops/flash_attention.py::flash_attention_decode_paged`): the grid's
-length follows (slot, page), not (slot, head, page).
+length follows (slot, head block), not (slot, head, page).
 
 A row scatter into the `(page, head, row, head_dim)` pool made the TPU
 compiler re-lay every pool twice per write (`copy` to `{3,1,2,0}` and
@@ -186,24 +186,25 @@ def test_no_whole_pool_copy(programs, name):
 
 @pytest.mark.parametrize("name", ["decode", "mixed"])
 def test_paged_reads_take_a_block_of_heads_a_grid_step(built, name):
-    """At the decode shape (one row padded to 16) a paged read walks at
-    most slots x pages-per-slot x heads / 8 grid steps (it walked
-    slots x heads x pages-per-slot = 1,024 with a head a step); the
-    chunk's read of the cache, whose 256 rows bound the block, at most
-    half of that walk. The page table stays the first operand,
-    two-dimensional: `benchmarks/layer_metrics/decode_paged_roofline.py`
-    tells the kernel by it."""
+    """A paged read takes ONE grid step a (slot, head block), whatever
+    the table's width (the slot's live pages are a loop inside the step;
+    the fixed grid walked slots x head blocks x pages-per-slot): at the
+    decode shape (one row padded to 16) every head of a page fits a
+    step, so a step a slot; the chunk's read of the cache, whose 256
+    rows bound the block, four heads a step. The page table stays the
+    first operand, two-dimensional:
+    `benchmarks/layer_metrics/decode_paged_roofline.py` tells the kernel
+    by it."""
     grids = built[1][name]
     steps = {}
     for rows, grid in grids:
-        steps.setdefault(rows, []).append(grid[0] * grid[1])
+        assert len(grid) == 1, grids
+        steps.setdefault(rows, []).append(grid[0])
     decode = steps.pop(16)
-    assert len(decode) == DEPTH, grids
-    assert max(decode) <= SLOTS * PAGES_PER_SLOT * HEADS // 8, decode
+    assert decode == [SLOTS] * DEPTH, grids
     if name == "mixed":
         chunk = steps.pop(BUDGET)
-        assert len(chunk) == DEPTH, grids
-        assert max(chunk) <= SLOTS * PAGES_PER_SLOT * HEADS // 2, chunk
+        assert chunk == [SLOTS * HEADS // 4] * DEPTH, grids
     assert not steps, steps
     text = built[0][name].as_text()
     table_first = re.findall(

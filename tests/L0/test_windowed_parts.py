@@ -130,20 +130,22 @@ def test_a_global_layer_has_no_positions_and_a_window_layer_has():
 
 
 # Equations of (`_mixed`, `_decode`) of the cells' engines at their
-# rehearsal sizes, read with `_step_programs.step_program_counts`. The
-# GPT pair and every `_decode` count are the PARENT's (GPT, granite and
-# longcat from the tree before the window/global model, commit 9f95df4,
-# PR 31; smallthinker's `_decode` from PR 35's, commit 3afee19): a
-# window-less call of either paged kernel, `HeldExperts` with its
-# default arguments, a cache without a window group and the frame's
-# decode-grid form trace what they traced then. A served model's
-# `_mixed` is ONE apply since PR 36 and is held UNDER what its two
-# applies traced at the parent (4,815, 5,316 and 12,772).
+# rehearsal sizes, read with `_step_programs.step_program_counts`.
+# Longcat's pair is the PARENT's of PR 36 (its `_decode` from the tree
+# before the window/global model, commit 9f95df4, PR 31): a latent cache
+# calls neither K/V kernel. The GPT, granite and smallthinker pairs are
+# read from PR 39's tree (the parent was commit 6281e14: 2,511 / 1,083,
+# 3,531 / 1,987, 8,313 / 5,668): `flash_attention_decode_paged` walks a
+# slot's live pages in a loop inside one grid step, and the kernel's
+# body (a fetch cursor, two loops) and its wrapper trace some thirty
+# equations a call site more than the fixed grid's did. A served
+# model's `_mixed` is ONE apply since PR 36 and is held UNDER what its
+# two applies traced at PR 36's parent (4,815, 5,316 and 12,772).
 PARENT_COUNTS = {
-    "gpt1p3b-serve-chat": (2511, 1083),
-    "granite4hs-serve-chat": (3531, 1987),
+    "gpt1p3b-serve-chat": (2635, 1145),
+    "granite4hs-serve-chat": (3593, 2018),
     "longcat-serve-agent-sat": (3619, 2319),
-    "smallthinker-serve-longmix-sat": (8313, 5668),
+    "smallthinker-serve-longmix-sat": (9097, 6060),
 }
 TWO_APPLIES = {
     "granite4hs-serve-chat": 4815,
