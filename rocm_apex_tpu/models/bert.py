@@ -21,6 +21,7 @@ from rocm_apex_tpu.models.gpt import (
     GPTConfig,
     ParallelTransformer,
     TransformerEmbedding,
+    extended_attention_mask,
     _init,
     _serial_cross_entropy,
 )
@@ -41,13 +42,9 @@ class BertConfig(GPTConfig):
     add_binary_head: bool = True
 
 
-def bert_extended_attention_mask(attention_mask: jnp.ndarray) -> jnp.ndarray:
-    """[b, s] padding mask (1 = keep) -> [b, 1, s, s] True = masked
-    (reference: standalone_bert.py bert_extended_attention_mask)."""
-    m = attention_mask.astype(bool)
-    # attend only where both query and key positions are valid
-    ext = m[:, None, :, None] & m[:, None, None, :]
-    return ~ext
+# the reference's name for it; `ParallelAttention` applies it itself to a
+# (b, s) row wherever the packed flash kernels do not take the row
+bert_extended_attention_mask = extended_attention_mask
 
 
 class BertLMHead(nn.Module):
@@ -120,23 +117,19 @@ class BertModel(nn.Module):
         deterministic: bool = True,
     ):
         cfg = self.cfg
-        # attention_mask=None means NO padded positions: keep it None
-        # so the attention layer takes the dense packed flash path
-        # (merged single-tile backward, no (b, s, s) zero-bias tensor)
-        # instead of masking against an all-keep tensor
-        ext_mask = (
-            bert_extended_attention_mask(attention_mask)
-            if attention_mask is not None
-            else None
-        )
-
+        # The (b, s) keep row goes down as it is: attention takes it as
+        # a key row inside the packed flash kernels where they apply,
+        # and blows it up to the reference's (b, 1, s, s) form
+        # (`bert_extended_attention_mask`) on every other path.
+        # attention_mask=None means NO padded positions and stays None,
+        # so no all-keep mask is made on any path.
         x = self.embedding(tokens, None, deterministic)
         if tokentype_ids is not None:
             x = x + jnp.take(
                 self.tokentype_embeddings, tokentype_ids, axis=0
             ).astype(cfg.dtype)
         x = self.transformer(
-            x, attention_mask=ext_mask, deterministic=deterministic
+            x, attention_mask=attention_mask, deterministic=deterministic
         )
 
         binary_logits = None

@@ -342,13 +342,23 @@ class ParallelMLP(nn.Module):
         return y
 
 
+def extended_attention_mask(keep: jnp.ndarray) -> jnp.ndarray:
+    """[b, s] padding mask (1 = keep) -> [b, 1, s, s] True = masked:
+    attend only where both query and key positions are valid
+    (reference: standalone_bert.py bert_extended_attention_mask)."""
+    m = keep.astype(bool)
+    return ~(m[:, None, :, None] & m[:, None, None, :])
+
+
 class ParallelAttention(nn.Module):
     """Self-attention with TP-sharded heads
     (reference: standalone_gpt.py:283-574): column-parallel fused QKV,
     scaled-masked-softmax core, row-parallel output projection.
 
     ``attn_mask_type``: 'causal' uses the Pallas upper-triang softmax;
-    'padding' takes an explicit mask (True = masked).
+    'padding' takes an explicit mask: 4-D (True = masked), or the
+    batch's own 2-D (b, s) row (nonzero = keep), which the packed flash
+    kernels read as a key row: see ``will_pack`` below.
     """
 
     cfg: GPTConfig
@@ -426,27 +436,43 @@ class ParallelAttention(nn.Module):
         use_flash = cfg.attention_impl == "flash" and (
             not dropout_active or use_flash_dropout
         )
-        # packed path: causal, or FULL bidirectional ("padding" type
-        # with no mask tensor — BERT with no padded positions): the
-        # dense packed kernels + merged single-tile backward serve it
-        # with causal=False, and no (b, s, s) zero-bias materializes
+        # A padding mask comes in one of two forms. 4-D, broadcastable to
+        # (b, 1, sq, sk) with True = MASKED, is the general form and
+        # rides the kernels' additive-bias operand. 2-D, the batch's own
+        # (b, s) row with nonzero = KEEP (what `BertModel` hands down),
+        # is the form the packed kernels take as a key row; on every
+        # other path it is blown up here to the 4-D form of the
+        # reference's `bert_extended_attention_mask` (query and key both
+        # valid), which is what those paths have always computed.
+        key_mask = None
+        if attention_mask is not None and attention_mask.ndim == 2:
+            key_mask = attention_mask
+        # packed path: the kernels read q/k/v straight out of the fused
+        # projection and write the context output-projection-ready. The
+        # conditions on head width, head count and sequence are the
+        # kernels' own (`packed_heads_per_step`, stated there once); the
+        # call site adds what the kernels cannot see: causal or
+        # "padding" attention whose mask is absent or the key row, no
+        # context-parallel axis, and no cache (cached paths materialize
+        # k/v, which must land in the cache buffers, and keep the
+        # projection bias in the matmul).
+        from rocm_apex_tpu.ops.flash_attention import packed_heads_per_step
+
         will_pack = (
             use_flash
             and (
                 self.attn_mask_type == "causal"
                 or (
                     self.attn_mask_type == "padding"
-                    and attention_mask is None
+                    and (attention_mask is None or key_mask is not None)
                 )
             )
             and cfg.context_parallel_axis is None
-            and hd % 128 == 0
-            # cached paths materialize k/v (they must land in the
-            # cache buffers), so the zero-relayout packed kernels —
-            # which read q/k/v straight out of the fused projection —
-            # do not apply; the projection bias stays in the matmul
             and cache is None
+            and packed_heads_per_step(nh_local, hd, sq) is not None
         )
+        if key_mask is not None and not will_pack:
+            attention_mask = extended_attention_mask(key_mask)
         # packed path: the projection bias rides into the attention
         # kernels (added on tile load; bias-grad partials emitted from
         # VMEM in backward) — param structure is unchanged
@@ -488,11 +514,6 @@ class ParallelAttention(nn.Module):
         use_pallas_softmax = (
             cfg.use_pallas_softmax and cfg.attention_impl != "jnp"
         )
-        # packed path: causal flash with hd % 128 == 0 reads q/k/v tiles
-        # straight out of the fused projection output — no split, no
-        # transposes, and the context lands output-projection-ready
-        # (measured ~8 ms/step of relayout on the 134M bench otherwise)
-
 
         def _dropout_seed():
             rng = self.make_rng("dropout")
@@ -917,38 +938,26 @@ class ParallelAttention(nn.Module):
                 .reshape(b, sq, nh_local * hd)
             )
         elif will_pack:
-            pk_causal = self.attn_mask_type == "causal"
-            if qkv_bias is None:
-                # use_bias=False projection: the unbiased packed ops
-                from rocm_apex_tpu.ops.flash_attention import (
-                    flash_attention_qkv,
-                    flash_attention_qkv_dropout,
-                )
+            from rocm_apex_tpu.ops import flash_attention as fa
 
-                if use_flash_dropout:
-                    ctx = flash_attention_qkv_dropout(
-                        qkv, _dropout_seed(), cfg.attention_dropout,
-                        pk_causal, scale,
-                    )
-                else:
-                    ctx = flash_attention_qkv(qkv, pk_causal, scale)
-            elif use_flash_dropout:
-                from rocm_apex_tpu.ops.flash_attention import (
-                    flash_attention_qkv_bias_dropout,
-                )
-
-                ctx = flash_attention_qkv_bias_dropout(
-                    qkv, qkv_bias, _dropout_seed(),
-                    cfg.attention_dropout, pk_causal, scale,
-                )
-            else:
-                from rocm_apex_tpu.ops.flash_attention import (
-                    flash_attention_qkv_bias,
-                )
-
-                ctx = flash_attention_qkv_bias(
-                    qkv, qkv_bias, pk_causal, scale
-                )
+            # one of the four packed entries, by what rides along: the
+            # projection bias (absent under use_bias=False) and the
+            # in-kernel dropout; the key row is an operand of all four
+            ins = [qkv]
+            if qkv_bias is not None:
+                ins.append(qkv_bias)
+            if use_flash_dropout:
+                ins += [_dropout_seed(), cfg.attention_dropout]
+            entry = {
+                (False, False): fa.flash_attention_qkv,
+                (False, True): fa.flash_attention_qkv_dropout,
+                (True, False): fa.flash_attention_qkv_bias,
+                (True, True): fa.flash_attention_qkv_bias_dropout,
+            }[qkv_bias is not None, use_flash_dropout]
+            ctx = entry(
+                *ins, self.attn_mask_type == "causal", scale,
+                key_mask=key_mask,
+            )
         elif use_flash:
             q, k, v = jnp.split(qkv, 3, axis=-1)  # (b, sq, nh, hd)
             qf = q.transpose(0, 2, 1, 3).reshape(b * nh_local, sq, hd)
@@ -1033,7 +1042,10 @@ class ParallelAttention(nn.Module):
                     if use_pallas_softmax:
                         probs = scaled_masked_softmax(scores, mask, scale)
                     else:
-                        s = jnp.where(mask, -jnp.inf, scores * scale)
+                        # a finite fill, as the flash bias has: a padded
+                        # row masks every key, and -inf there is a NaN
+                        # row that the next layer's 0 x NaN spreads
+                        s = jnp.where(mask, -1e30, scores * scale)
                         probs = jax.nn.softmax(s, axis=-1)
             probs = probs.astype(cfg.dtype)
 

@@ -1461,67 +1461,247 @@ def flash_attention_decode_paged(
 # transposes + context transpose, plus the non-contiguous residual
 # adds they induce) cost ~8 ms/step. The packed path instead reads
 # q/k/v tiles STRAIGHT OUT of the projection output via BlockSpec
-# index maps — grid row b decomposes as (batch b//nh, head b%nh), and
-# the head picks the (1, block, 1, hd) block column — and writes the
-# context back in (B, S, nh, hd) layout, bitcast-compatible with the
-# (B, S, H) input of the output projection. No transpose, no split,
-# no concat appears anywhere in the forward graph.
+# index maps and writes the context back in (B, S, nh, hd) layout,
+# bitcast-compatible with the (B, S, H) input of the output projection.
+# No transpose, no split, no concat appears anywhere in the forward
+# graph.
+#
+# WHO TAKES IT is written once, in `packed_heads_per_step`; the model's
+# call site (`models/gpt.py::ParallelAttention`, `will_pack`) asks it and
+# adds only what the kernels cannot see (no cache, no context-parallel
+# axis, and a mask that is absent or a (B, S) key row). Mosaic takes a
+# last-dimension block only in multiples of 128 lanes, and a head's
+# [q|k|v] columns are 3·hd wide, so a grid step takes G heads with
+# G·3·hd % 128 == 0:
+#
+# * hd % 128 == 0: G = 1, any sequence length. One tile covering the
+#   sequence runs the direct-softmax forward and the merged backward
+#   below; longer sequences run the general online-softmax kernels over
+#   (1, block, hd) columns of the same buffer.
+# * hd == 64 and an even head count: G = 2, ONE TILE only (a sequence of
+#   at most `DEFAULT_BLOCK_Q` rows). The step's (1, S, 384) block is
+#   [q0 k0 | v0 q1 | k1 v1]; the two heads are sliced out of it in VMEM
+#   and run one after the other; the context leaves as one lane-dense
+#   (1, S, 128) block [o0|o1] and the cotangent as one (1, S, 384) block
+#   in the projection's own layout. Nothing is padded to 128 in HBM.
+#
+# Anything else (an odd head count, another head width, heads of 64 over
+# more than one tile) is the caller's to send down the head-major path.
+
+
+def packed_heads_per_step(nh, hd, seq, block_q=DEFAULT_BLOCK_Q,
+                          block_k=DEFAULT_BLOCK_K):
+    """Heads one grid step of the packed path takes for ``nh`` heads of
+    ``hd`` over ``seq`` rows, or None where the packed path does not
+    apply (the one statement of its conditions: see the section's
+    comment)."""
+    if hd % 128 == 0:
+        return 1
+    if hd == 64 and nh % 2 == 0 and _one_tile(seq, block_q, block_k):
+        return 2
+    return None
+
+
+def _one_tile(seq, block_q, block_k):
+    """The block that covers ``seq`` rows in one (block, block) tile, or
+    None where the blocks asked for leave more than one."""
+    block_q = min(block_q, _round_up(seq, 128))
+    block_k = min(block_k, _round_up(seq, 128))
+    if block_q == block_k and _round_up(seq, block_q) == block_q:
+        return block_q
+    return None
+
+
+def _pad_seq(x, rows):
+    """``x`` (B, S, width) zero-padded to ``rows`` along S; no `pad` in
+    the graph where there is nothing to pad."""
+    if x.shape[1] == rows:
+        return x
+    return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, 0)))
+
+
+def _key_row(key_mask, rows):
+    """The (B, S) key keep-mask (nonzero = attend) as the additive
+    float32 (B, 1, rows) row the kernels add to every score row: 2 KB a
+    sequence where a (S, S) bias tile is 1 MiB. It rides the kernels'
+    ``bias_ref`` operand, so the masking itself stays in
+    `_masked_scores`. Columns past S need no entry (the kernels bound
+    them by ``sk_real``)."""
+    row = jnp.where(key_mask != 0, 0.0, NEG_INF).astype(jnp.float32)
+    if row.shape[1] != rows:
+        row = jnp.pad(row, ((0, 0), (0, rows - row.shape[1])))
+    return row[:, None, :]
+
+
+def _along_lanes(col):
+    """A (rows, 1) float32 column as a (1, rows) row, through an aligned
+    2-d transpose. The one-tile kernels save the log-sum-exp this way:
+    as a (B·nh, S, 1) array the chip tiles it (8, 128), 128 times its
+    bytes in HBM (64 MiB a layer at 16 x 16 heads x 512) moved 4 bytes
+    at a 512-byte stride; as (B·nh, 1, S) it is 0.5 MiB of whole rows."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], 128)))[:1]
+
+
+def _along_sublanes(row):
+    """`_along_lanes` undone: a (1, rows) row as a (rows, 1) column."""
+    return jnp.transpose(jnp.broadcast_to(row, (128, row.shape[1])))[:, :1]
+
+
+def _flat_head(step, heads, h):
+    """The flat batch·head index of head ``h`` of grid step ``step``:
+    what keys a head's dropout stream, whatever the heads a step."""
+    if heads == 1:
+        return step
+    return jax.lax.add(jax.lax.mul(step, heads), h)
+
+
+def _head_columns(x, h, hd):
+    """(q, k, v) of head ``h`` out of a step's [q|k|v]-per-head block."""
+    at = 3 * h * hd
+    return (
+        x[:, at:at + hd], x[:, at + hd:at + 2 * hd],
+        x[:, at + 2 * hd:at + 3 * hd],
+    )
 
 
 def _fwd_single_kernel(
-    causal, scale, sk_real, block_q, block_k, dropout_rate,
-    q_ref, k_ref, v_ref, *refs, has_qkv_bias=False,
+    causal, scale, sk_real, block, hd, heads, dropout_rate,
+    has_qkv_bias, has_key_row, x_ref, *refs,
 ):
-    """Single-block forward: the online-softmax carry (m/l scratch,
-    correction multiplies, init/finish phases) degenerates when one
-    (block_q, block_k) tile covers the whole sequence — this kernel
-    just computes the row softmax directly. Same masking via
-    `_masked_scores`, same dropout stream as the general kernel."""
+    """Single-tile forward over a step's ``heads`` heads: the
+    online-softmax carry (m/l scratch, correction multiplies, init /
+    finish phases) degenerates when one (block, block) tile covers the
+    whole sequence — each head's row softmax is computed directly. Same
+    masking via `_masked_scores`, same dropout stream as the general
+    kernel (keyed by the flat batch·head index)."""
     refs = list(refs)
-    qb_ref = refs.pop(0) if has_qkv_bias else None
-    kb_ref = refs.pop(0) if has_qkv_bias else None
-    vb_ref = refs.pop(0) if has_qkv_bias else None
+    b_ref = refs.pop(0) if has_qkv_bias else None
+    key_ref = refs.pop(0) if has_key_row else None
     seed_ref = refs.pop(0) if dropout_rate > 0.0 else None
     o_ref, lse_ref = refs
-    b = pl.program_id(0)
+    step = pl.program_id(0)
     zero = jnp.int32(0)
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
+    x = x_ref[0]
     if has_qkv_bias:
-        q = q + qb_ref[0]
-        k = k + kb_ref[0]
-        v = v + vb_ref[0]
-    s = _masked_scores(
-        causal, scale, sk_real, block_q, block_k,
-        q, k, None, None, b, zero, zero,
-    )
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp2(s - m)
-    l = jnp.sum(p, axis=1, keepdims=True)
-    if dropout_rate > 0.0:
-        keep = _keep_mask(
-            seed_ref, dropout_rate, b, zero, zero, (block_q, block_k)
+        # fused projection bias (same bf16 add the matmul epilogue
+        # would have performed); the (1, G·3·hd) row broadcasts
+        x = x + b_ref[0]
+    for h in range(heads):
+        head = _flat_head(step, heads, h)
+        q, k, v = _head_columns(x, h, hd)
+        s = _masked_scores(
+            causal, scale, sk_real, block, block,
+            q, k, key_ref, None, head, zero, zero,
         )
-        p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-    safe_l = jnp.where(l > 0.0, l, 1.0)
-    acc = jax.lax.dot(
-        p.astype(v.dtype), v,
-        preferred_element_type=jnp.float32, precision=_PREC,
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.exp2(s - m)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        if dropout_rate > 0.0:
+            keep = _keep_mask(
+                seed_ref, dropout_rate, head, zero, zero, (block, block)
+            )
+            p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+        safe_l = jnp.where(l > 0.0, l, 1.0)
+        acc = jax.lax.dot(
+            p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32, precision=_PREC,
+        )
+        o_ref[0, :, h * hd:(h + 1) * hd] = (acc / safe_l).astype(
+            o_ref.dtype
+        )
+        lse_ref[h] = _along_lanes((m + jnp.log2(safe_l)) * LN2)
+
+
+def _step_maps(nh, heads):
+    """Index maps of the one-tile kernels' 1-d grid, one step a group of
+    ``heads`` heads: the group's block of a (B, rows, groups·width)
+    buffer, its row of a (groups, 1, width) buffer, its sequence's row
+    of a (B, 1, rows) buffer, and its own row of a per-step buffer.
+    `lax.div`/`rem`: `//` and `%` trace through `sign` and a nested
+    `pjit` at every call site."""
+    groups = nh // heads
+
+    def of_batch(b):
+        return jax.lax.div(b, groups)
+
+    def of_group(b):
+        return jax.lax.rem(b, groups)
+
+    return (
+        lambda b: (of_batch(b), 0, of_group(b)),
+        lambda b: (of_group(b), 0, 0),
+        lambda b: (of_batch(b), 0, 0),
+        lambda b: (b, 0, 0),
     )
-    o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log2(safe_l)) * LN2
+
+
+def _optional_operands(nh, heads, width, block, qkv_bias, key_mask,
+                       dropout_rate, dropout_seed):
+    """The one-tile kernels' optional operands with their specs, in the
+    order both kernels take them: the projection bias, the key row, the
+    dropout seed."""
+    _, of_group, of_batch, _ = _step_maps(nh, heads)
+    ins, specs = [], []
+    if qkv_bias is not None:
+        # middle singleton dim so the (1, width) tile equals the
+        # array's last-two dims (Mosaic block divisibility rule)
+        ins.append(qkv_bias.reshape(nh // heads, 1, width))
+        specs.append(pl.BlockSpec((1, 1, width), of_group))
+    if key_mask is not None:
+        ins.append(_key_row(key_mask, block))
+        specs.append(pl.BlockSpec((1, 1, block), of_batch))
+    if dropout_rate > 0.0:
+        ins.append(jnp.asarray(dropout_seed, jnp.int32).reshape(1))
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    return ins, specs
 
 
 def _fwd_packed(qkv, causal, scale, block_q, block_k,
-                dropout_rate=0.0, dropout_seed=None, qkv_bias=None):
+                dropout_rate=0.0, dropout_seed=None, qkv_bias=None,
+                key_mask=None):
     B, S, nh, three_hd = qkv.shape
     hd = three_hd // 3
-    if three_hd != 3 * hd or hd % 128 != 0:
+    heads = packed_heads_per_step(nh, hd, S, block_q, block_k)
+    if three_hd != 3 * hd or heads is None:
         raise ValueError(
-            f"packed path needs qkv (B, S, nh, 3*hd) with hd % 128 == 0, "
+            "packed path needs qkv (B, S, nh, 3*hd) with hd % 128 == 0, "
+            "or hd == 64 with an even nh and a sequence of one tile; "
             f"got {qkv.shape}"
         )
+    # Pallas TPU tiles the LAST TWO dims, so the head lives in the flat
+    # last axis of the (B, S, nh*3*hd) view (free reshape of the
+    # projection output)
+    qkv3 = qkv.reshape(B, S, nh * three_hd)
+    has_qkv_bias = qkv_bias is not None
+    has_key_row = key_mask is not None
+    block = _one_tile(S, block_q, block_k)
+    if block is not None:
+        # one tile covers the sequence: direct softmax, no online carry
+        width = heads * three_hd
+        of_block, _, _, of_step = _step_maps(nh, heads)
+        more, more_specs = _optional_operands(
+            nh, heads, width, block, qkv_bias, key_mask, dropout_rate,
+            dropout_seed,
+        )
+        o, lse = pallas_call(
+            functools.partial(
+                _fwd_single_kernel, causal, scale, S, block, hd, heads,
+                dropout_rate, has_qkv_bias, has_key_row,
+            ),
+            grid=(B * nh // heads,),
+            in_specs=[pl.BlockSpec((1, block, width), of_block)]
+            + more_specs,
+            out_specs=[
+                pl.BlockSpec((1, block, heads * hd), of_block),
+                pl.BlockSpec((heads, 1, block), of_step),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, block, nh * hd), qkv.dtype),
+                jax.ShapeDtypeStruct((B * nh, 1, block), jnp.float32),
+            ],
+        )(_pad_seq(qkv3, block), *more)
+        return o[:, :S], lse
+
     block_q = min(block_q, _round_up(S, 128))
     block_k = min(block_k, _round_up(S, 128))
     # each grid dim rounds against ITS OWN block size (a shared
@@ -1531,13 +1711,8 @@ def _fwd_packed(qkv, causal, scale, block_q, block_k,
     sq_p = _round_up(S, block_q)
     sk_p = _round_up(S, block_k)
     pad = max(sq_p, sk_p)
-    # Pallas TPU tiles the LAST TWO dims, so the head lives in the flat
-    # last axis: hd-sized block column (head*3 + {0,1,2}) of the
-    # (B, S, nh*3*hd) view (free reshape of the projection output)
-    qkv3 = qkv.reshape(B, S, nh * three_hd)
-    qkv_p = jnp.pad(qkv3, ((0, 0), (0, pad - S), (0, 0)))
-    grid = (B * nh, sq_p // block_q, sk_p // block_k)
-
+    # hd-sized block column (head*3 + {0,1,2}) of the flat view
+    qkv_p = _pad_seq(qkv3, pad)
     ins = [qkv_p, qkv_p, qkv_p]
     in_specs = [
         pl.BlockSpec(
@@ -1552,10 +1727,7 @@ def _fwd_packed(qkv, causal, scale, block_q, block_k,
             lambda b, i, j: (b // nh, j, (b % nh) * 3 + 2),
         ),
     ]
-    has_qkv_bias = qkv_bias is not None
     if has_qkv_bias:
-        # middle singleton dim so the (1, hd) tile equals the array's
-        # last-two dims (Mosaic block divisibility rule)
         b2 = qkv_bias.reshape(nh * 3, 1, hd)
         ins += [b2, b2, b2]
         in_specs += [
@@ -1567,44 +1739,22 @@ def _fwd_packed(qkv, causal, scale, block_q, block_k,
                 (1, 1, hd), lambda b, i, j: ((b % nh) * 3 + 2, 0, 0)
             ),
         ]
+    if has_key_row:
+        # the general kernels' additive-bias operand, one row high
+        ins.append(_key_row(key_mask, sk_p))
+        in_specs.append(
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // nh, 0, j))
+        )
     if dropout_rate > 0.0:
         ins.append(jnp.asarray(dropout_seed, jnp.int32).reshape(1))
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
-    out_shape = [
-        jax.ShapeDtypeStruct((B, sq_p, nh * hd), qkv.dtype),
-        jax.ShapeDtypeStruct((B * nh, sq_p, 1), jnp.float32),
-    ]
-    if sq_p == block_q and sk_p == block_k and block_q == block_k:
-        # one tile covers the sequence: direct softmax, no online carry
-        def _one_d(spec):
-            # re-key the 3-d (b, i, j) index maps to the 1-d (b,) grid
-            if spec.index_map is None:  # the SMEM seed spec
-                return spec
-            f = spec.index_map
-            return pl.BlockSpec(spec.block_shape, lambda b, f=f: f(b, 0, 0))
-
-        o, lse = pallas_call(
-            functools.partial(
-                _fwd_single_kernel, causal, scale, S, block_q, block_k,
-                dropout_rate, has_qkv_bias=has_qkv_bias,
-            ),
-            grid=(B * nh,),
-            in_specs=[_one_d(spec) for spec in in_specs],
-            out_specs=[
-                pl.BlockSpec((1, block_q, hd), lambda b: (b // nh, 0, b % nh)),
-                pl.BlockSpec((1, block_q, 1), lambda b: (b, 0, 0)),
-            ],
-            out_shape=out_shape,
-        )(*ins)
-        return o[:, :S], lse[:, :S]
-
     o, lse = pallas_call(
         functools.partial(
-            _fwd_kernel, causal, scale, S, block_q, block_k, False,
+            _fwd_kernel, causal, scale, S, block_q, block_k, has_key_row,
             dropout_rate, False, has_qkv_bias=has_qkv_bias,
         ),
-        grid=grid,
+        grid=(B * nh, sq_p // block_q, sk_p // block_k),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec(
@@ -1612,7 +1762,10 @@ def _fwd_packed(qkv, causal, scale, block_q, block_k,
             ),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
-        out_shape=out_shape,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, sq_p, nh * hd), qkv.dtype),
+            jax.ShapeDtypeStruct((B * nh, sq_p, 1), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -1623,162 +1776,144 @@ def _fwd_packed(qkv, causal, scale, block_q, block_k,
 
 
 def _bwd_merged_kernel(
-    causal, scale, sk_real, block_q, block_k, hd, dropout_rate,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, *refs,
-    has_qkv_bias=False,
+    causal, scale, sk_real, block, hd, heads, dropout_rate,
+    has_qkv_bias, has_key_row, x_ref, do_ref, lse_ref, o_ref, *refs,
 ):
-    """Single-block fused backward: dq + dk + dv in ONE kernel pass.
+    """Single-tile fused backward: dq + dk + dv of a step's ``heads``
+    heads in ONE kernel pass.
 
-    Used when one (block_q, block_k) tile covers the whole sequence
-    (the common training regime, e.g. s=1024 blocks 1024²). The split
-    dkv/dq kernels each recompute the score and dp matrices and each
-    re-read q/k/v/do from HBM — 7 MXU matmuls and 2x input traffic.
-    This kernel shares those intermediates (5 matmuls, one read) and
-    writes the three cotangents STRAIGHT INTO the packed projection
-    layout: dqkv_ref is the (1, block, 3*hd) per-head column of the
-    (B, S, nh*3*hd) qkv-projection cotangent, so the 3-way concat the
-    split path needs disappears entirely. delta = rowsum(do·o) is also
-    computed here from the o tile (a few VPU ops on data already in
-    VMEM) instead of as a separate XLA reduction pass over the full
-    (B, S, nh, hd) product in HBM."""
+    Used when one (block, block) tile covers the whole sequence (the
+    common training regime, e.g. s=1024 blocks 1024²). The split dkv/dq
+    kernels each recompute the score and dp matrices and each re-read
+    q/k/v/do from HBM — 7 MXU matmuls and 2x input traffic. This kernel
+    shares those intermediates (5 matmuls, one read) and writes the
+    cotangents STRAIGHT INTO the packed projection layout: dqkv_ref is
+    the step's (1, block, heads·3·hd) column of the (B, S, nh·3·hd)
+    qkv-projection cotangent, so the 3-way concat the split path needs
+    disappears entirely. delta = rowsum(do·o) is also computed here from
+    the o tile (a few VPU ops on data already in VMEM) instead of as a
+    separate XLA reduction pass over the full (B, S, nh, hd) product in
+    HBM."""
     refs = list(refs)
-    qb_ref = refs.pop(0) if has_qkv_bias else None
-    kb_ref = refs.pop(0) if has_qkv_bias else None
-    vb_ref = refs.pop(0) if has_qkv_bias else None
+    b_ref = refs.pop(0) if has_qkv_bias else None
+    key_ref = refs.pop(0) if has_key_row else None
     seed_ref = refs.pop(0) if dropout_rate > 0.0 else None
     if has_qkv_bias:
         dqkv_ref, dbias_ref = refs
     else:
         (dqkv_ref,) = refs
-    b = pl.program_id(0)
+    step = pl.program_id(0)
     zero = jnp.int32(0)  # qi = ki = 0: the single block
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
+    x = x_ref[0]
     if has_qkv_bias:
         # the saved residual is the PRE-bias projection output; the
         # probability recompute needs the biased operands
-        q = q + qb_ref[0]
-        k = k + kb_ref[0]
-        v = v + vb_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0]
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
-    s = _masked_scores(
-        causal, scale, sk_real, block_q, block_k,
-        q, k, None, None, b, zero, zero,
-    )
-    p = jnp.exp2(s - lse * LOG2E)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_PREC,
-    )
-    if dropout_rate > 0.0:
-        keep = _keep_mask(
-            seed_ref, dropout_rate, b, zero, zero, (block_q, block_k)
+        x = x + b_ref[0]
+    do_all = do_ref[0]
+    dod = do_all.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    for h in range(heads):
+        head = _flat_head(step, heads, h)
+        q, k, v = _head_columns(x, h, hd)
+        do = do_all[:, h * hd:(h + 1) * hd]
+        delta = jnp.sum(
+            dod[:, h * hd:(h + 1) * hd], axis=-1, keepdims=True
         )
-        inv = 1.0 / (1.0 - dropout_rate)
-        p_drop = jnp.where(keep, p * inv, 0.0)
-        dp = jnp.where(keep, dp * inv, 0.0)
-    else:
-        p_drop = p
-    dv = jax.lax.dot_general(
-        p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_PREC,
-    )
-    # unscaled ds: the q·k scale is applied to the (block, d) dq/dk
-    # results, not the (block, block) score tile
-    ds = (p * (dp - delta)).astype(q.dtype)
-    dk = jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_PREC,
-    ) * scale
-    dq = jax.lax.dot(
-        ds, k, preferred_element_type=jnp.float32, precision=_PREC,
-    ) * scale
-    dqkv_ref[0, :, :hd] = dq.astype(dqkv_ref.dtype)
-    dqkv_ref[0, :, hd:2 * hd] = dk.astype(dqkv_ref.dtype)
-    dqkv_ref[0, :, 2 * hd:] = dv.astype(dqkv_ref.dtype)
-    if has_qkv_bias:
-        # fp32 per-(batch, head) bias-grad partials while the cotangent
-        # tiles are still in VMEM — replaces a full XLA reduction pass
-        # over the (B, S, nh, 3hd) dqkv buffer in HBM (whose producer is
-        # this opaque kernel, so XLA cannot fuse it)
-        dbias_ref[0, 0, :hd] = jnp.sum(dq, axis=0)
-        dbias_ref[0, 0, hd:2 * hd] = jnp.sum(dk, axis=0)
-        dbias_ref[0, 0, 2 * hd:] = jnp.sum(dv, axis=0)
+        s = _masked_scores(
+            causal, scale, sk_real, block, block,
+            q, k, key_ref, None, head, zero, zero,
+        )
+        p = jnp.exp2(s - _along_sublanes(lse_ref[h]) * LOG2E)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_PREC,
+        )
+        if dropout_rate > 0.0:
+            keep = _keep_mask(
+                seed_ref, dropout_rate, head, zero, zero, (block, block)
+            )
+            inv = 1.0 / (1.0 - dropout_rate)
+            p_drop = jnp.where(keep, p * inv, 0.0)
+            dp = jnp.where(keep, dp * inv, 0.0)
+        else:
+            p_drop = p
+        dv = jax.lax.dot_general(
+            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_PREC,
+        )
+        # unscaled ds: the q·k scale is applied to the (block, d) dq/dk
+        # results, not the (block, block) score tile
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dk = jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_PREC,
+        ) * scale
+        dq = jax.lax.dot(
+            ds, k, preferred_element_type=jnp.float32, precision=_PREC,
+        ) * scale
+        for j, g in enumerate((dq, dk, dv)):
+            at = (3 * h + j) * hd
+            dqkv_ref[0, :, at:at + hd] = g.astype(dqkv_ref.dtype)
+            if has_qkv_bias:
+                # fp32 per-(batch, head) bias-grad partials while the
+                # cotangent tiles are still in VMEM — replaces a full
+                # XLA reduction pass over the (B, S, nh, 3hd) dqkv
+                # buffer in HBM (whose producer is this opaque kernel,
+                # so XLA cannot fuse it)
+                dbias_ref[0, :, at:at + hd] = jnp.sum(
+                    g, axis=0, keepdims=True
+                )
 
 
 def _bwd_packed_merged(causal, scale, block, res, do,
                        dropout_rate=0.0, dropout_seed=None,
-                       qkv_bias=None):
+                       qkv_bias=None, key_mask=None):
     """Single-tile packed backward: see `_bwd_merged_kernel`.
 
     With ``qkv_bias`` also returns the (nh*3*hd,) fp32 bias cotangent
-    (summed over batch from the kernel's per-(batch, head) partials)."""
+    (summed over batch from the kernel's per-(batch, group) partials)."""
     qkv, o, lse = res
     B, S, nh, three_hd = qkv.shape
     hd = three_hd // 3
-    pad = block
+    heads = packed_heads_per_step(nh, hd, S, block, block)
+    width = heads * three_hd
+    of_block, _, _, of_step = _step_maps(nh, heads)
 
-    qkv_p = jnp.pad(
-        qkv.reshape(B, S, nh * three_hd), ((0, 0), (0, pad - S), (0, 0))
-    )
-    do_p = jnp.pad(do, ((0, 0), (0, pad - S), (0, 0)))
-    o_p = jnp.pad(o, ((0, 0), (0, pad - S), (0, 0)))
-    lse_p = jnp.pad(
-        lse, ((0, 0), (0, pad - S), (0, 0)), constant_values=-NEG_INF
-    )
-
-    ins = [qkv_p, qkv_p, qkv_p, do_p, lse_p, o_p]
+    ins = [
+        _pad_seq(qkv.reshape(B, S, nh * three_hd), block),
+        _pad_seq(do, block),
+        lse,
+        _pad_seq(o, block),
+    ]
     in_specs = [
-        pl.BlockSpec((1, block, hd), lambda b: (b // nh, 0, (b % nh) * 3)),
-        pl.BlockSpec(
-            (1, block, hd), lambda b: (b // nh, 0, (b % nh) * 3 + 1)
-        ),
-        pl.BlockSpec(
-            (1, block, hd), lambda b: (b // nh, 0, (b % nh) * 3 + 2)
-        ),
-        pl.BlockSpec((1, block, hd), lambda b: (b // nh, 0, b % nh)),
-        pl.BlockSpec((1, block, 1), lambda b: (b, 0, 0)),
-        pl.BlockSpec((1, block, hd), lambda b: (b // nh, 0, b % nh)),
+        pl.BlockSpec((1, block, width), of_block),
+        pl.BlockSpec((1, block, heads * hd), of_block),
+        pl.BlockSpec((heads, 1, block), of_step),
+        pl.BlockSpec((1, block, heads * hd), of_block),
     ]
     has_qkv_bias = qkv_bias is not None
-    if has_qkv_bias:
-        b2 = qkv_bias.reshape(nh * 3, 1, hd)
-        ins += [b2, b2, b2]
-        in_specs += [
-            pl.BlockSpec((1, 1, hd), lambda b: ((b % nh) * 3, 0, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b: ((b % nh) * 3 + 1, 0, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b: ((b % nh) * 3 + 2, 0, 0)),
-        ]
-    if dropout_rate > 0.0:
-        ins.append(jnp.asarray(dropout_seed, jnp.int32).reshape(1))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-
-    out_specs = pl.BlockSpec(
-        (1, block, three_hd), lambda b: (b // nh, 0, b % nh)
+    has_key_row = key_mask is not None
+    more, more_specs = _optional_operands(
+        nh, heads, width, block, qkv_bias, key_mask, dropout_rate,
+        dropout_seed,
     )
-    out_shape = jax.ShapeDtypeStruct((B, pad, nh * three_hd), qkv.dtype)
+    ins += more
+    in_specs += more_specs
+
+    out_specs = pl.BlockSpec((1, block, width), of_block)
+    out_shape = jax.ShapeDtypeStruct((B, block, nh * three_hd), qkv.dtype)
     if has_qkv_bias:
-        out_specs = [
-            out_specs,
-            pl.BlockSpec((1, 1, three_hd), lambda b: (b, 0, 0)),
-        ]
+        out_specs = [out_specs, pl.BlockSpec((1, 1, width), of_step)]
         out_shape = [
             out_shape,
-            jax.ShapeDtypeStruct((B * nh, 1, three_hd), jnp.float32),
+            jax.ShapeDtypeStruct((B * nh // heads, 1, width), jnp.float32),
         ]
 
     out = pallas_call(
         functools.partial(
-            _bwd_merged_kernel, causal, scale, S, block, block, hd,
-            dropout_rate, has_qkv_bias=has_qkv_bias,
+            _bwd_merged_kernel, causal, scale, S, block, hd, heads,
+            dropout_rate, has_qkv_bias, has_key_row,
         ),
-        grid=(B * nh,),
+        grid=(B * nh // heads,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -1793,21 +1928,23 @@ def _bwd_packed_merged(causal, scale, block, res, do,
 
 
 def _bwd_packed(causal, scale, block_q, block_k, res, do,
-                dropout_rate=0.0, dropout_seed=None, qkv_bias=None):
+                dropout_rate=0.0, dropout_seed=None, qkv_bias=None,
+                key_mask=None):
     qkv, o, lse = res  # qkv (B,S,nh,3hd), o (B,S,nh*hd), lse (B*nh,S,1)
     B, S, nh, three_hd = qkv.shape
     hd = three_hd // 3
+    block = _one_tile(S, block_q, block_k)
+    if block is not None:
+        # one tile covers the sequence: fused dq+dk+dv kernel, no concat
+        return _bwd_packed_merged(
+            causal, scale, block, res, do,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            qkv_bias=qkv_bias, key_mask=key_mask,
+        )
     block_q = min(block_q, _round_up(S, 128))
     block_k = min(block_k, _round_up(S, 128))
     sq_p = _round_up(S, block_q)
     sk_p = _round_up(S, block_k)
-    if sq_p == block_q and sk_p == block_k and block_q == block_k:
-        # one tile covers the sequence: fused dq+dk+dv kernel, no concat
-        return _bwd_packed_merged(
-            causal, scale, block_q, res, do,
-            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-            qkv_bias=qkv_bias,
-        )
     if qkv_bias is not None:
         # multi-tile fallback: biased operands via the pre-add (the
         # kernels then see the same values), dbias via an XLA reduce.
@@ -1822,6 +1959,7 @@ def _bwd_packed(causal, scale, block_q, block_k, res, do,
         dqkv = _bwd_packed(
             causal, scale, block_q, block_k, (qkv, o, lse), do,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            key_mask=key_mask,
         )
         return dqkv, jnp.sum(
             dqkv.astype(jnp.float32), axis=(0, 1)
@@ -1845,6 +1983,9 @@ def _bwd_packed(causal, scale, block_q, block_k, res, do,
     delta_p = jnp.pad(delta, ((0, 0), (0, pad - S), (0, 0)))
 
     ins = [qkv_p, qkv_p, qkv_p, do_p, lse_p, delta_p]
+    has_key_row = key_mask is not None
+    if has_key_row:
+        ins.append(_key_row(key_mask, sk_p))
     if dropout_rate > 0.0:
         ins.append(jnp.asarray(dropout_seed, jnp.int32).reshape(1))
 
@@ -1874,6 +2015,10 @@ def _bwd_packed(causal, scale, block_q, block_k, res, do,
                 (1, block_q, 1), lambda b, a, c: (b, q_of(a, c), 0)
             ),
         ]
+        if has_key_row:
+            specs.append(pl.BlockSpec(
+                (1, 1, block_k), lambda b, a, c: (b // nh, 0, k_of(a, c))
+            ))
         if dropout_rate > 0.0:
             specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         return specs
@@ -1881,8 +2026,8 @@ def _bwd_packed(causal, scale, block_q, block_k, res, do,
     # dk/dv: grid (bh, kv, q) — q innermost
     dk, dv = pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, causal, scale, S, block_q, block_k, False,
-            dropout_rate, False,
+            _bwd_dkv_kernel, causal, scale, S, block_q, block_k,
+            has_key_row, dropout_rate, False,
         ),
         grid=(B * nh, sk_p // block_k, sq_p // block_q),
         in_specs=_specs(q_of=lambda j, i: i, k_of=lambda j, i: j),
@@ -1907,8 +2052,8 @@ def _bwd_packed(causal, scale, block_q, block_k, res, do,
     # dq: grid (bh, q, kv) — kv innermost
     dq = pallas_call(
         functools.partial(
-            _bwd_dq_kernel, causal, scale, S, block_q, block_k, False,
-            dropout_rate, False,
+            _bwd_dq_kernel, causal, scale, S, block_q, block_k,
+            has_key_row, dropout_rate, False,
         ),
         grid=(B * nh, sq_p // block_q, sk_p // block_k),
         in_specs=_specs(q_of=lambda i, j: i, k_of=lambda i, j: j),
@@ -1936,6 +2081,20 @@ def _qkv_scale(qkv, scale):
     return scale if scale is not None else 1.0 / np.sqrt(qkv.shape[-1] // 3)
 
 
+# The four entries below share one contract. ``qkv`` is (B, S, nh, 3*hd)
+# — exactly the reshape of a fused QKV projection, q|k|v contiguous per
+# head in the last dim — at a head width and count that
+# `packed_heads_per_step` takes. ``key_mask`` is an optional (B, S) mask
+# of KEYS (nonzero = attend): a padded batch's own row, added to every
+# score row inside the kernels. It masks keys alone. A row whose own
+# position is padded still attends the kept keys and holds their
+# softmax-weighted values (finite; the mean of all values where a
+# sequence keeps no key at all); nothing downstream of a padding mask
+# reads such a row, and its cotangent is whatever the caller hands in
+# (zero from a masked loss). The mask is data, not a parameter: its
+# cotangent is zero.
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
 def flash_attention_qkv(
     qkv: jnp.ndarray,
@@ -1943,35 +2102,38 @@ def flash_attention_qkv(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
+    key_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Zero-relayout self attention on a fused projection output.
 
-    ``qkv`` is (B, S, nh, 3*hd) — exactly the reshape of a fused QKV
-    projection, with q|k|v contiguous per head in the last dim and
-    hd % 128 == 0. Returns the (B, S, nh*hd) context, laid out for the
-    output projection. q/k/v tiles are read straight out of ``qkv`` by
-    kernel index maps: no transpose, split, or concat materializes in
-    forward (backward does one concat for the qkv cotangent).
+    Returns the (B, S, nh*hd) context, laid out for the output
+    projection. q/k/v tiles are read straight out of ``qkv`` by kernel
+    index maps: no transpose, split, or concat materializes in forward
+    (the multi-tile backward does one concat for the qkv cotangent, the
+    one-tile backward none).
     """
     o, _ = _fwd_packed(
-        qkv, causal, _qkv_scale(qkv, scale), block_q, block_k
+        qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
+        key_mask=key_mask,
     )
     return o
 
 
-def _faq_fwd(qkv, causal, scale, block_q, block_k):
+def _faq_fwd(qkv, causal, scale, block_q, block_k, key_mask):
     o, lse = _fwd_packed(
-        qkv, causal, _qkv_scale(qkv, scale), block_q, block_k
+        qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
+        key_mask=key_mask,
     )
-    return o, (qkv, o, lse)
+    return o, (qkv, o, lse, key_mask)
 
 
 def _faq_bwd(causal, scale, block_q, block_k, res, do):
-    qkv = res[0]
+    qkv, o, lse, key_mask = res
     dqkv = _bwd_packed(
-        causal, _qkv_scale(qkv, scale), block_q, block_k, res, do
+        causal, _qkv_scale(qkv, scale), block_q, block_k, (qkv, o, lse),
+        do, key_mask=key_mask,
     )
-    return (dqkv,)
+    return (dqkv, None)
 
 
 flash_attention_qkv.defvjp(_faq_fwd, _faq_bwd)
@@ -1986,34 +2148,37 @@ def flash_attention_qkv_dropout(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
+    key_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """`flash_attention_qkv` with in-kernel attention dropout (see
     `flash_attention_dropout` for the seeding/regeneration scheme)."""
     o, _ = _fwd_packed(
         qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+        key_mask=key_mask,
     )
     return o
 
 
 def _faqd_fwd(qkv, dropout_seed, dropout_rate, causal, scale,
-              block_q, block_k):
+              block_q, block_k, key_mask):
     o, lse = _fwd_packed(
         qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+        key_mask=key_mask,
     )
-    return o, (qkv, o, lse, dropout_seed)
+    return o, (qkv, o, lse, dropout_seed, key_mask)
 
 
 def _faqd_bwd(dropout_rate, causal, scale, block_q, block_k, res, do):
-    qkv, o, lse, seed = res
+    qkv, o, lse, seed, key_mask = res
     dqkv = _bwd_packed(
         causal, _qkv_scale(qkv, scale), block_q, block_k,
         (qkv, o, lse), do,
-        dropout_rate=dropout_rate, dropout_seed=seed,
+        dropout_rate=dropout_rate, dropout_seed=seed, key_mask=key_mask,
     )
     seed_ct = np.zeros((), jax.dtypes.float0)
-    return (dqkv, seed_ct)
+    return (dqkv, seed_ct, None)
 
 
 flash_attention_qkv_dropout.defvjp(_faqd_fwd, _faqd_bwd)
@@ -2027,6 +2192,7 @@ def flash_attention_qkv_bias(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
+    key_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """`flash_attention_qkv` with the QKV-projection BIAS fused in.
 
@@ -2041,26 +2207,28 @@ def flash_attention_qkv_bias(
     (apex/contrib/csrc/multihead_attn/ *_bias variants)."""
     o, _ = _fwd_packed(
         qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
-        qkv_bias=qkv_bias,
+        qkv_bias=qkv_bias, key_mask=key_mask,
     )
     return o
 
 
-def _faqb_fwd(qkv, qkv_bias, causal, scale, block_q, block_k):
+def _faqb_fwd(qkv, qkv_bias, causal, scale, block_q, block_k, key_mask):
     o, lse = _fwd_packed(
         qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
-        qkv_bias=qkv_bias,
+        qkv_bias=qkv_bias, key_mask=key_mask,
     )
-    return o, (qkv, qkv_bias, o, lse)
+    return o, (qkv, qkv_bias, o, lse, key_mask)
 
 
 def _faqb_bwd(causal, scale, block_q, block_k, res, do):
-    qkv, qkv_bias, o, lse = res
+    qkv, qkv_bias, o, lse, key_mask = res
     dqkv, dbias = _bwd_packed(
         causal, _qkv_scale(qkv, scale), block_q, block_k,
-        (qkv, o, lse), do, qkv_bias=qkv_bias,
+        (qkv, o, lse), do, qkv_bias=qkv_bias, key_mask=key_mask,
     )
-    return (dqkv, dbias.astype(qkv_bias.dtype).reshape(qkv_bias.shape))
+    return (
+        dqkv, dbias.astype(qkv_bias.dtype).reshape(qkv_bias.shape), None,
+    )
 
 
 flash_attention_qkv_bias.defvjp(_faqb_fwd, _faqb_bwd)
@@ -2076,38 +2244,41 @@ def flash_attention_qkv_bias_dropout(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
+    key_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """`flash_attention_qkv_bias` with in-kernel attention dropout."""
     o, _ = _fwd_packed(
         qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-        qkv_bias=qkv_bias,
+        qkv_bias=qkv_bias, key_mask=key_mask,
     )
     return o
 
 
 def _faqbd_fwd(qkv, qkv_bias, dropout_seed, dropout_rate, causal, scale,
-               block_q, block_k):
+               block_q, block_k, key_mask):
     o, lse = _fwd_packed(
         qkv, causal, _qkv_scale(qkv, scale), block_q, block_k,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-        qkv_bias=qkv_bias,
+        qkv_bias=qkv_bias, key_mask=key_mask,
     )
-    return o, (qkv, qkv_bias, o, lse, dropout_seed)
+    return o, (qkv, qkv_bias, o, lse, dropout_seed, key_mask)
 
 
 def _faqbd_bwd(dropout_rate, causal, scale, block_q, block_k, res, do):
-    qkv, qkv_bias, o, lse, seed = res
+    qkv, qkv_bias, o, lse, seed, key_mask = res
     dqkv, dbias = _bwd_packed(
         causal, _qkv_scale(qkv, scale), block_q, block_k,
         (qkv, o, lse), do,
         dropout_rate=dropout_rate, dropout_seed=seed, qkv_bias=qkv_bias,
+        key_mask=key_mask,
     )
     seed_ct = np.zeros((), jax.dtypes.float0)
     return (
         dqkv,
         dbias.astype(qkv_bias.dtype).reshape(qkv_bias.shape),
         seed_ct,
+        None,
     )
 
 

@@ -424,6 +424,146 @@ class TestFMHA:
             tpu_rtol=2e-2, tpu_atol=2e-2,
         )
 
+    # the packed path at heads of 64 (two heads a grid step), and the
+    # two geometries just outside it
+    @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+    @pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+    @pytest.mark.parametrize("mask_kind", ["nomask", "prefix", "hole"])
+    @pytest.mark.parametrize(
+        "nh,hd", [(2, 64), (16, 64), (3, 64), (4, 32)],
+        ids=["2x64", "16x64", "odd-3x64", "4x32"],
+    )
+    def test_packed_heads_of_64_in_pairs(
+        self, nh, hd, mask_kind, with_bias, causal
+    ):
+        """Heads of 64 are read in pairs out of the projection's own
+        layout, the padding mask as a (B, S) key row: output, dqkv and
+        the projection-bias gradient against the float32 `jax.numpy`
+        reference (which masks keys alone) at every row whose own key is
+        kept. An odd head count and heads of 32 are outside the packed
+        path: the entry refuses them, `ParallelAttention` sends them
+        down the head-major path it has always taken (a transpose in its
+        graph, three backward kernels), and that path agrees with the
+        same reference on the same rows."""
+        from rocm_apex_tpu.ops import flash_attention as fa
+
+        B, S = 2, 128
+        kq, kb, kd = jax.random.split(jax.random.PRNGKey(64 + nh), 3)
+        qkv = jax.random.normal(kq, (B, S, nh, 3 * hd))
+        bias = (
+            0.1 * jax.random.normal(kb, (nh * 3 * hd,)) if with_bias
+            else None
+        )
+        keep = np.ones((B, S), np.int32)
+        if mask_kind == "prefix":
+            keep[1, 40:] = 0
+        elif mask_kind == "hole":
+            keep[0, 17:33] = 0
+            keep[1, 100:] = 0
+        mask = None if mask_kind == "nomask" else jnp.asarray(keep)
+        rows = keep.astype(bool)
+        # a cotangent on every kept row; padded rows are never read
+        do = jax.random.normal(kd, (B, S, nh * hd)) * rows[..., None]
+
+        def reference(qkv, bias):
+            x = qkv if bias is None else qkv + bias.reshape(nh, 3 * hd)
+            q, k, v = x[..., :hd], x[..., hd:2 * hd], x[..., 2 * hd:]
+            s = jnp.einsum("bqnd,bknd->bnqk", q, k) / np.sqrt(hd)
+            if mask is not None:
+                s = jnp.where(mask[:, None, None, :] != 0, s, -1e30)
+            if causal:
+                s = jnp.where(np.tril(np.ones((S, S), bool)), s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            return jnp.einsum("bnqk,bknd->bqnd", p, v).reshape(B, S, nh * hd)
+
+        if nh % 2 or hd != 64:
+            assert fa.packed_heads_per_step(nh, hd, S) is None
+            with pytest.raises(ValueError, match="packed path needs"):
+                fa.flash_attention_qkv(qkv, causal, key_mask=mask)
+            self._head_major_path_agrees(
+                nh, hd, mask, causal, with_bias, rows
+            )
+            return
+        assert fa.packed_heads_per_step(nh, hd, S) == 2
+
+        def attend(qkv, bias):
+            if bias is None:
+                return fa.flash_attention_qkv(qkv, causal, key_mask=mask)
+            return fa.flash_attention_qkv_bias(
+                qkv, bias, causal, key_mask=mask
+            )
+
+        with jax.default_matmul_precision("highest"):
+            o_ref, vjp_ref = jax.vjp(reference, qkv, bias)
+            g_ref = vjp_ref(do)
+        o, vjp = jax.vjp(attend, qkv, bias)
+        g = vjp(do)
+        tol = dict(rtol=2e-5, atol=2e-5, tpu_rtol=2e-2, tpu_atol=2e-2)
+        assert np.isfinite(np.asarray(o)).all()
+        assert_close(np.asarray(o)[rows], np.asarray(o_ref)[rows], **tol)
+        assert_close(np.asarray(g[0])[rows], np.asarray(g_ref[0])[rows], **tol)
+        if with_bias:
+            # a sum over B x S rows: on the chip every row carries the
+            # arrays' rounding, so the sum is held per root row
+            root = np.sqrt(B * S)
+            assert_close(
+                np.asarray(g[1]) / root, np.asarray(g_ref[1]) / root,
+                rtol=1e-4, atol=1e-5, tpu_rtol=2e-2, tpu_atol=2e-2,
+            )
+
+    @staticmethod
+    def _head_major_path_agrees(nh, hd, mask, causal, with_bias, rows):
+        """`ParallelAttention` at a geometry outside the packed path,
+        handed the (B, S) row: its graph is the head-major one (a
+        transpose, one forward and two backward kernels) and it agrees
+        with the `jnp` implementation of the same module."""
+        from rocm_apex_tpu.models.gpt import GPTConfig, ParallelAttention
+
+        h = nh * hd
+        mods = {
+            impl: ParallelAttention(
+                GPTConfig(
+                    hidden_size=h, num_attention_heads=nh, num_layers=1,
+                    attention_dropout=0.0, hidden_dropout=0.0,
+                    tensor_parallel_size=1, dtype=jnp.float32,
+                    attention_impl=impl,
+                ),
+                "causal" if causal else "padding",
+            )
+            for impl in ("flash", "jnp")
+        }
+        B, S = rows.shape
+        kx, kp, kd = jax.random.split(jax.random.PRNGKey(hd + nh), 3)
+        x = jax.random.normal(kx, (B, S, h))
+        do = jax.random.normal(kd, (B, S, h)) * rows[..., None]
+        params = mods["jnp"].init(kp, x, mask)
+        if not with_bias:
+            qkv_p = dict(params["params"]["query_key_value"])
+            qkv_p["bias"] = jnp.zeros_like(qkv_p["bias"])
+            params = {"params": {
+                **params["params"], "query_key_value": qkv_p}}
+
+        def grads(impl):
+            out, vjp = jax.vjp(
+                lambda p, x: mods[impl].apply(p, x, mask), params, x
+            )
+            return out, vjp(do)
+
+        jaxpr = str(jax.make_jaxpr(lambda: grads("flash"))())
+        assert jaxpr.count("pallas_call") == 3, jaxpr.count("pallas_call")
+        assert "transpose" in jaxpr
+        (o, g), (o_ref, g_ref) = grads("flash"), grads("jnp")
+        assert_close(
+            np.asarray(o)[rows], np.asarray(o_ref)[rows],
+            rtol=2e-5, atol=2e-5, tpu_rtol=2e-2, tpu_atol=2e-2,
+        )
+        for a, b in zip(jax.tree_util.tree_leaves(g),
+                        jax.tree_util.tree_leaves(g_ref)):
+            assert_close(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4,
+                tpu_rtol=2e-2, tpu_atol=2e-2,
+            )
+
     def test_packed_qkv_odd_blocks_cover_tail(self):
         """Non-default block sizes that do not divide each other's
         rounding must still process every q row and k column (round-2

@@ -248,6 +248,80 @@ class TestBERT:
         assert np.isfinite(np.asarray(losses)).all()
 
 
+    # a padded batch at heads of 64: the packed path under a key row
+    @staticmethod
+    def _heads_of_64(impl):
+        cfg = BertConfig(
+            vocab_size=128,
+            hidden_size=128,
+            num_layers=2,
+            num_attention_heads=2,
+            max_position_embeddings=128,
+            hidden_dropout=0.0,
+            attention_dropout=0.0,
+            tensor_parallel_size=1,
+            attention_impl=impl,
+            dtype=jnp.float32,
+        )
+        model = BertModel(cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(12), (2, 128), 0, 128)
+        mask = jnp.ones((2, 128), jnp.int32).at[1, 50:].set(0)
+        loss_mask = mask.astype(jnp.float32)
+
+        def loss(params):
+            losses, binary = model.apply(
+                params, tokens, mask, lm_labels=tokens)
+            lm = jnp.sum(losses * loss_mask) / jnp.sum(loss_mask)
+            return lm + jnp.mean(jax.nn.logsumexp(binary, axis=-1))
+
+        params = model.init(jax.random.PRNGKey(13), tokens, mask)
+        return loss, params
+
+    def test_heads_of_64_padded_batch_flash_matches_jnp(self):
+        """`BertModel` at heads of 64 on a batch with one short padded
+        sequence: the packed flash path (key row) against the `jnp`
+        implementation, loss and every parameter's gradient. The two
+        differ only in rows whose own position is padded, which the
+        loss never reads."""
+        loss_f, params = self._heads_of_64("flash")
+        loss_j, _ = self._heads_of_64("jnp")
+        lf, gf = jax.value_and_grad(loss_f)(params)
+        lj, gj = jax.value_and_grad(loss_j)(params)
+        assert np.isfinite(float(lf))
+        np.testing.assert_allclose(float(lf), float(lj), rtol=1e-5)
+        flat_f = jax.tree_util.tree_leaves_with_path(gf)
+        flat_j = jax.tree_util.tree_leaves(gj)
+        for (path, a), b in zip(flat_f, flat_j):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-3, atol=2e-5,
+                err_msg=jax.tree_util.keystr(path),
+            )
+
+    def test_heads_of_64_padded_batch_stays_in_the_projections_layout(self):
+        """How the packed path says that it engaged: in the train
+        step's graph every layer's `self_attention` scope holds, outside
+        its two projections, exactly two Pallas calls (one tile forward,
+        one merged backward) and no `transpose`, `pad`, `split` or
+        `concatenate`: the kernels read the projection's output where it
+        lies and write the context and the cotangent likewise."""
+        loss, params = self._heads_of_64("flash")
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+        seen = {}
+        for eqn in jaxpr.jaxpr.eqns:  # top level: not inside the kernels
+            scope = str(eqn.source_info.name_stack)
+            if "self_attention" not in scope:
+                continue
+            if "query_key_value" in scope or scope.endswith("/dense"):
+                continue
+            layer = scope.split("transformer/")[1].split("/")[0]
+            seen.setdefault(layer, []).append(eqn.primitive.name)
+        assert sorted(seen) == ["layer_0", "layer_1"], sorted(seen)
+        for layer, prims in seen.items():
+            assert prims.count("pallas_call") == 2, (layer, prims)
+            moved = {"transpose", "pad", "split", "concatenate"} & set(prims)
+            assert not moved, (layer, moved)
+
+
 class TestFoldedConvBN:
     """The projection-shortcut fold (models/resnet.py FoldedConvBN):
     training-mode BN stats of a 1x1 conv's output computed from the
